@@ -154,8 +154,11 @@ def partition_dry_run(archs) -> dict:
         for n in PARTITION_MESHES:
             cmd += ["--mesh", str(n)]
         # drop this process's forced 2-device flag so the subprocess can
-        # force the full matrix's device count itself
+        # force the full matrix's device count itself; the child only
+        # lowers, so it stays on the CPU whatever platform this process
+        # holds (one process per chip)
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = " ".join(
             f for f in env.get("XLA_FLAGS", "").split()
             if "xla_force_host_platform_device_count" not in f)

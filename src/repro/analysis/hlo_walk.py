@@ -16,8 +16,10 @@ Per collective the walker recovers
 * the replica grouping, in both the explicit ``{{0,1},{2,3}}`` and the
   iota ``[4,2]<=[2,4]T(1,0)`` form (4 groups of 2),
 * jax provenance from the ``metadata`` field (``op_name`` carries the
-  eqn path, e.g. ``jit(decode)/.../gather``; ``source_file``/
-  ``source_line`` point into the model source), and
+  eqn path, e.g. ``jit(decode)/.../gather``; ``stack_frame_id`` names
+  the innermost frame of the module's ``FileNames`` / ``FileLocations``
+  / ``StackFrames`` tables, which resolve to the model source file and
+  line), and
 * exact wire bytes per device under the standard ring schedules:
   all-gather moves ``out*(g-1)/g`` through every device, reduce-scatter
   ``in*(g-1)/g``, all-reduce ``2*in*(g-1)/g`` (reduce-scatter +
@@ -146,8 +148,37 @@ _HEAD_RE = re.compile(
 _EXPLICIT_GROUPS_RE = re.compile(r"replica_groups=\{(\{[\d,{} ]*\})?\}")
 _IOTA_GROUPS_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=")
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
-_SOURCE_FILE_RE = re.compile(r'source_file="([^"]*)"')
-_SOURCE_LINE_RE = re.compile(r"source_line=(\d+)")
+_FRAME_ID_RE = re.compile(r"stack_frame_id=(\d+)")
+_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+_TABLE_ROW_RE = re.compile(r"^(\d+) (.*)$")
+_FIELD_RE = re.compile(r"(\w+)=(\d+)")
+
+
+def _stack_frames(hlo_text: str) -> Dict[int, Tuple[str, int]]:
+    """Frame id -> (source file, line), from the module's stack-frame
+    tables (rows ``<id> "<name>"`` or ``<id> {key=value ...}`` under a
+    table header; several blocks merge by id)."""
+    rows: Dict[str, Dict[int, str]] = {t: {} for t in _TABLES}
+    table = None
+    for line in hlo_text.splitlines():
+        if line in _TABLES:
+            table = line
+            continue
+        m = _TABLE_ROW_RE.match(line) if table else None
+        if m is None:
+            table = None
+            continue
+        rows[table][int(m.group(1))] = m.group(2)
+    def fields(v: str) -> Dict[str, int]:
+        return {k: int(n) for k, n in _FIELD_RE.findall(v)}
+
+    files = {i: v.strip('"') for i, v in rows["FileNames"].items()}
+    locs = {i: fields(v) for i, v in rows["FileLocations"].items()}
+    out: Dict[int, Tuple[str, int]] = {}
+    for i, v in rows["StackFrames"].items():
+        loc = locs.get(fields(v).get("file_location_id"), {})
+        out[i] = (files.get(loc.get("file_name_id"), ""), loc.get("line", 0))
+    return out
 
 
 def _parse_shapes(text: str) -> Tuple[Shape, ...]:
@@ -196,6 +227,7 @@ def parse_collectives(hlo_text: str,
     group spanning all participants).  ``-done`` instructions are
     skipped — their ``-start`` carries the shapes and metadata.
     """
+    frames = _stack_frames(hlo_text)
     out: List[Collective] = []
     for line in hlo_text.splitlines():
         # ``-done`` ops never match _HEAD_RE (the kind must be followed
@@ -208,8 +240,9 @@ def parse_collectives(hlo_text: str,
         canonical = kind[:-len("-start")] if is_async else kind
         operands = _operand_region(line, line.index("(", m.end(3)))
         n_groups, group_size = _parse_groups(line, n_devices)
-        src = _SOURCE_FILE_RE.search(line)
-        ln = _SOURCE_LINE_RE.search(line)
+        frame = _FRAME_ID_RE.search(line)
+        src, ln = frames.get(int(frame.group(1)), ("", 0)) if frame \
+            else ("", 0)
         opn = _OP_NAME_RE.search(line)
         out.append(Collective(
             kind=canonical, name=name,
@@ -217,8 +250,7 @@ def parse_collectives(hlo_text: str,
             operand_shapes=_parse_shapes(operands),
             n_groups=n_groups, group_size=group_size,
             op_name=opn.group(1) if opn else "",
-            source_file=src.group(1) if src else "",
-            source_line=int(ln.group(1)) if ln else 0,
+            source_file=src, source_line=ln,
             is_async=is_async))
     return out
 

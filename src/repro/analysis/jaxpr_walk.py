@@ -213,7 +213,7 @@ class _Walker:
             self._pallas(eqn, env, mult)
             return
 
-        if prim in ("pjit", "closed_call", "core_call", "custom_jvp_call",
+        if prim in ("jit", "closed_call", "core_call", "custom_jvp_call",
                     "custom_vjp_call", "remat", "checkpoint"):
             closed = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
             if closed is None or not hasattr(closed, "jaxpr"):
@@ -286,7 +286,8 @@ class _Walker:
         env[eqn.outvars[0]] = t          # in-place chain continues
 
     def _pallas(self, eqn, env: Dict, mult: int) -> None:
-        name_src = str(eqn.params.get("name_and_src_info", ""))
+        # the kernel body's "<fn> at <file>:<line>", which names its package
+        name_src = str(eqn.params["jaxpr"].debug_info.func_src_info)
         taints = tuple(self._get(env, v) for v in eqn.invars)
         self.sites.append(PallasSite(
             name_and_src=name_src, multiplier=mult,

@@ -79,7 +79,7 @@ def abstract_mesh(n: int):
     (data-parallel over slots/pages; the model axis stays 1 — smoke
     configs have too few KV heads to fill one)."""
     from jax.sharding import AbstractMesh
-    return AbstractMesh((("data", int(n)), ("model", 1)))
+    return AbstractMesh((int(n), 1), ("data", "model"))
 
 
 @dataclasses.dataclass

@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the reproduction's compute hot-spots.
 
 Each kernel is a package of three modules — ``kernel.py`` (the Pallas
-TPU implementation, runnable in interpret mode on CPU so CI validates
+TPU implementation, run in interpret mode off the TPU so CI validates
 it without hardware), ``ref.py`` (a pure-jnp oracle with the same
 feature set), and ``ops.py`` (the public op with ``backend="pallas" |
 "ref"`` dispatch).  The kernel CI job runs every package's parity suite
@@ -36,11 +36,16 @@ buys nothing.
 
 The kernel removes the copy:
 
-* **Grid layout** — ``(batch_slot, kv_head, logical_page)`` with the
-  page axis innermost.  TPU grids are sequential over the last
-  dimension, so the online-softmax state (running max, running sum,
-  fp32 output accumulator) lives in VMEM scratch across one slot+head's
-  page walk, exactly like the flash kernel's KV-block axis.
+* **Grid layout** — ``(batch_slot, logical_page)`` with the page axis
+  innermost; one step takes one whole pool page, all KV heads (block
+  ``(1, page_size, kv_heads, head_dim)`` — the TPU lowering requires a
+  block's last two dims to be tile multiples or the array's own, so a
+  one-head block is refused), scored by one matmul batched over KV
+  heads against the ``(1, kv_heads, group, head_dim)`` query block.
+  TPU grids are sequential over the last dimension, so the
+  online-softmax state (running max, running sum, fp32 output
+  accumulator, per query head) lives in VMEM scratch across one slot's
+  page walk.
 * **Block-table index map** — the block table and per-slot positions
   are scalar-prefetch operands
   (:class:`~jax.experimental.pallas.tpu.PrefetchScalarGridSpec`); the
@@ -70,7 +75,7 @@ page of the shared pool might serve any slot, so partitioning the
 unmapped kernel forces all-gathers of the *whole pool* every step —
 the ``pool-collective`` finding family the static auditor used to
 baseline.  The fix is layout, not kernel code: the kernel itself stays
-mesh-oblivious (one slot+head's page walk never crosses a slot
+mesh-oblivious (one slot's page walk never crosses a slot
 boundary), and the serving layer makes locality true by construction.
 :class:`~repro.serve.paging.PageTable` pins slots to data-axis shards
 and carves the pool into per-shard extents (``shards`` contiguous
@@ -85,4 +90,22 @@ exchange.  Generations are bit-identical to the solo engine — pinned
 across forced preemption/offload in ``tests/test_serve_multidevice.py``
 — and the auditor's partition gate now runs against an *empty*
 baseline at every mesh size.
+
+Interpret mode
+--------------
+:func:`pallas_interpret` is the one place that decides it: a kernel
+interprets exactly when JAX's default backend is not a TPU.  On a TPU
+every kernel compiles through Mosaic, and a kernel the TPU lowering
+refuses raises the compiler's error instead of quietly interpreting.
 """
+from __future__ import annotations
+
+import jax
+
+__all__ = ["pallas_interpret"]
+
+
+def pallas_interpret() -> bool:
+    """Interpret mode for a Pallas call: exactly when the default
+    backend is not a TPU."""
+    return jax.default_backend() != "tpu"

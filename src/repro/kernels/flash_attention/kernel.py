@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 __all__ = ["flash_attention", "DEFAULT_Q_BLOCK", "DEFAULT_KV_BLOCK"]
 
 DEFAULT_Q_BLOCK = 128
@@ -102,8 +104,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "softcap", "q_block", "kv_block",
-                     "interpret"),
+    static_argnames=("causal", "window", "softcap", "q_block", "kv_block"),
 )
 def flash_attention(
     q: jnp.ndarray,            # [b, sq, h, hd]
@@ -115,7 +116,6 @@ def flash_attention(
     softcap: Optional[float] = None,
     q_block: int = DEFAULT_Q_BLOCK,
     kv_block: int = DEFAULT_KV_BLOCK,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     b, sq, h, hd = q.shape
     skv, kvh = k.shape[1], k.shape[2]
@@ -163,6 +163,6 @@ def flash_attention(
             pltpu.VMEM((q_block,), jnp.float32),      # running sum
             pltpu.VMEM((q_block, hd), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(q, k, v)
     return out[:, :sq] if pad_q else out

@@ -1,10 +1,10 @@
 """Public op: flash attention with backend dispatch.
 
-``attention(..., backend="pallas")`` runs the tiled TPU kernel
-(interpret mode on CPU); ``backend="ref"`` runs the O(s^2) jnp oracle.
-The model layer (repro.models.attention) uses its own blocked-jnp path
-for XLA lowering; on real TPU hardware this op substitutes via
-``use_kernel=True`` plumbing in the serving/training launchers.
+``attention(..., backend="pallas")`` runs the tiled kernel (interpreted
+off the TPU — :func:`repro.kernels.pallas_interpret`); ``backend="ref"``
+runs the O(s^2) jnp oracle.  The model layer (repro.models.attention)
+uses its own blocked-jnp path and never calls this op: the TPU lowering
+still refuses the kernel's one-head ``(…, 1, head_dim)`` blocks.
 """
 from __future__ import annotations
 
@@ -55,12 +55,11 @@ def attention(
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     backend: str = "ref",
-    interpret: bool = True,
 ) -> jnp.ndarray:
     if backend == "ref":
         return mha_ref(q, k, v, causal=causal, window=window,
                        softcap=softcap)
     if backend == "pallas":
         return flash_attention(q, k, v, causal=causal, window=window,
-                               softcap=softcap, interpret=interpret)
+                               softcap=softcap)
     raise ValueError(f"unknown backend {backend!r}")

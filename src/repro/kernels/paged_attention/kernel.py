@@ -9,29 +9,40 @@ step) — exactly the avoidable off-chip traffic the RTC paper's
 access-management argument targets.  This kernel consumes the block
 table directly:
 
-* ``grid = (batch, kv_heads, n_logical_pages)`` with the page axis
-  innermost: TPU grids execute sequentially over the last dimension,
-  so the online-softmax running state (max, sum, accumulator) lives in
-  VMEM scratch across the pages of one (slot, kv_head) walk;
+* ``grid = (batch, n_logical_pages)`` with the page axis innermost:
+  TPU grids execute sequentially over the last dimension, so the
+  online-softmax running state (max, sum, accumulator — one row per
+  query head) lives in VMEM scratch across the pages of one slot's
+  walk;
+* one grid step takes one whole pool page, all KV heads: the K/V block
+  is ``(1, page_size, kv_heads, head_dim)``, whose last two dims are
+  the pool's own, which is what the TPU lowering requires of a block
+  (a one-head block ``(…, 1, head_dim)`` is refused);
 * the block table and per-slot positions ride in as **scalar
   prefetch** (:class:`~jax.experimental.pallas.tpu.PrefetchScalarGridSpec`):
   the K/V BlockSpec index maps read ``block[b, j]`` to DMA exactly one
   pool page HBM->VMEM per grid step — the gather never exists, pages
   stream through on-chip memory in block-table order;
+* q and out ride as ``(1, kv_heads, group, head_dim)`` blocks; the
+  page's ``[page_size, kv_heads, head_dim]`` rows are swapped to
+  head-major in VMEM and scored by one matmul batched over KV heads, so
+  each query head meets only its own GQA head's keys (no cross-head
+  work) and the running state is kept per head;
 * ring/append semantics, sliding windows, and softcap are enforced
-  in-kernel from ``pos`` alone: logical slot ``s`` of page ``j`` holds
-  absolute position ``pos - ((pos % cache_len - s) % cache_len)``
-  (negative = never written), matching ``attention._cache_positions``;
-  the partial tail page (``cache_len % page_size != 0``) masks its
-  out-of-range rows the same way;
+  in-kernel from ``pos`` alone: logical slot ``s`` holds absolute
+  position ``pos - ((pos % cache_len - s) % cache_len)`` (negative =
+  never written), matching ``attention._cache_positions``; the partial
+  tail page (``cache_len % page_size != 0``) masks its out-of-range
+  rows the same way;
 * pages with no valid row (unwritten ZERO pages, fully out-of-window
   pages) take a block-level early exit — no MXU cycles, mirroring the
   banded FLOP count of the jnp path;
 * fp32 accumulation; one query token per slot (decode).
 
-VMEM per step: q tile (g*hd*4) + K/V pages (2*page_size*hd*bytes) +
-scores (g*page_size*4) + scratch (g*(hd+2)*4) — tiny next to the
-flash-attention prefill tiles; the page size is the streaming quantum.
+VMEM per step: q tile (h*hd*4) + K/V pages (2*page_size*kv_heads*hd*
+bytes, double-buffered) + scores (h*page_size*4) + scratch
+(h*(hd+2)*4), with h = kv_heads * group query heads — the page size is
+the streaming quantum.
 """
 from __future__ import annotations
 
@@ -43,6 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 __all__ = ["paged_decode_attention"]
 
 _NEG_INF = -1e30
@@ -53,7 +66,8 @@ def _kernel(block_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             page_size: int, cache_len: int, n_lp: int,
             window: Optional[int], softcap: Optional[float]):
     ib = pl.program_id(0)
-    ij = pl.program_id(2)
+    ij = pl.program_id(1)
+    g, hd = q_ref.shape[2], q_ref.shape[3]
 
     @pl.when(ij == 0)
     def _init():
@@ -61,47 +75,53 @@ def _kernel(block_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Validity of this page's rows, from the slot position alone.
-    # Logical slot ls holds absolute position pos - ((pos%L - ls) % L);
-    # negative means never written (ZERO page reads land here), ls >=
-    # cache_len is the partial tail page's padding.
+    # Validity of this page's rows, from the slot position alone: logical
+    # slot ls holds absolute position pos - ((pos%L - ls) % L), written
+    # without a vector modulo as base + ls - (ls > cur) * L.  Negative
+    # means never written (ZERO page reads land here); ls >= cache_len is
+    # the partial tail page's padding.  Every KV head shares the mask.
     pos = pos_ref[ib]
-    ls = ij * page_size + jax.lax.iota(jnp.int32, page_size)
-    kv_pos = pos - ((pos % cache_len - ls) % cache_len)
+    cur = pos % cache_len
+    ls = ij * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (g, page_size), 1)
+    kv_pos = pos - cur + ls - jnp.where(ls > cur, cache_len, 0)
     valid = (ls < cache_len) & (kv_pos >= 0)
     if window is not None:
         valid &= kv_pos > pos - window
 
     @pl.when(jnp.any(valid))
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)           # [g, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # [page_size, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        hd = q.shape[-1]
-        s = (q @ k.T) * (hd ** -0.5)                  # [g, page_size]
+        q = q_ref[0].astype(jnp.float32)                        # [kvh, g, hd]
+        # [page, kvh, hd] -> [kvh, page, hd]: one batched matmul per
+        # page, batched over KV heads, scores each head's own rows only
+        k = jnp.swapaxes(k_ref[0].astype(jnp.float32), 0, 1)
+        v = jnp.swapaxes(v_ref[0].astype(jnp.float32), 0, 1)
+        s = jnp.einsum("hgd,hpd->hgp", q, k,
+                       preferred_element_type=jnp.float32) * (hd ** -0.5)
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
-        s = jnp.where(valid[None, :], s, _NEG_INF)
+        mask = jnp.broadcast_to(valid[None], s.shape)
+        s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                     # [kvh, g, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         m_ref[...] = m_new
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ v
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
+            "hgp,hpd->hgd", p, v, preferred_element_type=jnp.float32)
 
     @pl.when(ij == n_lp - 1)
     def _finish():
         l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe_l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cache_len", "window", "softcap", "interpret"),
+    static_argnames=("cache_len", "window", "softcap"),
 )
 def paged_decode_attention(
     q: jnp.ndarray,        # [b, kv_heads, group, head_dim] post-RoPE query
@@ -113,7 +133,6 @@ def paged_decode_attention(
     cache_len: int,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """One-token GQA attention reading K/V pages in place.
 
@@ -129,26 +148,25 @@ def paged_decode_attention(
         raise ValueError(
             f"block table covers {n_lp} pages x {page_size} rows "
             f"< cache_len {cache_len}")
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, n_lp),
+        grid=(b, n_lp),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda ib, ik, ij, blk, ps: (ib, ik, 0, 0)),
+            pl.BlockSpec((1, kvh, g, hd),
+                         lambda ib, ij, blk, ps: (ib, 0, 0, 0)),
             # THE point of the kernel: the index map resolves the block
             # table, so each grid step DMAs exactly one pool page.
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda ib, ik, ij, blk, ps: (blk[ib, ij], 0, ik, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda ib, ik, ij, blk, ps: (blk[ib, ij], 0, ik, 0)),
+            pl.BlockSpec((1, page_size, kvh, hd),
+                         lambda ib, ij, blk, ps: (blk[ib, ij], 0, 0, 0)),
+            pl.BlockSpec((1, page_size, kvh, hd),
+                         lambda ib, ij, blk, ps: (blk[ib, ij], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda ib, ik, ij, blk, ps: (ib, ik, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, g, hd),
+                               lambda ib, ij, blk, ps: (ib, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),        # running max
-            pltpu.VMEM((g,), jnp.float32),        # running sum
-            pltpu.VMEM((g, hd), jnp.float32),     # output accumulator
+            pltpu.VMEM((kvh, g, 1), jnp.float32),     # running max per head
+            pltpu.VMEM((kvh, g, 1), jnp.float32),     # running sum per head
+            pltpu.VMEM((kvh, g, hd), jnp.float32),    # output accumulator
         ],
     )
     kern = functools.partial(
@@ -157,6 +175,6 @@ def paged_decode_attention(
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=pallas_interpret(),
     )(block, pos, q, kp, vp)

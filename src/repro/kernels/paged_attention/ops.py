@@ -1,7 +1,8 @@
 """Public op: paged decode attention with backend dispatch.
 
 ``paged_attention(..., backend="pallas")`` runs the block-table Pallas
-kernel (interpret mode on CPU); ``backend="ref"`` runs the gather +
+kernel (compiled on a TPU, interpreted elsewhere —
+:func:`repro.kernels.pallas_interpret`); ``backend="ref"`` runs the gather +
 dense-softmax jnp oracle.  The model layer
 (``repro.models.attention.attn_decode``) calls this op when the serving
 engine selects ``decode_backend="pallas_paged"``; the oracle is the
@@ -39,10 +40,10 @@ def _pallas_cost(eqn) -> KernelCost:
     pos, q, kp, vp)``.  The scalar-prefetch operands (block, pos) and q
     (index map depends only on outer grid axes) stream once; the K/V
     page blocks are driven by the *data-dependent* block-table index
-    map, which the grid walks once per (batch, kv_head, logical_page) —
-    every logical page's physical page is DMA'd whole, which is exactly
-    ``TrafficModel.kv_page_read_bytes`` at full occupancy.  The output
-    block is written once per (batch, kv_head).
+    map, which the grid walks once per (batch, logical_page) — every
+    logical page's physical page is DMA'd whole, all KV heads at once,
+    which is exactly ``TrafficModel.kv_page_read_bytes`` at full
+    occupancy.  The output block is written once per batch slot.
     """
     block, pos, q, kp, vp = eqn.invars
     b, n_lp = block.aval.shape
@@ -71,7 +72,6 @@ def paged_attention(
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     backend: str = "pallas",
-    interpret: bool = True,
 ) -> jnp.ndarray:
     if backend == "ref":
         return paged_decode_ref(q, kp, vp, block, pos, cache_len=cache_len,
@@ -79,5 +79,5 @@ def paged_attention(
     if backend == "pallas":
         return paged_decode_attention(q, kp, vp, block, pos,
                                       cache_len=cache_len, window=window,
-                                      softcap=softcap, interpret=interpret)
+                                      softcap=softcap)
     raise ValueError(f"unknown backend {backend!r}")

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_interpret
+
 __all__ = ["schedule_pallas", "BLOCK_SLOTS"]
 
 BLOCK_SLOTS = 16 * 1024  # 64 KiB int32 out per block
@@ -33,8 +35,8 @@ def _kernel(scalars_ref, out_ref):
     out_ref[...] = jnp.where(nr <= na, jnp.ones_like(bits), bits)
 
 
-@functools.partial(jax.jit, static_argnames=("length", "interpret"))
-def schedule_pallas(start, na, nr, *, length: int, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("length",))
+def schedule_pallas(start, na, nr, *, length: int):
     """xfer bits for slots [start+1, start+length]; length % BLOCK == 0."""
     if length % BLOCK_SLOTS:
         raise ValueError(f"length {length} not a multiple of {BLOCK_SLOTS}")
@@ -45,5 +47,5 @@ def schedule_pallas(start, na, nr, *, length: int, interpret: bool = True):
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((BLOCK_SLOTS,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((length,), jnp.int32),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(scalars)

@@ -18,7 +18,7 @@ register_pallas_cost("kernels/rate_match/", uniform_cost)
 
 def schedule_bits(
     n_a: int, n_r: int, length: int, *, start: int = 0,
-    backend: str = "ref", interpret: bool = True,
+    backend: str = "ref",
 ):
     """xfer bits for slots [start+1, start+length] (int32 0/1 array).
 
@@ -33,6 +33,6 @@ def schedule_bits(
         return schedule_block_ref(start, length, na, nr)
     if backend == "pallas":
         pad = (-length) % BLOCK_SLOTS
-        bits = schedule_pallas(start, na, nr, length=length + pad, interpret=interpret)
+        bits = schedule_pallas(start, na, nr, length=length + pad)
         return bits[:length]
     raise ValueError(f"unknown backend {backend!r}")

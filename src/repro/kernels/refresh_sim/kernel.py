@@ -15,11 +15,11 @@ hardware-aligned (multiples of 128).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import pallas_interpret
 
 __all__ = ["window_update_masked_pallas", "window_update_pallas",
            "BLOCK_ROWS"]
@@ -70,7 +70,7 @@ def _kernel(scalars_ref, age_ref, age_out_ref, counts_ref):
     counts_ref[2] = jnp.sum(violation.astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def window_update_pallas(
     age: jnp.ndarray,
     acc_start,
@@ -80,8 +80,6 @@ def window_update_pallas(
     ref_lo,
     ref_hi,
     skip_accessed,
-    *,
-    interpret: bool = True,
 ):
     """Tiled window update. Returns (new_age, implicit, explicit, violations).
 
@@ -115,7 +113,7 @@ def window_update_pallas(
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((3 * n_blocks,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(scalars, age.astype(jnp.int32))
     counts = counts.reshape(n_blocks, 3).sum(axis=0)
     return new_age, counts[0], counts[1], counts[2]
@@ -164,7 +162,7 @@ def _masked_kernel(scalars_ref, age_ref, touched_ref, age_out_ref,
     counts_ref[2] = jnp.sum(violation.astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def window_update_masked_pallas(
     age: jnp.ndarray,
     touched: jnp.ndarray,
@@ -173,8 +171,6 @@ def window_update_masked_pallas(
     ref_lo,
     ref_hi,
     skip_accessed,
-    *,
-    interpret: bool = True,
 ):
     """Tiled trace-driven window update.
 
@@ -212,7 +208,7 @@ def window_update_masked_pallas(
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((3 * n_blocks,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(scalars, age.astype(jnp.int32), touched.astype(jnp.int32))
     counts = counts.reshape(n_blocks, 3).sum(axis=0)
     return new_age, counts[0], counts[1], counts[2]

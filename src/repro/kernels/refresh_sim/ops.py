@@ -2,7 +2,7 @@
 
 ``window_update(..., backend=)`` — affine-cursor access model;
 ``window_update_masked(..., backend=)`` — trace-driven bitmap model:
-  * ``"pallas"`` — the tiled TPU kernel (interpret=True on CPU);
+  * ``"pallas"`` — the tiled TPU kernel (interpreted off the TPU);
   * ``"ref"``    — the pure-jnp oracle (always available, used for
     allclose validation and as the fast path under jit on CPU).
 """
@@ -34,7 +34,6 @@ def window_update(
     skip_accessed,
     *,
     backend: str = "ref",
-    interpret: bool = True,
 ):
     """Returns (new_age, n_implicit, n_explicit, n_violations)."""
     if backend == "pallas":
@@ -47,7 +46,7 @@ def window_update(
             age_p = age
         new_age, imp, exp, vio = window_update_pallas(
             age_p, acc_start, acc_len, alloc_lo, alloc_hi, ref_lo, ref_hi,
-            skip_accessed, interpret=interpret,
+            skip_accessed,
         )
         return new_age[:n], imp, exp, vio
     if backend == "ref":
@@ -73,7 +72,6 @@ def window_update_masked(
     skip_accessed,
     *,
     backend: str = "ref",
-    interpret: bool = True,
 ):
     """Trace-driven window update (accessed set = per-row bitmap).
 
@@ -94,7 +92,7 @@ def window_update_masked(
             age_p, touched_p = age, touched
         new_age, imp, exp, vio = window_update_masked_pallas(
             age_p, touched_p, alloc_lo, alloc_hi, ref_lo, ref_hi,
-            skip_accessed, interpret=interpret,
+            skip_accessed,
         )
         return new_age[:n], imp, exp, vio
     if backend == "ref":

@@ -16,6 +16,7 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.compile_cache import use_compile_cache
 from repro.dist.sharding import ShardingPolicy
 from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models.transformer import TransformerLM
@@ -41,6 +42,7 @@ def main(argv=None) -> int:
                     default="auto")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.mesh == "auto":

@@ -302,8 +302,6 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
         and all(s == 1 for a, s in sizes.items()
                 if a not in policy.data_axes))
     if use_shard_map:
-        from jax.experimental.shard_map import shard_map
-
         bspec = policy.batch_spec
         logit_spec = P(bspec, None)
 
@@ -332,12 +330,12 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
                 new_cache, cache, is_leaf=_is_paged_node)
             return logits, new_cache
 
-        smap = shard_map(
+        smap = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspecs, cspecs, P(bspec),
                       P(bspec) if per_slot_pos else P()),
             out_specs=(logit_spec, cspecs),
-            check_rep=False)
+            check_vma=False)
 
         def decode_sm(params, cache, token, pos):
             logits, new_cache = smap(params, cache, token, pos)
@@ -546,9 +544,9 @@ class ServeEngine:
     view every step — bit-identical to contiguous serving but a full
     cache-length copy per layer per step; ``"pallas_paged"`` runs the
     :mod:`repro.kernels.paged_attention` kernel, which reads K/V pages
-    through the block-table indirection in place (interpret mode on
-    CPU).  Generations are identical across backends on every arch
-    (logits agree to accumulation-order tolerance; pinned in
+    through the block-table indirection in place (compiled on a TPU,
+    interpreted elsewhere).  Generations are identical across backends
+    on every arch (logits agree to accumulation-order tolerance; pinned in
     ``tests/test_paged_attention_kernel.py``), and telemetry accounts
     only true per-page reads on the kernel path — no materialized-view
     traffic.
@@ -884,6 +882,43 @@ class ServeEngine:
                 f"{self.max_len}); split the prompt or raise max_len")
         return p
 
+    # ------------------------------------------------------------ step API
+    def new_cache(self):
+        """An empty cache for ``max_batch`` slots (paged: every page of
+        the table is freed first)."""
+        if self._table is not None:
+            self._table.reset()
+            return self._table.init_cache()
+        return self.model.init_cache(self.max_batch, self.max_len)
+
+    def prefill_into(self, cache, slot: int, prompt: np.ndarray,
+                     keys: Optional[PrefixKeys] = None):
+        """Prefill one prompt, right-padded to its bucket, and write its
+        cache into batch slot ``slot``: the paged table admits it
+        (deduplicating against ``keys`` under prefix sharing), the
+        contiguous cache inserts it.  Returns ``(logits [1, vocab],
+        cache, one)`` with ``one`` the batch-1 prefilled cache."""
+        plen = prompt.shape[0]
+        bucket = self.buckets.bucket_for(plen)
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :plen] = prompt
+        logits, one = self._prefill(self.params, jnp.asarray(padded),
+                                    jnp.asarray([plen], jnp.int32))
+        if self._table is not None:
+            cache = self._table.admit(cache, one, slot, plen, keys)
+        else:
+            cache = self._insert(cache, one, jnp.asarray(slot, jnp.int32))
+        self.buckets.record(plen, bucket)
+        return logits, cache, one
+
+    def decode_step(self, cache, tokens: np.ndarray, positions: np.ndarray):
+        """One decode step over every slot: ``tokens`` and ``positions``
+        are ``[max_batch]`` int32.  A paged cache must already hold the
+        page each slot writes (``page_table.prepare_step``).  Returns
+        ``(logits [max_batch, vocab] f32, cache)``."""
+        return self._decode(self.params, cache, jnp.asarray(tokens),
+                            jnp.asarray(positions))
+
     # ----------------------------------------------------------------- serve
     def serve(self, prompts: Sequence[np.ndarray], max_new_tokens: int,
               temperature: float = 0.0, top_k: Optional[int] = None,
@@ -1017,11 +1052,7 @@ class ServeEngine:
             return self._sample(logits, keys, temps_, topks_, use_top_k)
 
         base = jax.random.key(seed)
-        if paged:
-            self._table.reset()
-            cache = self._table.init_cache()
-        else:
-            cache = self.model.init_cache(B, self.max_len)
+        cache = self.new_cache()
         slots: List[Optional[_Slot]] = [None] * B
         tok_vec = np.zeros((B,), np.int32)
         pos_vec = np.zeros((B,), np.int32)
@@ -1211,48 +1242,37 @@ class ServeEngine:
                             occupy(s, st, int(req.prompt[ktok]))
                             continue
                     pending.popleft()
-                    bucket = self.buckets.bucket_for(plen)
-                    padded = np.zeros((1, bucket), np.int32)
-                    padded[0, :plen] = req.prompt
                     t0 = time.perf_counter()
-                    logits, one = self._prefill(
-                        self.params, jnp.asarray(padded),
-                        jnp.asarray([plen], jnp.int32))
+                    logits, cache, one = self.prefill_into(
+                        cache, s, req.prompt, keys)
+                    if paged and sharing is not None:
+                        adm = self._table.last_admit
+                        if telemetry is not None:
+                            rec = getattr(telemetry,
+                                          "record_admit_shared", None)
+                            if rec is not None:
+                                rec(plen, adm["attached_layer_tokens"],
+                                    adm["total_layer_tokens"])
+                        if (sharing.memo_size > 0
+                                and self._table.fully_shareable(plen)
+                                and keys.whole not in memo):
+                            memo[keys.whole] = (
+                                np.asarray(logits),
+                                self._table.state_snapshot(one), plen)
+                            while len(memo) > sharing.memo_size:
+                                memo.pop(next(iter(memo)))
                     if paged:
-                        if sharing is not None:
-                            cache = self._table.admit(cache, one, s, plen,
-                                                      keys)
-                            adm = self._table.last_admit
-                            if telemetry is not None:
-                                rec = getattr(telemetry,
-                                              "record_admit_shared", None)
-                                if rec is not None:
-                                    rec(plen, adm["attached_layer_tokens"],
-                                        adm["total_layer_tokens"])
-                            if (sharing.memo_size > 0
-                                    and self._table.fully_shareable(plen)
-                                    and keys.whole not in memo):
-                                memo[keys.whole] = (
-                                    np.asarray(logits),
-                                    self._table.state_snapshot(one), plen)
-                                while len(memo) > sharing.memo_size:
-                                    memo.pop(next(iter(memo)))
-                        else:
-                            cache = self._table.admit(cache, one, s, plen)
                         note_pages(s)   # admission scatters the prefill
-                    else:
-                        cache = self._insert(cache, one,
-                                             jnp.asarray(s, jnp.int32))
                     key = self._keys(base, np.asarray([req.req_id], np.int32),
                                      np.zeros((1,), np.int32))
                     first = int(np.asarray(sample(
                         logits, key,
                         np.asarray([req.temperature], np.float32),
                         np.asarray([req.top_k], np.int32)))[0])
-                    self.buckets.record(plen, bucket)
                     if telemetry is not None:
                         telemetry.record_prefill(
-                            plen, time.perf_counter() - t0, padded_len=bucket)
+                            plen, time.perf_counter() - t0,
+                            padded_len=self.buckets.bucket_for(plen))
                     st = _Slot(req, pos=plen, first_token=first)
                     occupy(s, st, first)
                     if finished(st, first):
@@ -1271,9 +1291,7 @@ class ServeEngine:
             active = [s for s in range(B) if slots[s] is not None]
             ctx = [int(pos_vec[s]) + 1 for s in active]
             t0 = time.perf_counter()
-            logits, cache = self._decode(self.params, cache,
-                                         jnp.asarray(tok_vec),
-                                         jnp.asarray(pos_vec))
+            logits, cache = self.decode_step(cache, tok_vec, pos_vec)
             keys = self._keys(base, req_vec, emit_vec)
             toks = np.asarray(sample(logits, keys, jnp.asarray(temp_vec),
                                      jnp.asarray(topk_vec)))

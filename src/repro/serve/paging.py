@@ -21,7 +21,7 @@ architectures serve through the same allocator:
 * **free-on-retire** — a retired slot's pages return to the free list
   and its block-table rows point back at the DUMP page.
 * **offload / restore** — a preempted slot's resident pages are copied
-  to host memory (:func:`jax.device_put` to the CPU backend), freed on
+  to host memory (:func:`jax.device_get` into numpy), freed on
   device, and later restored bit-identically into freshly allocated
   pages (the block table re-targets; content is unchanged).  The
   engine accounts both directions as page-in/page-out traffic
@@ -1025,7 +1025,6 @@ class PageTable:
         keyed by stream index — what :meth:`admit_cached` writes back
         (state pages are never shared, so the full-prompt memo restores
         them through the same host round trip offload/restore uses)."""
-        host = jax.devices("cpu")[0]
         snap = {}
         for si, st in enumerate(self.streams):
             if not st.is_state:
@@ -1034,8 +1033,7 @@ class PageTable:
             grouped = st.where[0] == "groups"
             conv = oc.conv[:, 0] if grouped else oc.conv[0]
             h = oc.h[:, 0] if grouped else oc.h[0]
-            snap[si] = (np.asarray(jax.device_put(conv, host)),
-                        np.asarray(jax.device_put(h, host)))
+            snap[si] = jax.device_get((conv, h))
         return snap
 
     def admit_cached(self, cache, slot: int, plen: int, keys: PrefixKeys,
@@ -1235,10 +1233,9 @@ class PageTable:
         """Copy a slot's resident pages to host, free them on device.
 
         Returns ``(cache, payload)``.  The host copy is explicit
-        (``jax.device_put`` onto the CPU backend), so the content
-        round-trips through host memory, not a device alias.
+        (``jax.device_get`` into numpy), so the content round-trips
+        through host memory, not a device alias.
         """
-        host = jax.devices("cpu")[0]
         g = self.shard_of(slot)
         kv, state = {}, {}
         for si, st in enumerate(self.streams):
@@ -1251,16 +1248,14 @@ class PageTable:
             held = st.slot_pages.pop(slot)
             if st.is_state:
                 conv, h = self._fetch_jit[si](cache, jnp.asarray(held, jnp.int32))
-                state[si] = (np.asarray(jax.device_put(conv, host)),
-                             np.asarray(jax.device_put(h, host)))
+                state[si] = jax.device_get((conv, h))
                 st.free[g].append(held)
             else:
                 jdxs = sorted(held)
                 ids = jnp.asarray([held[j] for j in jdxs], jnp.int32)
                 kpg, vpg = self._fetch_jit[si](cache, ids)
                 kv[si] = (dict(zip(jdxs, range(len(jdxs)))),
-                          np.asarray(jax.device_put(kpg, host)),
-                          np.asarray(jax.device_put(vpg, host)))
+                          *jax.device_get((kpg, vpg)))
                 # the host payload owns a private copy of shared pages,
                 # so offload just drops this slot's references; restore
                 # later allocates fresh private pages

@@ -29,6 +29,7 @@ The 2-device GSPMD-gather detection lives in
 ``test_serve_multidevice.py`` (it needs a forced device count before
 jax initializes, hence a subprocess).
 """
+import itertools
 import json
 import os
 import pathlib
@@ -39,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 from jax.sharding import PartitionSpec
 
 from repro.analysis import decode_traffic_report, unit_from_engine
@@ -128,7 +128,6 @@ def test_walker_shard_map_bills_per_shard_times_shard_count():
     # device-local decode shape: the body gathers from its LOCAL pool
     # extent; per-shard bytes x the shard count (mesh axes not in
     # `auto`) is the exact global bill for evenly split pool operands
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import AbstractMesh
 
     pool = jnp.zeros((8, 4, 2), jnp.float32)      # 4 pages per shard
@@ -138,9 +137,9 @@ def test_walker_shard_map_bills_per_shard_times_shard_count():
         view = pool[idx]
         return (view * 2.0).sum()
 
-    smap = shard_map(f, mesh=AbstractMesh((("data", 2), ("model", 1))),
-                     in_specs=(PartitionSpec("data"), PartitionSpec()),
-                     out_specs=PartitionSpec(), check_rep=False)
+    smap = jax.shard_map(f, mesh=AbstractMesh((2, 1), ("data", "model")),
+                         in_specs=(PartitionSpec("data"), PartitionSpec()),
+                         out_specs=PartitionSpec(), check_vma=False)
     closed = jax.make_jaxpr(smap)(pool, idx)
     assert closed.jaxpr.eqns[0].primitive.name == "shard_map"
     res = walk_jaxpr(closed, [Taint("kv_pool", src=0), None])
@@ -300,7 +299,7 @@ def test_sharding_pass_silent_on_single_device():
 
 
 def test_hygiene_pass_flags_donation_constants_and_f64():
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x * 2.0)(jnp.ones(4, jnp.float64))
     art = _artifact(closed, [_kv()], expect=[True], donated=[False],
                     consts=(np.zeros(1 << 19, np.float32),))   # 2 MiB
@@ -379,7 +378,18 @@ def test_static_audit_matches_telemetry_exactly(arch, mode):
 
 
 # ------------------------------------------------------ HLO collective goldens
-_META = ('metadata={op_name="%s" source_file="%s" source_line=%d}')
+_FRAME_IDS = itertools.count(1)
+
+
+def _meta(op_name, source_file, source_line):
+    """Collective metadata naming a fresh stack frame, followed by the
+    module tables that resolve it (the parser merges tables by id)."""
+    i = next(_FRAME_IDS)
+    return (f'metadata={{op_name="{op_name}" stack_frame_id={i}}}\n'
+            f'FileNames\n{i} "{source_file}"\n'
+            f'FileLocations\n{i} {{file_name_id={i} function_name_id={i} '
+            f'line={source_line} end_line={source_line}}}\n'
+            f'StackFrames\n{i} {{file_location_id={i} parent_frame_id={i}}}\n')
 
 
 def _one(line, n_devices=None):
@@ -392,7 +402,7 @@ def test_all_gather_explicit_groups_and_ring_bytes():
         '  %all-gather.1 = f32[8,16]{1,0} all-gather(f32[2,16]{1,0} %p.0), '
         'channel_id=1, replica_groups={{0,1,2,3},{4,5,6,7}}, dimensions={0}, '
         'use_global_device_ids=true, '
-        + _META % ("jit(decode)/jit(main)/while/body/gather",
+        + _meta("jit(decode)/jit(main)/while/body/gather",
                    "/repo/src/repro/models/attention.py", 336))
     assert (c.kind, c.n_groups, c.group_size) == ("all-gather", 2, 4)
     assert c.result_bytes == 8 * 16 * 4 and c.operand_bytes == 2 * 16 * 4
@@ -407,7 +417,7 @@ def test_all_reduce_iota_groups_and_state_classification():
     c = _one(
         '  %all-reduce.2 = f32[4,4]{1,0} all-reduce(f32[4,4]{1,0} %x), '
         'channel_id=2, replica_groups=[2,4]<=[4,2]T(1,0), to_apply=%add, '
-        + _META % ("jit(decode)/jit(main)/while/body/gather",
+        + _meta("jit(decode)/jit(main)/while/body/gather",
                    "/repo/src/repro/models/rglru.py", 151))
     assert (c.kind, c.n_groups, c.group_size) == ("all-reduce", 2, 4)
     # ring all-reduce = reduce-scatter + all-gather: 2*in*(g-1)/g
@@ -432,7 +442,7 @@ def test_all_to_all_integer_payload_is_meta():
     c = _one(
         '  %all-to-all.4 = s32[4]{0} all-to-all(s32[4]{0} %idx), '
         'replica_groups={{0,1},{2,3}}, dimensions={0}, '
-        + _META % ("jit(decode)/jit(main)/while/body/all_to_all",
+        + _meta("jit(decode)/jit(main)/while/body/all_to_all",
                    "/repo/src/repro/models/attention.py", 100))
     assert (c.kind, c.n_groups, c.group_size) == ("all-to-all", 2, 2)
     assert c.wire_bytes_per_device() == 4 * 4 * 1 // 2
@@ -444,7 +454,7 @@ def test_collective_permute_wires_full_operand():
     c = _one(
         '  %collective-permute.5 = f32[2,8]{1,0} collective-permute('
         'f32[2,8]{1,0} %w), channel_id=5, source_target_pairs={{0,1},{1,0}}, '
-        + _META % ("jit(prefill)/while/body/slice",
+        + _meta("jit(prefill)/while/body/slice",
                    "/repo/src/repro/models/layers.py", 40))
     assert c.kind == "collective-permute"
     # point-to-point: the whole shard moves, group arithmetic is moot
@@ -492,7 +502,7 @@ def test_transformer_cache_write_sites_classify_as_cache_not_params():
     line = ('  %all-reduce.10 = f32[1,1,32,4,16]{4,3,2,1,0} all-reduce('
             'f32[1,1,32,4,16]{4,3,2,1,0} %dus), replica_groups={{0,1}}, '
             'to_apply=%add, '
-            + _META % ("jit(prefill)/jit(main)/while/body/"
+            + _meta("jit(prefill)/jit(main)/while/body/"
                        "dynamic_update_slice",
                        "/repo/src/repro/models/transformer.py", 382))
     c = _one(line)
@@ -507,14 +517,14 @@ def test_paged_kernel_collectives_get_their_own_ledger_site():
         '  %all-gather.11 = f32[40,8,2,4]{3,2,1,0} all-gather('
         'f32[5,8,2,4]{3,2,1,0} %kp), replica_groups={{0,1,2,3,4,5,6,7}}, '
         'dimensions={0}, '
-        + _META % ("jit(decode)/jit(paged_decode_attention)/while/body/"
+        + _meta("jit(decode)/jit(paged_decode_attention)/while/body/"
                    "dynamic_slice",
                    "/repo/src/repro/kernels/paged_attention/kernel.py", 157)
         + '\n'
         '  %all-gather.12 = f32[40,8,2,4]{3,2,1,0} all-gather('
         'f32[5,8,2,4]{3,2,1,0} %kp2), replica_groups={{0,1,2,3,4,5,6,7}}, '
         'dimensions={0}, '
-        + _META % ("jit(decode)/jit(paged_decode_attention)/while/body/"
+        + _meta("jit(decode)/jit(paged_decode_attention)/while/body/"
                    "dynamic_slice",
                    "/repo/src/repro/kernels/paged_attention/kernel.py", 157))
     rows = ledger_rows(parse_collectives(text), "pallas_paged")

@@ -108,6 +108,23 @@ def test_continuous_batching_temperature_schedule_independent(
         np.testing.assert_array_equal(a, b)
 
 
+def test_step_api_matches_serve(engine, mixed_prompts):
+    """new_cache / prefill_into / decode_step — the admission and step
+    path serve itself runs — reproduce serve's first two greedy tokens."""
+    prompts = mixed_prompts[:engine.max_batch]
+    want = engine.serve(prompts, 2)
+    cache = engine.new_cache()
+    tok = np.zeros((engine.max_batch,), np.int32)
+    pos = np.zeros((engine.max_batch,), np.int32)
+    for s, p in enumerate(prompts):
+        logits, cache, _ = engine.prefill_into(cache, s, p)
+        tok[s], pos[s] = int(np.argmax(np.asarray(logits[0]))), p.size
+    logits, _ = engine.decode_step(cache, tok, pos)
+    nxt = np.argmax(np.asarray(logits), -1)
+    for s, w in enumerate(want):
+        np.testing.assert_array_equal([tok[s], nxt[s]], w)
+
+
 def test_eos_retirement_frees_slot(engine, solo_engine, mixed_prompts):
     """Retiring on EOS mid-flight must not disturb other requests."""
     ref = engine.serve(mixed_prompts, 6)
