@@ -43,6 +43,7 @@ from repro.models.config import ModelConfig
 from repro.models.rglru import PagedRGLRUCache
 from repro.models.ssm import PagedSSMCache
 from repro.models.transformer import TransformerLM
+from repro.serve import spans
 from repro.serve.paging import (PagedCacheConfig, PageTable, PrefixKeys,
                                 prefix_page_keys, slot_floor)
 
@@ -178,7 +179,7 @@ def build_prefill_step(model: TransformerLM, mesh: Mesh,
                                      lengths=lengths)
 
         len_sh = NamedSharding(mesh, P(bspec))
-        return jax.jit(prefill_cached,
+        return jax.jit(spans.named("serve_prefill", prefill_cached),
                        in_shardings=(psh, tok_sh, len_sh)), psh, tok_sh
 
     def prefill(params, tokens):
@@ -354,7 +355,7 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
         fn = decode
 
     step = jax.jit(
-        fn,
+        spans.named("serve_decode", fn),
         in_shardings=(psh, csh, tok_sh, pos_sh),
         out_shardings=(NamedSharding(mesh, P(
             policy.batch_spec if batch > 1 else None, None)), csh),
@@ -659,13 +660,14 @@ class ServeEngine:
             # dynamic_update_slice, and without donation every admission
             # copied the full max_batch cache (the donation lint in
             # repro.analysis flagged exactly this executable).
-            self._insert = jax.jit(self._insert_cache,
-                                   out_shardings=self._cache_sh,
-                                   donate_argnums=(0,))
-        self._keys = jax.jit(jax.vmap(
+            self._insert = jax.jit(
+                spans.named("serve_insert", self._insert_cache),
+                out_shardings=self._cache_sh, donate_argnums=(0,))
+        self._keys = jax.jit(spans.named("serve_sample_keys", jax.vmap(
             lambda base, r, i: jax.random.fold_in(jax.random.fold_in(base, r), i),
-            in_axes=(None, 0, 0)))
-        self._sample = jax.jit(self._sample_fn, static_argnums=(4,))
+            in_axes=(None, 0, 0))))
+        self._sample = jax.jit(spans.named("serve_sample", self._sample_fn),
+                               static_argnums=(4,))
 
     def _resolve_shards(self) -> int:
         """Device-local pool extents for the paged cache geometry.
@@ -749,9 +751,9 @@ class ServeEngine:
                 decode_fn, _, cache_sh = build_decode_step(
                     self.model, lower_mesh, pol, batch=self.max_batch,
                     cache_len=self.max_len, per_slot_pos=True)
-                insert_fn = jax.jit(self._insert_cache,
-                                    out_shardings=cache_sh,
-                                    donate_argnums=(0,))
+                insert_fn = jax.jit(
+                    spans.named("serve_insert", self._insert_cache),
+                    out_shardings=cache_sh, donate_argnums=(0,))
         aparams = jax.eval_shape(
             lambda: self.model.init(jax.random.key(0)))
         B = self.max_batch
@@ -902,12 +904,15 @@ class ServeEngine:
         bucket = self.buckets.bucket_for(plen)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :plen] = prompt
-        logits, one = self._prefill(self.params, jnp.asarray(padded),
-                                    jnp.asarray([plen], jnp.int32))
-        if self._table is not None:
-            cache = self._table.admit(cache, one, slot, plen, keys)
-        else:
-            cache = self._insert(cache, one, jnp.asarray(slot, jnp.int32))
+        with spans.span("serve.prefill"):
+            logits, one = self._prefill(self.params, jnp.asarray(padded),
+                                        jnp.asarray([plen], jnp.int32))
+        with spans.span("page_table.insert"):
+            if self._table is not None:
+                cache = self._table.admit(cache, one, slot, plen, keys)
+            else:
+                cache = self._insert(cache, one,
+                                     jnp.asarray(slot, jnp.int32))
         self.buckets.record(plen, bucket)
         return logits, cache, one
 
@@ -957,7 +962,19 @@ class ServeEngine:
         front with the colliding indices named (two requests sharing an
         id would silently alias each other's sampling stream).  Outputs
         stay in *input* order regardless of the ids.
+
+        Each call records spans and counters (:mod:`repro.serve.spans`)
+        while a profiler trace is being collected.
         """
+        with spans.span("serve.call") as call:
+            return self._serve(prompts, max_new_tokens, temperature, top_k,
+                               seed, eos_id, telemetry, request_ids,
+                               call.start_ns)
+
+    def _serve(self, prompts, max_new_tokens, temperature, top_k, seed,
+               eos_id, telemetry, request_ids, t_entry: int):
+        """:meth:`serve`'s body; ``t_entry`` stamps the call's entry
+        (``time.perf_counter_ns``), where each queue wait starts."""
         if max_new_tokens < 0:
             raise ValueError("max_new_tokens must be >= 0")
         n_req = len(prompts)
@@ -1093,7 +1110,8 @@ class ServeEngine:
             outputs[out_index[st.req.req_id]] = np.asarray(st.out, np.int32)
             slots[s] = None
             if paged:
-                cache = self._table.release(cache, s)
+                with spans.span("page_table.release"):
+                    cache = self._table.release(cache, s)
 
         def finished(st: _Slot, token: int) -> bool:
             if st.emitted >= st.req.max_new_tokens:
@@ -1187,6 +1205,9 @@ class ServeEngine:
                         # logits (bit-identical: both round trips are
                         # exact).  No prefill executable runs.
                         pending.popleft()
+                        spans.add("serve.queue_wait", t_entry,
+                                  time.perf_counter_ns(), "serve.call",
+                                  req.req_id)
                         mlogits, msnap, _ = memo[keys.whole]
                         cache = self._table.admit_cached(
                             cache, s, plen, keys, msnap)
@@ -1223,6 +1244,9 @@ class ServeEngine:
                             # executables, tolerance-level (not
                             # bitwise) parity with the prefill path.
                             pending.popleft()
+                            spans.add("serve.queue_wait", t_entry,
+                                      time.perf_counter_ns(), "serve.call",
+                                      req.req_id)
                             ktok = k * self.paged.page_size
                             cache = self._table.attach_prefix(
                                 cache, s, keys, k)
@@ -1242,36 +1266,41 @@ class ServeEngine:
                             occupy(s, st, int(req.prompt[ktok]))
                             continue
                     pending.popleft()
-                    t0 = time.perf_counter()
-                    logits, cache, one = self.prefill_into(
-                        cache, s, req.prompt, keys)
-                    if paged and sharing is not None:
-                        adm = self._table.last_admit
-                        if telemetry is not None:
-                            rec = getattr(telemetry,
-                                          "record_admit_shared", None)
-                            if rec is not None:
-                                rec(plen, adm["attached_layer_tokens"],
-                                    adm["total_layer_tokens"])
-                        if (sharing.memo_size > 0
-                                and self._table.fully_shareable(plen)
-                                and keys.whole not in memo):
-                            memo[keys.whole] = (
-                                np.asarray(logits),
-                                self._table.state_snapshot(one), plen)
-                            while len(memo) > sharing.memo_size:
-                                memo.pop(next(iter(memo)))
-                    if paged:
-                        note_pages(s)   # admission scatters the prefill
-                    key = self._keys(base, np.asarray([req.req_id], np.int32),
-                                     np.zeros((1,), np.int32))
-                    first = int(np.asarray(sample(
-                        logits, key,
-                        np.asarray([req.temperature], np.float32),
-                        np.asarray([req.top_k], np.int32)))[0])
+                    with spans.span("serve.admit",
+                                    request=req.req_id) as adm_span:
+                        logits, cache, one = self.prefill_into(
+                            cache, s, req.prompt, keys)
+                        if paged and sharing is not None:
+                            adm = self._table.last_admit
+                            if telemetry is not None:
+                                rec = getattr(telemetry,
+                                              "record_admit_shared", None)
+                                if rec is not None:
+                                    rec(plen, adm["attached_layer_tokens"],
+                                        adm["total_layer_tokens"])
+                            if (sharing.memo_size > 0
+                                    and self._table.fully_shareable(plen)
+                                    and keys.whole not in memo):
+                                memo[keys.whole] = (
+                                    np.asarray(logits),
+                                    self._table.state_snapshot(one), plen)
+                                while len(memo) > sharing.memo_size:
+                                    memo.pop(next(iter(memo)))
+                        if paged:
+                            note_pages(s)   # admission scatters the prefill
+                        with spans.span("serve.first_token"):
+                            key = self._keys(
+                                base, np.asarray([req.req_id], np.int32),
+                                np.zeros((1,), np.int32))
+                            first = int(np.asarray(sample(
+                                logits, key,
+                                np.asarray([req.temperature], np.float32),
+                                np.asarray([req.top_k], np.int32)))[0])
+                    spans.add("serve.queue_wait", t_entry, adm_span.start_ns,
+                              "serve.call", req.req_id)
                     if telemetry is not None:
                         telemetry.record_prefill(
-                            plen, time.perf_counter() - t0,
+                            plen, adm_span.seconds,
                             padded_len=self.buckets.bucket_for(plen))
                     st = _Slot(req, pos=plen, first_token=first)
                     occupy(s, st, first)
@@ -1286,47 +1315,55 @@ class ServeEngine:
                     raise RuntimeError(
                         "serve stalled: no slot admissible — resident-page "
                         "budget cannot hold any pending/suspended request")
-            if paged:
-                grow()
-            active = [s for s in range(B) if slots[s] is not None]
-            ctx = [int(pos_vec[s]) + 1 for s in active]
-            t0 = time.perf_counter()
-            logits, cache = self.decode_step(cache, tok_vec, pos_vec)
-            keys = self._keys(base, req_vec, emit_vec)
-            toks = np.asarray(sample(logits, keys, jnp.asarray(temp_vec),
-                                     jnp.asarray(topk_vec)))
-            if telemetry is not None:
-                telemetry.record_decode(ctx, time.perf_counter() - t0)
-            if trace is not None:
-                # one trace step per decode step: every active slot's
-                # resident pages (allocate-on-write: residency == the
-                # context this step's KV sweep reads; the append lands
-                # in the same set after grow()) plus whatever moved
-                # between steps, with the weights re-streamed.
+            with spans.span("serve.step"):
+                if paged:
+                    with spans.span("page_table.grow"):
+                        grow()
+                    self._table.count_pages()
+                active = [s for s in range(B) if slots[s] is not None]
+                ctx = [int(pos_vec[s]) + 1 for s in active]
+                with spans.span("serve.decode") as dec_span:
+                    logits, cache = self.decode_step(cache, tok_vec, pos_vec)
+                    keys = self._keys(base, req_vec, emit_vec)
+                    toks = sample(logits, keys, jnp.asarray(temp_vec),
+                                  jnp.asarray(topk_vec))
+                with spans.span("serve.token_pull") as pull_span:
+                    toks = np.asarray(toks)
+                spans.count("serve.decode_steps")
+                if telemetry is not None:
+                    telemetry.record_decode(
+                        ctx, dec_span.seconds + pull_span.seconds)
+                if trace is not None:
+                    # one trace step per decode step: every active slot's
+                    # resident pages (allocate-on-write: residency == the
+                    # context this step's KV sweep reads; the append lands
+                    # in the same set after grow()) plus whatever moved
+                    # between steps, with the weights re-streamed.
+                    for s in active:
+                        note_pages(s)
+                    trace.record_step(pending_pages, param_read=True)
+                    pending_pages.clear()
                 for s in active:
-                    note_pages(s)
-                trace.record_step(pending_pages, param_read=True)
-                pending_pages.clear()
-            for s in active:
-                st = slots[s]
-                if st.feed is not None and st.feed:
-                    # suffix feed: this step consumed a prompt token;
-                    # its sampled draw is discarded (emit_vec stays 0,
-                    # so the eventual first token still uses sampling
-                    # key (request, 0)) and the next prompt token rides
-                    # the next step.
+                    st = slots[s]
+                    if st.feed is not None and st.feed:
+                        # suffix feed: this step consumed a prompt token;
+                        # its sampled draw is discarded (emit_vec stays 0,
+                        # so the eventual first token still uses sampling
+                        # key (request, 0)) and the next prompt token
+                        # rides the next step.
+                        st.pos += 1
+                        tok_vec[s], pos_vec[s] = st.feed.popleft(), st.pos
+                        continue
+                    st.feed = None   # last fed step falls through: its
+                    token = int(toks[s])   # draw IS the first emitted token
+                    st.out.append(token)
+                    st.emitted += 1
                     st.pos += 1
-                    tok_vec[s], pos_vec[s] = st.feed.popleft(), st.pos
-                    continue
-                st.feed = None   # last fed step falls through: its
-                token = int(toks[s])   # draw IS the first emitted token
-                st.out.append(token)
-                st.emitted += 1
-                st.pos += 1
-                tok_vec[s], pos_vec[s], emit_vec[s] = token, st.pos, st.emitted
-                if finished(st, token):
-                    retire(s)
-            admit()
+                    tok_vec[s], pos_vec[s], emit_vec[s] = (token, st.pos,
+                                                           st.emitted)
+                    if finished(st, token):
+                        retire(s)
+                admit()
         if trace is not None and pending_pages:
             # trailing page moves with no decode step after them (e.g. a
             # final admission that retired on its prefill token)

@@ -74,6 +74,7 @@ original single-pool allocator, bit for bit.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Dict, List, Optional, Tuple
 
@@ -87,6 +88,7 @@ from repro.models.attention import (DUMP_PAGE, RESERVED_PAGES, ZERO_PAGE,
 from repro.models.rglru import PagedRGLRUCache, RGLRUCache
 from repro.models.ssm import PagedSSMCache, SSMCache
 from repro.models.transformer import TransformerLM
+from repro.serve import spans
 
 __all__ = ["PagedCacheConfig", "PageTable", "PagePayload", "PageTableError",
            "PrefixSharingConfig", "PrefixKeys", "prefix_page_keys",
@@ -514,22 +516,28 @@ class PageTable:
         kw = {"donate_argnums": (0,)}
         if cache_shardings is not None:
             kw["out_shardings"] = cache_shardings
-        self._insert_jit = jax.jit(self._insert_fn, **kw)
-        self._release_jit = jax.jit(self._release_fn, **kw)
-        self._restore_jit = jax.jit(self._restore_fn, **kw)
-        self._attach_jit = jax.jit(self._attach_fn, **kw)
+        # stable program names (``jit_page_table_*``) in a trace
+        named = spans.named
+        self._insert_jit = jax.jit(named("page_table_insert",
+                                         self._insert_fn), **kw)
+        self._release_jit = jax.jit(named("page_table_release",
+                                          self._release_fn), **kw)
+        self._restore_jit = jax.jit(named("page_table_restore",
+                                          self._restore_fn), **kw)
+        self._attach_jit = jax.jit(named("page_table_attach",
+                                         self._attach_fn), **kw)
         self._assign_jit = {
-            si: jax.jit(lambda c, s, j, p, _si=si: self._assign_fn(_si, c, s, j, p),
-                        **kw)
+            si: jax.jit(named(f"page_table_assign_{si}",
+                              functools.partial(self._assign_fn, si)), **kw)
             for si, st in enumerate(self.streams) if not st.is_state}
         self._fork_jit = {
-            si: jax.jit(lambda c, s, src, dst, j, _si=si:
-                        self._fork_fn(_si, c, s, src, dst, j), **kw)
+            si: jax.jit(named(f"page_table_fork_{si}",
+                              functools.partial(self._fork_fn, si)), **kw)
             for si, st in enumerate(self.streams) if not st.is_state}
         self._fetch_jit = {
-            si: (jax.jit(lambda c, pid, _si=si: self._fetch_state_fn(_si, c, pid))
-                 if st.is_state else
-                 jax.jit(lambda c, ids, _si=si: self._fetch_kv_fn(_si, c, ids)))
+            si: jax.jit(named(f"page_table_fetch_{si}", functools.partial(
+                self._fetch_state_fn if st.is_state else self._fetch_kv_fn,
+                si)))
             for si, st in enumerate(self.streams)}
 
     def reset(self) -> None:
@@ -1216,6 +1224,7 @@ class PageTable:
                     jnp.asarray(pid, jnp.int32), jnp.asarray(dst, jnp.int32),
                     jnp.asarray(jdx, jnp.int32))
                 self.stats["cow_forks"] += 1
+                spans.count("page_table.forks")
                 if cow_events is not None:
                     cow_events.append(
                         (si, self.page_size * self._stream_layers(st)))
@@ -1227,7 +1236,21 @@ class PageTable:
             cache = self._assign_jit[si](
                 cache, jnp.asarray(slot, jnp.int32),
                 jnp.asarray(jdx, jnp.int32), jnp.asarray(pid, jnp.int32))
+            spans.count("page_table.assigns")
         return cache, True
+
+    def count_pages(self) -> None:
+        """Add the KV pages live slots hold now (a shared page once: the
+        pool less its free lists) and the pool's KV pages to the span
+        counters ``page_table.pages_live`` and ``page_table.pages_pool``;
+        the engine calls it once per decode step."""
+        if not spans.recording():
+            return
+        kv = [st for st in self.streams if not st.is_state]
+        pool = self.resident_pages * len(kv)
+        spans.count("page_table.pages_live",
+                    pool - sum(len(f) for st in kv for f in st.free))
+        spans.count("page_table.pages_pool", pool)
 
     def offload(self, cache, slot: int, tokens: int):
         """Copy a slot's resident pages to host, free them on device.
