@@ -189,7 +189,8 @@ def phase_kernel_vs_ref(engine, prompts, seed):
     cfg = engine.model.cfg
     cache, _, pos = admit(engine, prompts)
     node = cache["groups"][0]                     # stacked over layers
-    kp, vp, block = node.kp[-1], node.vp[-1], node.block[-1]
+    kp, vp, block = node.kp, node.vp, node.block[-1]
+    last = jnp.asarray(kp.shape[0] - 1, jnp.int32)
     kvh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     q = jax.random.normal(jax.random.key(seed),
                           (engine.max_batch, kvh, cfg.n_heads // kvh, hd),
@@ -197,9 +198,11 @@ def phase_kernel_vs_ref(engine, prompts, seed):
     # query the last prompt position: every slot's row is written
     qpos = jnp.asarray(jnp.maximum(pos - 1, 0), jnp.int32)
     kw = dict(cache_len=node.cache_len)
-    pal = paged_attention(q, kp, vp, block, qpos, backend="pallas", **kw)
+    pal = paged_attention(q, kp, vp, block, qpos, last, backend="pallas",
+                          **kw)
     with jax.default_matmul_precision("highest"):
-        ref = paged_attention(q, kp, vp, block, qpos, backend="ref", **kw)
+        ref = paged_attention(q, kp, vp, block, qpos, last, backend="ref",
+                              **kw)
     n = len(prompts)
     err = _rel_err(pal.block_until_ready()[:n], ref.block_until_ready()[:n])
     _check(err <= KERNEL_TOL, f"kernel vs ref: {err:.3e} > {KERNEL_TOL}")

@@ -26,9 +26,10 @@ rules mirror how XLA treats the equations:
   buffer is never billed as a fresh full-size write at the jaxpr
   boundary.
 * **scan** multiplies its body's bytes by the trip count; cache leaves
-  ride through as xs/ys slices keeping their taint.  Stacking the ys
-  back is billed at zero — XLA aliases donated loop buffers in place,
-  an assumption the donation hygiene lint guards.
+  ride through keeping their taint, as xs/ys slices or, for the paged
+  KV pools the decode step updates in place, as carry.  Stacking the
+  ys back is billed at zero — XLA aliases donated loop buffers in
+  place, an assumption the donation hygiene lint guards.
 * **pallas_call** is opaque: a registered per-kernel cost handler
   (:mod:`repro.analysis.costs`) supplies per-operand bytes, which are
   classified by operand taint.  A missing handler is itself reported.

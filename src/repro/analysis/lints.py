@@ -137,7 +137,7 @@ def sharding_pass(unit: AuditUnit) -> List[Finding]:
                                             art.closed_jaxpr.jaxpr.invars)):
             if seed is None or seed.cls != "kv_pool":
                 continue
-            page_dim = len(var.aval.shape) - 4     # [(G,) pages, P, kvh, hd]
+            page_dim = len(var.aval.shape) - 3     # [(G,) pages, P, kvh*hd]
             n_pages = var.aval.shape[page_dim]
             if n_pages % data_size:
                 continue                           # legitimately replicated

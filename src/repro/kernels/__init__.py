@@ -37,19 +37,30 @@ buys nothing.
 The kernel removes the copy:
 
 * **Grid layout** — ``(batch_slot, logical_page)`` with the page axis
-  innermost; one step takes one whole pool page, all KV heads (block
-  ``(1, page_size, kv_heads, head_dim)`` — the TPU lowering requires a
-  block's last two dims to be tile multiples or the array's own, so a
-  one-head block is refused), scored by one matmul batched over KV
-  heads against the ``(1, kv_heads, group, head_dim)`` query block.
+  innermost; one step takes one whole pool page of one layer, all KV
+  heads (block ``(None, None, page_size, kv_heads*head_dim)`` — the TPU
+  lowering requires a block's last two dims to be tile multiples or the
+  array's own).  The pool row is lane-dense, every KV head side by
+  side, so the query is laid out block-diagonally in VMEM and one
+  matmul scores every query head against its own KV head's lanes only.
   TPU grids are sequential over the last dimension, so the
   online-softmax state (running max, running sum, fp32 output
   accumulator, per query head) lives in VMEM scratch across one slot's
   page walk.
-* **Block-table index map** — the block table and per-slot positions
-  are scalar-prefetch operands
+* **Pool layout** — ``[layers, n_pages, page_size,
+  kv_heads*head_dim]``.  With ``head_dim`` (64 for qwen) as the minor
+  axis the TPU's default layout put the page axis minor-most: every
+  page write strode the whole pool, and the decode step copied each
+  layer's pool into and out of the kernel's row-major layout.  A minor
+  axis of ``kv_heads*head_dim`` fills whole lanes, so the default
+  layout is row-major and a page is contiguous.  The decode step
+  carries the stacked pools through its layer scan and the kernel takes
+  the layer as a third scalar-prefetch operand, so no pool is sliced,
+  copied or restacked (guarded by ``tests/test_tpu_compile.py``).
+* **Block-table index map** — the block table, per-slot positions and
+  the layer index are scalar-prefetch operands
   (:class:`~jax.experimental.pallas.tpu.PrefetchScalarGridSpec`); the
-  K/V BlockSpec index maps evaluate ``block[b, j]`` so the pipeline
+  K/V BlockSpec index maps evaluate ``(layer, block[b, j])`` so the pipeline
   DMAs exactly one pool page HBM->VMEM per grid step, in block-table
   order.  Ring/append validity, sliding windows, softcap, and the
   partial tail page are reconstructed in-kernel from ``pos`` alone

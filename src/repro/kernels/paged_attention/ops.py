@@ -37,24 +37,27 @@ def _pallas_cost(eqn) -> KernelCost:
     """HBM bytes of one kernel launch, from the equation's operand avals.
 
     Operand order is fixed by ``kernel.py``'s pallas_call: ``(block,
-    pos, q, kp, vp)``.  The scalar-prefetch operands (block, pos) and q
-    (index map depends only on outer grid axes) stream once; the K/V
-    page blocks are driven by the *data-dependent* block-table index
-    map, which the grid walks once per (batch, logical_page) — every
-    logical page's physical page is DMA'd whole, all KV heads at once,
-    which is exactly ``TrafficModel.kv_page_read_bytes`` at full
+    pos, layer, q, kp, vp)``, with ``kp``/``vp`` the stacked
+    ``[layers, n_pages, page_size, kv_heads*head_dim]`` pools.  The
+    scalar-prefetch operands (block, pos, layer) and q (index map
+    depends only on outer grid axes) stream once; the K/V page blocks
+    are driven by the *data-dependent* block-table index map, which the
+    grid walks once per (batch, logical_page) — every logical page's
+    physical page of the one layer is DMA'd whole, all KV heads at
+    once, which is exactly ``TrafficModel.kv_page_read_bytes`` at full
     occupancy.  The output block is written once per batch slot.
     """
-    block, pos, q, kp, vp = eqn.invars
+    block, pos, layer, q, kp, vp = eqn.invars
     b, n_lp = block.aval.shape
-    _, page, kvh, hd = kp.aval.shape
-    page_read = b * kvh * n_lp * page * hd * int(kp.aval.dtype.itemsize)
+    page, f = kp.aval.shape[-2:]
+    page_read = b * n_lp * page * f * int(kp.aval.dtype.itemsize)
 
     def nbytes(v):
         return int(v.aval.size) * int(v.aval.dtype.itemsize)
 
     return KernelCost(
-        reads=(nbytes(block), nbytes(pos), nbytes(q), page_read, page_read),
+        reads=(nbytes(block), nbytes(pos), nbytes(layer), nbytes(q),
+               page_read, page_read),
         writes=tuple(nbytes(v) for v in eqn.outvars))
 
 
@@ -63,10 +66,11 @@ register_pallas_cost("kernels/paged_attention/", _pallas_cost)
 
 def paged_attention(
     q: jnp.ndarray,        # [b, kv_heads, group, head_dim]
-    kp: jnp.ndarray,       # [n_pages, page_size, kv_heads, head_dim]
+    kp: jnp.ndarray,       # [(layers,) n_pages, page_size, kv_heads*head_dim]
     vp: jnp.ndarray,
     block: jnp.ndarray,    # [b, n_logical_pages] int32
     pos: jnp.ndarray,      # [b] int32
+    layer=None,            # [] int32 layer of a stacked pool; None if 3-D
     *,
     cache_len: int,
     window: Optional[int] = None,
@@ -74,10 +78,11 @@ def paged_attention(
     backend: str = "pallas",
 ) -> jnp.ndarray:
     if backend == "ref":
-        return paged_decode_ref(q, kp, vp, block, pos, cache_len=cache_len,
-                                window=window, softcap=softcap)
+        return paged_decode_ref(q, kp, vp, block, pos, layer,
+                                cache_len=cache_len, window=window,
+                                softcap=softcap)
     if backend == "pallas":
-        return paged_decode_attention(q, kp, vp, block, pos,
+        return paged_decode_attention(q, kp, vp, block, pos, layer,
                                       cache_len=cache_len, window=window,
                                       softcap=softcap)
     raise ValueError(f"unknown backend {backend!r}")

@@ -26,10 +26,11 @@ __all__ = ["paged_decode_ref"]
 
 def paged_decode_ref(
     q: jnp.ndarray,        # [b, kv_heads, group, head_dim] post-RoPE query
-    kp: jnp.ndarray,       # [n_pages, page_size, kv_heads, head_dim] pool
+    kp: jnp.ndarray,       # [(layers,) n_pages, page_size, kv_heads*head_dim]
     vp: jnp.ndarray,
     block: jnp.ndarray,    # [b, n_logical_pages] int32 pool page ids
     pos: jnp.ndarray,      # [b] int32 absolute position being decoded
+    layer=None,            # [] int32 layer of a stacked pool; None if 3-D
     *,
     cache_len: int,
     window: Optional[int] = None,
@@ -38,9 +39,11 @@ def paged_decode_ref(
     """Gather + one-token GQA attention. Returns [b, kv_heads, group, hd]."""
     b, kvh, g, hd = q.shape
     n_lp = block.shape[1]
+    if layer is not None:
+        kp, vp = kp[layer], vp[layer]
     page_size = kp.shape[1]
-    k = kp[block].reshape((b, n_lp * page_size) + kp.shape[2:])[:, :cache_len]
-    v = vp[block].reshape((b, n_lp * page_size) + vp.shape[2:])[:, :cache_len]
+    k = kp[block].reshape(b, n_lp * page_size, kvh, hd)[:, :cache_len]
+    v = vp[block].reshape(b, n_lp * page_size, kvh, hd)[:, :cache_len]
 
     # Absolute position held by each ring slot (-1 if never written):
     # slot s holds the newest p <= pos with p % cache_len == s.

@@ -261,22 +261,30 @@ class PagedKVCache:
     fixed-size pages of a shared pool, indirected per batch slot through
     ``block``.  Pages are allocated on first write and freed on retire
     by the serving-side :class:`repro.serve.paging.PageTable`; the model
-    layer only reads/writes through the indirection.  ``page_size`` and
-    ``cache_len`` are static (pytree aux data), so one lowered decode
-    step serves any block-table state.
+    layer only reads/writes through the indirection.  ``page_size``,
+    ``cache_len`` and ``kv_heads`` are static (pytree aux data), so one
+    lowered decode step serves any block-table state.
+
+    A pool row holds every KV head side by side, ``kv_heads*head_dim``
+    wide: that minor axis fills the TPU's 128 lanes where ``head_dim``
+    alone may not, so the chip lays the pool out page-major and a page
+    is one contiguous block (see :mod:`repro.kernels.paged_attention`).
+    Stacked over layer groups the pools are ``[G, n_pages, page_size,
+    kv_heads*head_dim]``, and the decode step updates them in place.
     """
 
-    kp: jnp.ndarray       # [n_pages, page_size, kv_heads, head_dim] pool
+    kp: jnp.ndarray       # [n_pages, page_size, kv_heads*head_dim] pool
     vp: jnp.ndarray
     block: jnp.ndarray    # [b, n_logical_pages] int32 pool page ids
     length: jnp.ndarray   # [] int32 — high-water mark (as KVCache)
     page_size: int = dataclasses.field(metadata=dict(static=True))
     cache_len: int = dataclasses.field(metadata=dict(static=True))
+    kv_heads: int = dataclasses.field(metadata=dict(static=True))
 
 
 jax.tree_util.register_dataclass(
     PagedKVCache, data_fields=("kp", "vp", "block", "length"),
-    meta_fields=("page_size", "cache_len"))
+    meta_fields=("page_size", "cache_len", "kv_heads"))
 
 
 def n_logical_pages(cache_len: int, page_size: int) -> int:
@@ -295,30 +303,32 @@ def init_paged_kv_cache(cfg: ModelConfig, batch: int, cache_len: int,
     never writes across the data axis (``shards == 1``: plain DUMP)."""
     kvh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     n_lp = n_logical_pages(cache_len, page_size)
-    shape = (n_pages, page_size, kvh, hd)
+    shape = (n_pages, page_size, kvh * hd)
     dump = _shard_dump_ids(batch, n_pages, shards)
     return PagedKVCache(
         kp=jnp.zeros(shape, dtype), vp=jnp.zeros(shape, dtype),
         block=jnp.broadcast_to(dump[:, None], (batch, n_lp)),
         length=jnp.zeros((), jnp.int32),
-        page_size=page_size, cache_len=cache_len)
+        page_size=page_size, cache_len=cache_len, kv_heads=kvh)
 
 
-def paged_kv_view(cache: PagedKVCache):
+def paged_kv_view(cache: PagedKVCache, layer=None):
     """Gather the block-table indirection into the contiguous
     ``[b, cache_len, kv_heads, head_dim]`` layout :class:`KVCache`
     stores directly.  Values land in the exact same slot order, which is
-    what makes paged attention bit-identical to contiguous attention."""
+    what makes paged attention bit-identical to contiguous attention.
+    ``layer`` picks one layer of pools stacked over layer groups (one
+    gather from the stacked pool, which is never sliced whole)."""
     b, n_lp = cache.block.shape
-    k = cache.kp[cache.block].reshape(
-        (b, n_lp * cache.page_size) + cache.kp.shape[2:])
-    v = cache.vp[cache.block].reshape(
-        (b, n_lp * cache.page_size) + cache.vp.shape[2:])
+    at = () if layer is None else (layer,)
+    shape = (b, n_lp * cache.page_size, cache.kv_heads, -1)
+    k = cache.kp[(*at, cache.block)].reshape(shape)
+    v = cache.vp[(*at, cache.block)].reshape(shape)
     return k[:, :cache.cache_len], v[:, :cache.cache_len]
 
 
 def attn_decode(params, cfg: ModelConfig, x, cache, pos, kind: str,
-                backend: str = "gather"):
+                backend: str = "gather", layer=None):
     """One-token decode. x: [b, 1, d]; pos: [] or [b] int32 absolute
     position (vector = per-slot positions for continuous batching).
 
@@ -337,6 +347,13 @@ def attn_decode(params, cfg: ModelConfig, x, cache, pos, kind: str,
     materialized.  The kernel mirrors the gather math up to
     accumulation order (online softmax over pages), so generations are
     identical while logits agree to interpret-mode tolerance.
+
+    ``layer`` (paged caches only): the cache's pools are stacked over
+    layer groups (``[G, n_pages, page_size, kv_heads*head_dim]``) and
+    this layer is group ``layer`` of them.  The new row is written into
+    the stacked pool and the attention reads it there, so a decode step
+    that carries the stacked pools through its layer loop updates them
+    in place; the returned cache holds the whole stacked pools.
     """
     if backend not in ("gather", "pallas_paged"):
         raise ValueError(f"unknown decode backend {backend!r}")
@@ -366,8 +383,9 @@ def attn_decode(params, cfg: ModelConfig, x, cache, pos, kind: str,
             pid = cache.block[jnp.arange(b), jdx]
         else:
             pid = cache.block[:, jdx]
-        kp = cache.kp.at[pid, off].set(k_new[:, 0])
-        vp = cache.vp.at[pid, off].set(v_new[:, 0])
+        at = () if layer is None else (layer,)
+        kp = cache.kp.at[(*at, pid, off)].set(k_new[:, 0].reshape(b, -1))
+        vp = cache.vp.at[(*at, pid, off)].set(v_new[:, 0].reshape(b, -1))
         new_cache = dataclasses.replace(cache, kp=kp, vp=vp)
         if backend == "pallas_paged":
             # the kernel walks the block table in place; no logical view
@@ -377,7 +395,7 @@ def attn_decode(params, cfg: ModelConfig, x, cache, pos, kind: str,
             posb = pos if per_slot else jnp.full((b,), pos, jnp.int32)
             out = paged_attention(
                 q[:, 0].reshape(b, kvh, g, hd), kp, vp, new_cache.block,
-                posb, cache_len=cache_len,
+                posb, layer, cache_len=cache_len,
                 window=(cfg.window_size if kind == "local" else None),
                 softcap=cfg.attn_softcap)
             out = out.reshape(b, 1, cfg.n_heads * hd)
@@ -385,7 +403,7 @@ def attn_decode(params, cfg: ModelConfig, x, cache, pos, kind: str,
             new_cache = dataclasses.replace(
                 new_cache, length=new_len.astype(jnp.int32))
             return out @ params["wo"], new_cache
-        k, v = paged_kv_view(new_cache)
+        k, v = paged_kv_view(new_cache, layer)
     elif per_slot:
         rows = jnp.arange(b)
         k = cache.k.at[rows, slot].set(k_new[:, 0])

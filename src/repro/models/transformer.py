@@ -15,6 +15,7 @@ API (functional, dict pytrees):
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Tuple
 
@@ -407,11 +408,13 @@ class TransformerLM:
         logits = self._unembed(params, last)[:, 0, :]
         return logits, cache
 
-    def _block_decode(self, kind, p, c, x, pos, backend: str = "gather"):
+    def _block_decode(self, kind, p, c, x, pos, backend: str = "gather",
+                      layer=None):
         cfg = self.cfg
         if kind in ("global", "local"):
             h, c = attn.attn_decode(p["attn"], cfg, rmsnorm(p["ln1"], x),
-                                    c, pos, kind, backend=backend)
+                                    c, pos, kind, backend=backend,
+                                    layer=layer)
             x = x + h
             hh = rmsnorm(p["ln2"], x)
             if cfg.n_experts:
@@ -441,6 +444,13 @@ class TransformerLM:
         kernel of :mod:`repro.kernels.paged_attention`; no gather).
 
         Returns (logits [b, vocab] f32, new_cache).
+
+        The KV pools of paged attention layers, stacked over groups,
+        ride through the depth loop as its *carry*: layer ``g`` writes
+        its new row into group ``g`` of the stacked pool and reads it
+        there, so the pools are updated in place and never sliced or
+        restacked.  Everything else (params, block tables, contiguous
+        caches, recurrent state) is sliced per group as before.
         """
         cfg = self.cfg
         if token.ndim == 2:  # frontend embedding
@@ -448,27 +458,50 @@ class TransformerLM:
         else:
             x = self._embed(params, token[:, None])
 
-        def body(x, inputs):
-            gp, gc = inputs
-            new_cs = []
+        def body(carry, inputs):
+            x, pools = carry
+            gp, gc, g = inputs
+            new_cs, new_pools = [], []
             for i, kind in enumerate(cfg.attn_pattern):
-                x, nc = self._block_decode(kind, gp[i], gc[i], x, pos,
-                                           backend=decode_backend)
+                pool = pools[i]
+                if pool is None:
+                    x, nc = self._block_decode(kind, gp[i], gc[i], x, pos,
+                                               backend=decode_backend)
+                else:
+                    c = dataclasses.replace(gc[i], kp=pool[0], vp=pool[1])
+                    x, nc = self._block_decode(kind, gp[i], c, x, pos,
+                                               backend=decode_backend,
+                                               layer=g)
+                    pool = (nc.kp, nc.vp)
+                    nc = dataclasses.replace(nc, kp=None, vp=None)
                 new_cs.append(nc)
-            return x, tuple(new_cs)
+                new_pools.append(pool)
+            return (x, tuple(new_pools)), tuple(new_cs)
 
-        gcache = cache["groups"]
+        paged = tuple(isinstance(c, attn.PagedKVCache)
+                      for c in cache["groups"])
+        pools = tuple((c.kp, c.vp) if p else None
+                      for c, p in zip(cache["groups"], paged))
+        rest = tuple(dataclasses.replace(c, kp=None, vp=None) if p else c
+                     for c, p in zip(cache["groups"], paged))
+        carry = (x, pools)
         if self.unroll:
             new_groups = []
             for g in range(cfg.n_groups):
                 gp = jax.tree.map(lambda l: l[g], params["blocks"])
-                gc = jax.tree.map(lambda l: l[g], gcache)
-                x, nc = body(x, (gp, gc))
+                gc = jax.tree.map(lambda l: l[g], rest)
+                carry, nc = body(carry, (gp, gc, g))
                 new_groups.append(nc)
-            new_gcache = jax.tree.map(
+            new_rest = jax.tree.map(
                 lambda *leaves: jnp.stack(leaves), *new_groups)
         else:
-            x, new_gcache = jax.lax.scan(body, x, (params["blocks"], gcache))
+            carry, new_rest = jax.lax.scan(
+                body, carry, (params["blocks"], rest,
+                              jnp.arange(cfg.n_groups, dtype=jnp.int32)))
+        x, pools = carry
+        new_gcache = tuple(
+            c if p is None else dataclasses.replace(c, kp=p[0], vp=p[1])
+            for c, p in zip(new_rest, pools))
         new_tail = []
         for i, kind in enumerate(cfg.pattern_tail):
             x, nc = self._block_decode(kind, params["tail"][i],

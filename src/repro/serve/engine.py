@@ -95,7 +95,7 @@ def cache_specs(model: TransformerLM, batch: int, cache_len: int,
             if heads_fit:
                 return P(*lead, b, None, m, None)
             return P(*lead, b, m, None, None)
-        if name in ("kp", "vp"):          # [(G,) n_pages, P, KV, hd]
+        if name in ("kp", "vp"):          # [(G,) n_pages, P, KV*hd]
             n_pages = leaf.shape[len(lead)]
             if kv_seq_axis is not None:
                 # same no-padding rule as page_spec: pjit argument
@@ -107,11 +107,13 @@ def cache_specs(model: TransformerLM, batch: int, cache_len: int,
                 for a in axes:
                     size *= policy.axis_size(a) or 0
                 sd = kv_seq_axis if size and n_pages % size == 0 else None
-                return P(*lead, sd, None, None, None)
+                return P(*lead, sd, None, None)
             pd = policy.page_spec(n_pages)
             if heads_fit:
-                return P(*lead, pd, None, m, None)
-            return P(*lead, pd, None, None, None)
+                # KV*hd is head-major, so splitting it on the model
+                # axis splits whole heads, as the contiguous cache does
+                return P(*lead, pd, None, m)
+            return P(*lead, pd, None, None)
         if name == "block":
             # [(G,) B(, n_lp)] — slot dim rides the data axes with the
             # pool extents; no sharding along kv_seq_axis (the seq-split
@@ -206,7 +208,7 @@ def _shift_block_ids(cache, shift):
     restores them."""
     def one(node):
         if isinstance(node, PagedKVCache):
-            ext = node.kp.shape[node.kp.ndim - 4]   # [(G,) n_pages, P, kvh, hd]
+            ext = node.kp.shape[node.kp.ndim - 3]   # [(G,) n_pages, P, kvh*hd]
             return dataclasses.replace(node, block=node.block + shift * ext)
         ext = node.conv_p.shape[node.conv_p.ndim - 3]  # [(G,) n_sp, k-1, d]
         return dataclasses.replace(node, block=node.block + shift * ext)

@@ -395,7 +395,7 @@ class PagePayload:
     """Host-resident copy of one offloaded slot (all streams).
 
     ``kv[si] = (jdx->row, k_pages, v_pages)`` with contents shaped
-    ``[G?, n_rows, page_size, kv_heads, head_dim]``;
+    ``[G?, n_rows, page_size, kv_heads*head_dim]``;
     ``state[si] = (conv, h)``.  ``tokens`` is the slot's context length
     at offload time (for traffic accounting).
     """
@@ -786,8 +786,7 @@ class PageTable:
 
         def scat(pool, rows):            # rows: [L, kvh, hd]
             src = jnp.pad(rows, ((0, pad), (0, 0), (0, 0)))
-            return pool.at[write_ids].set(
-                src.reshape((n_lp, P) + rows.shape[1:]))
+            return pool.at[write_ids].set(src.reshape(n_lp, P, -1))
 
         block_row = jnp.where(bids < 0, zero, bids)
         if grouped:

@@ -63,9 +63,9 @@ def test_kernel_matches_gather_oracle(b, kvh, g, hd, page, L, window,
     n_lp = -(-L // page)
     n_pages = 2 + b * n_lp + 3
     q = jnp.asarray(rng.standard_normal((b, kvh, g, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((n_pages, page, kvh, hd)),
+    kp = jnp.asarray(rng.standard_normal((n_pages, page, kvh * hd)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, page, kvh, hd)),
+    vp = jnp.asarray(rng.standard_normal((n_pages, page, kvh * hd)),
                      jnp.float32)
     block = jnp.asarray(
         rng.permutation(np.arange(2, n_pages))[:b * n_lp].reshape(b, n_lp),
@@ -80,9 +80,67 @@ def test_kernel_matches_gather_oracle(b, kvh, g, hd, page, L, window,
                                atol=2e-6, rtol=2e-6)
 
 
+STACKED_CASES = [
+    # layers, b, kvh, g, hd, page, cache_len, window, softcap
+    (3, 2, 2, 3, 16, 5, 24, None, None),  # GQA 3, partial tail page
+    (4, 3, 2, 2, 8, 4, 16, 6, 30.0),      # window + softcap
+    (2, 2, 4, 1, 16, 3, 10, None, 50.0),  # MHA, ring wrap
+]
+
+
+@pytest.mark.parametrize("layers,b,kvh,g,hd,page,L,window,softcap",
+                         STACKED_CASES)
+def test_kernel_stacked_pool_matches_ref_per_layer(layers, b, kvh, g, hd,
+                                                   page, L, window, softcap,
+                                                   rng):
+    """The kernel reads one layer of a pool stacked over layers, picked
+    by its scalar-prefetch layer index, where it lies: every layer holds
+    different values, and each index matches the oracle run on that
+    layer's pool alone."""
+    n_lp = -(-L // page)
+    n_pages = 2 + b * n_lp + 3
+    shape = (layers, n_pages, page, kvh * hd)
+    q = jnp.asarray(rng.standard_normal((b, kvh, g, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    block = jnp.asarray(
+        rng.permutation(np.arange(2, n_pages))[:b * n_lp].reshape(b, n_lp),
+        jnp.int32)
+    pos = jnp.asarray(rng.integers(0, 2 * L, (b,)), jnp.int32)
+    kw = dict(cache_len=L, window=window, softcap=softcap)
+    outs = []
+    for layer in range(layers):
+        ref = paged_attention(q, kp[layer], vp[layer], block, pos,
+                              backend="ref", **kw)
+        pal = paged_attention(q, kp, vp, block, pos,
+                              jnp.asarray(layer, jnp.int32),
+                              backend="pallas", **kw)
+        np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
+                                   atol=2e-6, rtol=2e-6,
+                                   err_msg=f"layer {layer}")
+        outs.append(np.asarray(pal))
+    # the layers differ, so reading the wrong one could not pass above
+    assert not np.allclose(outs[0], outs[1])
+
+
+def test_kernel_rejects_bad_pool_rank_or_width(rng):
+    q = jnp.zeros((1, 2, 1, 8), jnp.float32)
+    block = jnp.full((1, 2), 2, jnp.int32)
+    pos = jnp.zeros((1,), jnp.int32)
+    one = jnp.zeros((4, 4, 16), jnp.float32)
+    stacked = jnp.zeros((3, 4, 4, 16), jnp.float32)
+    with pytest.raises(ValueError, match="layer index"):
+        paged_attention(q, stacked, stacked, block, pos, cache_len=8)
+    with pytest.raises(ValueError, match="single-layer"):
+        paged_attention(q, one, one, block, pos, jnp.int32(0), cache_len=8)
+    narrow = jnp.zeros((4, 4, 8), jnp.float32)   # one head's width
+    with pytest.raises(ValueError, match="kv_heads"):
+        paged_attention(q, narrow, narrow, block, pos, cache_len=8)
+
+
 def test_kernel_rejects_short_block_table(rng):
     q = jnp.zeros((1, 1, 1, 8), jnp.float32)
-    kp = jnp.zeros((4, 4, 1, 8), jnp.float32)
+    kp = jnp.zeros((4, 4, 8), jnp.float32)
     block = jnp.zeros((1, 2), jnp.int32)       # 2 pages x 4 rows < 12
     with pytest.raises(ValueError, match="block table"):
         paged_attention(q, kp, kp, block, jnp.zeros((1,), jnp.int32),

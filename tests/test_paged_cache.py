@@ -16,6 +16,7 @@ pages), Mamba/RG-LRU state pages and conv tails, and dropless-MoE
 decode — i.e. all 10 ``repro.configs`` entries.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -148,6 +149,32 @@ def test_page_size_extremes(page_size):
      tok, pos) = _build_pair("qwen1.5-0.5b", (5, 9), page_size)
     _lockstep(model, params, decode, table, cache_c, cache_p, tok,
               pos, 6, f"page_size={page_size}")
+
+
+@pytest.mark.parametrize("arch", ["gemma2-9b", "recurrentgemma-2b"])
+@pytest.mark.parametrize("backend", ["gather", "pallas_paged"])
+def test_unrolled_decode_matches_scan(arch, backend):
+    """The unrolled depth loop (the analysis form) indexes the stacked
+    KV pools by a static group where the scan carries them with a traced
+    index: both write the same rows into the same pages and return the
+    same logits, with local and global pools per group (gemma2) and
+    recurrent state beside a pool (recurrentgemma)."""
+    (model, params, _, table, _, cache_p,
+     tok, pos) = _build_pair(arch, (7, 10))
+    for s in range(pos.shape[0]):
+        cache_p, ok = table.prepare_step(cache_p, s, int(pos[s]))
+        assert ok
+    unrolled = TransformerLM(model.cfg, unroll=True)
+    outs = [jax.jit(functools.partial(m.decode_step, decode_backend=backend))(
+        params, cache_p, jnp.asarray(tok), jnp.asarray(pos))
+        for m in (model, unrolled)]
+    (la, ca), (lb, cb) = outs
+    np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
+                               atol=1e-5, rtol=1e-5)
+    assert jax.tree.structure(ca) == jax.tree.structure(cb)
+    for a, b in zip(jax.tree.leaves(ca), jax.tree.leaves(cb)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
 
 
 # ---------------------------------------------------------------------------
