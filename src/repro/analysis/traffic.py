@@ -25,6 +25,7 @@ from typing import List
 
 # importing the ops modules registers their pallas cost handlers
 import repro.kernels.flash_attention.ops    # noqa: F401
+import repro.kernels.grouped_matmul.ops     # noqa: F401
 import repro.kernels.paged_attention.ops    # noqa: F401
 import repro.kernels.rate_match.ops         # noqa: F401
 import repro.kernels.refresh_sim.ops        # noqa: F401

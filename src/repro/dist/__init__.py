@@ -46,11 +46,15 @@ attn        wo             ``P(..., m, None)``
 attn        bq/bk/bv       ``P(..., m)``
 mlp         wi/wg          ``P(..., None, m)``
 mlp         wo             ``P(..., m, None)``
-moe         wi/wg/wo       expert-parallel ``P(..., m, None, None)``
+moe         wi/wg/wo       tensor-parallel inside each expert
+                           (``P(..., None, None, m)``, ``wo``
+                           ``P(..., None, m, None)``) where every
+                           slice of the width stays at least
+                           ``EXPERT_SLICE`` (1024) wide; else
+                           expert-parallel ``P(..., m, None, None)``
                            when the model-axis size divides the
                            storage expert count (virtual split
-                           included), else tensor-parallel inside
-                           each expert
+                           included); else tensor-parallel
 moe         router         replicated
 ssm         in_proj        ``P(..., None, m)``
 ssm         out_proj       ``P(..., m, None)``

@@ -13,10 +13,12 @@ import threading
 from typing import Optional, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["AxisEnv", "axis_env", "current_env", "constrain"]
+__all__ = ["AxisEnv", "axis_env", "current_env", "constrain",
+           "model_shard", "reduce_model"]
 
 # A tag target: no sharding, one mesh axis, or several mesh axes.
 Axes = Union[None, str, Tuple[str, ...]]
@@ -37,14 +39,22 @@ class AxisEnv:
 
     ``batch`` / ``seq`` / ``model`` keep their raw form (``None`` means
     "unsharded", which callers test with ``env.seq is not None``).
+
+    ``manual`` (``(axis name, size)`` or ``None``): the code runs inside
+    a ``shard_map`` body mapped over that mesh axis, on each device's
+    share of the model (its heads, its expert share, its vocabulary
+    slice); the layers reduce their partial outputs over it
+    (:func:`reduce_model`).
     """
 
     def __init__(self, batch: Axes, model: Axes, seq: Axes,
-                 mesh: Optional[Mesh]):
+                 mesh: Optional[Mesh],
+                 manual: Optional[Tuple[str, int]] = None):
         self.batch = batch
         self.model = model
         self.seq = seq
         self.mesh = mesh
+        self.manual = manual
 
     def axes(self, tag: Optional[str]) -> Tuple[str, ...]:
         """Mesh axes a tag resolves to (only axes present on the mesh)."""
@@ -80,13 +90,15 @@ def current_env() -> Optional[AxisEnv]:
 @contextlib.contextmanager
 def axis_env(policy=None, *, batch_axes: Axes = _UNSET,
              model_axis: Axes = _UNSET, seq_axis: Axes = _UNSET,
-             mesh: Optional[Mesh] = None):
+             mesh: Optional[Mesh] = None,
+             manual: Optional[Tuple[str, int]] = None):
     """Install an :class:`AxisEnv` for the dynamic extent of the block.
 
     Accepts either a :class:`~repro.dist.sharding.ShardingPolicy`
     (positional) or explicit ``batch_axes`` / ``model_axis`` /
     ``seq_axis`` kwargs; explicit kwargs override the policy's fields
     (including an explicit ``None``, which unbinds the tag).
+    ``manual``: see :class:`AxisEnv`.
     """
     if policy is not None:
         batch = policy.data_axes if batch_axes is _UNSET else batch_axes
@@ -96,7 +108,7 @@ def axis_env(policy=None, *, batch_axes: Axes = _UNSET,
         batch = None if batch_axes is _UNSET else batch_axes
         model = None if model_axis is _UNSET else model_axis
         seq = None if seq_axis is _UNSET else seq_axis
-    env = AxisEnv(batch, model, seq, mesh)
+    env = AxisEnv(batch, model, seq, mesh, manual)
     stack = getattr(_LOCAL, "stack", None)
     if stack is None:
         stack = _LOCAL.stack = []
@@ -132,3 +144,19 @@ def constrain(x, *tags: Optional[str]):
             entries.append(free)
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(env.mesh, P(*entries)))
+
+
+def model_shard() -> Optional[Tuple[str, int]]:
+    """``(axis name, size)`` of the manual model axis the code runs
+    under, or ``None`` (whole model, or GSPMD-placed)."""
+    env = current_env()
+    return env.manual if env is not None else None
+
+
+def reduce_model(x):
+    """Sum a layer's partial output over the manual model axis, in
+    float32, returned in ``x``'s dtype (the identity without one)."""
+    shard = model_shard()
+    if shard is None:
+        return x
+    return jax.lax.psum(x.astype(jnp.float32), shard[0]).astype(x.dtype)

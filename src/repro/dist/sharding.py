@@ -187,6 +187,12 @@ class ShardingPolicy:
         return dsize
 
 
+#: narrowest slice of an expert's width that ``param_specs`` splits
+#: experts into: 1024 columns (eight 128-lane tiles), so a grouped
+#: matmul still streams wide weight tiles from each slice
+EXPERT_SLICE = 1024
+
+
 def _key(entry) -> str:
     """Stringify one pytree path entry (DictKey/SequenceKey/GetAttrKey)."""
     for attr in ("key", "idx", "name"):
@@ -256,9 +262,16 @@ def param_specs(shapes, policy: Optional[ShardingPolicy] = None):
         elif mod == "moe":
             if name in ("wi", "wg", "wo"):       # [E, d, f] / [E, f, d]
                 n_storage_experts = leaf.shape[len(lead)]
+                width = leaf.shape[len(lead) + (1 if name == "wo" else 2)]
                 msize = policy.model_size
-                expert_parallel = (msize is None
-                                   or n_storage_experts % msize == 0)
+                # split every expert's width where the slices stay at
+                # least EXPERT_SLICE wide: each device then holds a share
+                # of every expert and does the same work whatever the
+                # routing; across whole experts otherwise
+                width_split = (msize is not None and width % msize == 0
+                               and width // msize >= EXPERT_SLICE)
+                expert_parallel = not width_split and (
+                    msize is None or n_storage_experts % msize == 0)
                 if expert_parallel:
                     spec = P(*lead, m, None, None)
                 elif name == "wo":

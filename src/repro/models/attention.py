@@ -14,7 +14,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.dist.axisenv import constrain, current_env
+from repro.dist.axisenv import (constrain, current_env, model_shard,
+                                reduce_model)
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, rope, softcap
 
@@ -42,6 +43,9 @@ def attn_init(key, cfg: ModelConfig, dtype) -> dict:
 
 
 def _project_qkv(params, cfg: ModelConfig, x):
+    """q [b, s, heads, hd], k and v [b, s, kv_heads, hd].  The head
+    counts are read off the weights, which under a manual model axis
+    hold this device's heads only."""
     b, s, _ = x.shape
     hd = cfg.resolved_head_dim
     q = x @ params["wq"]
@@ -49,10 +53,22 @@ def _project_qkv(params, cfg: ModelConfig, x):
     v = x @ params["wv"]
     if cfg.qkv_bias:
         q, k, v = q + params["bq"], k + params["bk"], v + params["bv"]
-    q = q.reshape(b, s, cfg.n_heads, hd)
-    k = k.reshape(b, s, cfg.n_kv_heads, hd)
-    v = v.reshape(b, s, cfg.n_kv_heads, hd)
+    q = q.reshape(b, s, -1, hd)
+    k = k.reshape(b, s, -1, hd)
+    v = v.reshape(b, s, -1, hd)
     return q, k, v
+
+
+def _out_proj(params, out):
+    """Output projection.  Under a manual model axis each device holds
+    its heads' rows of ``wo``: the partial products stay in float32
+    through the sum over the axis and are rounded once, as one device's
+    product is."""
+    if model_shard() is None:
+        return out @ params["wo"]
+    return reduce_model(jnp.dot(out, params["wo"],
+                                preferred_element_type=jnp.float32)
+                        ).astype(out.dtype)
 
 
 def _mask(s_q: int, s_kv: int, offset, local_window: Optional[int]):
@@ -139,7 +155,7 @@ def attn_apply(params, cfg: ModelConfig, x, positions, kind: str):
     k = rope(k, positions, cfg.rope_theta)
     window = cfg.window_size if kind == "local" else None
     out = _attend_causal(q, k, v, cfg, window)
-    return out @ params["wo"]
+    return _out_proj(params, out)
 
 
 def attn_prefill(params, cfg: ModelConfig, x, positions, kind: str,
@@ -168,7 +184,7 @@ def attn_prefill(params, cfg: ModelConfig, x, positions, kind: str,
     out = _attend_causal(q, k, v, cfg, window)
 
     s = x.shape[1]
-    shape = (x.shape[0], cache_len, cfg.n_kv_heads, cfg.resolved_head_dim)
+    shape = (x.shape[0], cache_len) + k.shape[2:]
     if lengths is None:
         keep = min(s, cache_len)
         slots = jnp.arange(s - keep, s) % cache_len
@@ -191,7 +207,7 @@ def attn_prefill(params, cfg: ModelConfig, x, positions, kind: str,
         cv = jax.vmap(scatter)(v, slots)
         keep = jnp.minimum(jnp.max(lengths), cache_len).astype(jnp.int32)
         cache = KVCache(ck, cv, keep)
-    return out @ params["wo"], cache
+    return _out_proj(params, out), cache
 
 
 # ---------------------------------------------------------------------------
@@ -390,19 +406,19 @@ def attn_decode(params, cfg: ModelConfig, x, cache, pos, kind: str,
         if backend == "pallas_paged":
             # the kernel walks the block table in place; no logical view
             from repro.kernels.paged_attention.ops import paged_attention
-            kvh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-            g = cfg.n_heads // kvh
+            kvh, hd = k_new.shape[2], cfg.resolved_head_dim
+            g = q.shape[2] // kvh
             posb = pos if per_slot else jnp.full((b,), pos, jnp.int32)
             out = paged_attention(
                 q[:, 0].reshape(b, kvh, g, hd), kp, vp, new_cache.block,
                 posb, layer, cache_len=cache_len,
                 window=(cfg.window_size if kind == "local" else None),
                 softcap=cfg.attn_softcap)
-            out = out.reshape(b, 1, cfg.n_heads * hd)
+            out = out.reshape(b, 1, -1)
             new_len = jnp.minimum(jnp.max(pos) + 1, cache_len)
             new_cache = dataclasses.replace(
                 new_cache, length=new_len.astype(jnp.int32))
-            return out @ params["wo"], new_cache
+            return _out_proj(params, out), new_cache
         k, v = paged_kv_view(new_cache, layer)
     elif per_slot:
         rows = jnp.arange(b)
@@ -418,9 +434,9 @@ def attn_decode(params, cfg: ModelConfig, x, cache, pos, kind: str,
         valid &= kv_pos > (pos[:, None] if per_slot else pos) - cfg.window_size
     if valid.ndim == 1:
         valid = valid[None]                      # [1, L] broadcasts over b
-    kvh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    kvh, hd = k_new.shape[2], cfg.resolved_head_dim
     scale = hd ** -0.5
-    g = cfg.n_heads // kvh
+    g = q.shape[2] // kvh
     # Cache sharding choice (mirrors serve.engine.cache_specs): enough
     # KV heads to fill the model axis -> shard heads; otherwise shard
     # the cache *length* (flash-decode-style distributed attention with
@@ -450,7 +466,7 @@ def attn_decode(params, cfg: ModelConfig, x, cache, pos, kind: str,
         new_cache = dataclasses.replace(new_cache, length=new_len)
     else:
         new_cache = KVCache(k, v, new_len)
-    return out @ params["wo"], new_cache
+    return _out_proj(params, out), new_cache
 
 
 def _cache_positions(cache_len: int, pos):

@@ -74,6 +74,7 @@ class ModelConfig:
     frontend_tokens: int = 0               # prompt positions fed as embeddings
     # --- numerics -------------------------------------------------------------
     dtype: str = "bfloat16"
+    rms_norm_eps: float = 1e-6             # every RMSNorm's epsilon
     # --- training-shape metadata ----------------------------------------------
     max_seq_len: int = 8192
 

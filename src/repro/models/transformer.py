@@ -22,7 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.dist.axisenv import constrain
+from repro.dist.axisenv import constrain, model_shard, reduce_model
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
@@ -110,16 +110,30 @@ class TransformerLM:
         )
 
     # ------------------------------------------------------------- embedding
+    def _norm(self, p, x):
+        return rmsnorm(p, x, self.cfg.rms_norm_eps)
+
     def _embed(self, params, tokens):
         cfg = self.cfg
-        x = params["embed"]["tok"][tokens]
+        tok = params["embed"]["tok"]
+        shard = model_shard()
+        if shard is not None and tok.shape[0] < cfg.vocab_size:
+            # this device holds one slice of the vocabulary: look up the
+            # tokens that fall in it, zeros elsewhere, and sum the slices
+            v = tok.shape[0]
+            local = tokens - jax.lax.axis_index(shard[0]) * v
+            hit = (local >= 0) & (local < v)
+            x = reduce_model(jnp.where(hit[..., None],
+                                       tok[jnp.clip(local, 0, v - 1)], 0))
+        else:
+            x = tok[tokens]
         if cfg.scale_embeddings:
             x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
         return x
 
     def _unembed(self, params, x):
         cfg = self.cfg
-        x = rmsnorm(params["final_norm"], x)
+        x = self._norm(params["final_norm"], x)
         if cfg.tie_embeddings:
             logits = x @ params["embed"]["tok"].T
         else:
@@ -136,19 +150,20 @@ class TransformerLM:
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         if kind in ("global", "local"):
-            x = x + attn.attn_apply(p["attn"], cfg, rmsnorm(p["ln1"], x),
+            x = x + attn.attn_apply(p["attn"], cfg, self._norm(p["ln1"], x),
                                     positions, kind)
-            h = rmsnorm(p["ln2"], x)
+            h = self._norm(p["ln2"], x)
             if cfg.n_experts:
                 y, aux = moe_mod.moe_apply(p["moe"], cfg, h)
             else:
                 y = mlp_apply(p["mlp"], h, cfg.mlp_activation)
             x = x + y
         elif kind == "ssm":
-            x = x + ssm_mod.ssm_apply(p["ssm"], cfg, rmsnorm(p["ln1"], x))
+            x = x + ssm_mod.ssm_apply(p["ssm"], cfg, self._norm(p["ln1"], x))
         elif kind == "rglru":
-            x = x + rglru_mod.rglru_apply(p["rec"], cfg, rmsnorm(p["ln1"], x))
-            x = x + mlp_apply(p["mlp"], rmsnorm(p["ln2"], x),
+            x = x + rglru_mod.rglru_apply(p["rec"], cfg,
+                                          self._norm(p["ln1"], x))
+            x = x + mlp_apply(p["mlp"], self._norm(p["ln2"], x),
                               cfg.mlp_activation)
         return x, aux
 
@@ -299,7 +314,41 @@ class TransformerLM:
                      for kind in cfg.pattern_tail)
         return {"groups": tuple(groups), "tail": tail}
 
-    def _block_prefill(self, kind, p, x, positions, max_len, lengths=None):
+    def _ffn_serve(self, p, h, token_mask=None, experts=None):
+        """The serving paths' MLP or dropless expert layer, reduced over
+        a manual model axis: ``(y, rows)``, ``rows`` the expert layer's
+        rows per expert (``None`` for an MLP).  ``experts``: ``(stack,
+        layer)``, the expert weights stacked over layer groups
+        (:meth:`_split_experts`) and this layer's group."""
+        cfg = self.cfg
+        if not cfg.n_experts:
+            return (reduce_model(mlp_apply(p["mlp"], h, cfg.mlp_activation)),
+                    None)
+        if experts is None:
+            return moe_mod.moe_held(p["moe"], cfg, h, token_mask=token_mask)
+        stack, layer = experts
+        return moe_mod.moe_held(dict(p["moe"], **stack), cfg, h,
+                                token_mask=token_mask, layer=layer)
+
+    def _split_experts(self, blocks):
+        """``(blocks, stacks)``: the stacked block parameters without
+        the expert weights, for the depth loop to slice per group, and
+        the expert weights of each pattern position stacked over groups
+        (``None`` without experts), which the grouped matmul reads where
+        they lie: a slice of them would be a copy of the layer's whole
+        expert weights, made every step."""
+        if not self.cfg.n_experts:
+            return blocks, None
+        thin, stacks = [], []
+        for p in blocks:
+            moe = p.get("moe", {})
+            thin.append(dict(p, moe={"router": moe["router"]})
+                        if moe else p)
+            stacks.append({n: w for n, w in moe.items() if n != "router"})
+        return tuple(thin), tuple(stacks)
+
+    def _block_prefill(self, kind, p, x, positions, max_len, lengths=None,
+                       experts=None):
         """Full-sequence block forward that also emits the decode cache.
 
         ``lengths`` ([b] int32): right-padded (length-bucketed) prefill —
@@ -309,36 +358,27 @@ class TransformerLM:
         """
         cfg = self.cfg
         if kind in ("global", "local"):
-            h, c = attn.attn_prefill(p["attn"], cfg, rmsnorm(p["ln1"], x),
+            h, c = attn.attn_prefill(p["attn"], cfg, self._norm(p["ln1"], x),
                                      positions, kind,
                                      cfg.decode_cache_len(kind, max_len),
                                      lengths=lengths)
             x = x + h
-            hh = rmsnorm(p["ln2"], x)
-            if cfg.n_experts:
-                # dropless dispatch: prefill must agree with decode,
-                # which never capacity-drops (seq = 1).  The static slot
-                # bound is the (padded) sequence length; with a token
-                # mask the occupancy actually dispatched is the real
-                # (unpadded) token count.
-                mask = None if lengths is None \
-                    else positions < lengths[:, None]
-                y, _ = moe_mod.moe_apply(p["moe"], cfg, hh,
-                                         capacity=hh.shape[1],
-                                         token_mask=mask)
-            else:
-                y = mlp_apply(p["mlp"], hh, cfg.mlp_activation)
+            # dropless experts: prefill must agree with decode; padded
+            # tokens route nowhere
+            mask = None if lengths is None else positions < lengths[:, None]
+            y, _ = self._ffn_serve(p, self._norm(p["ln2"], x), mask,
+                                   experts)
             x = x + y
         elif kind == "ssm":
-            h, c = ssm_mod.ssm_prefill(p["ssm"], cfg, rmsnorm(p["ln1"], x),
+            h, c = ssm_mod.ssm_prefill(p["ssm"], cfg, self._norm(p["ln1"], x),
                                        lengths=lengths)
             x = x + h
         elif kind == "rglru":
             h, c = rglru_mod.rglru_prefill(p["rec"], cfg,
-                                           rmsnorm(p["ln1"], x),
+                                           self._norm(p["ln1"], x),
                                            lengths=lengths)
             x = x + h
-            x = x + mlp_apply(p["mlp"], rmsnorm(p["ln2"], x),
+            x = x + mlp_apply(p["mlp"], self._norm(p["ln2"], x),
                               cfg.mlp_activation)
         else:  # pragma: no cover
             raise ValueError(kind)
@@ -375,11 +415,15 @@ class TransformerLM:
         if lengths is not None:
             lengths = jnp.asarray(lengths, jnp.int32)
 
-        def group_body(x, gp):
+        blocks, stacks = self._split_experts(params["blocks"])
+
+        def group_body(x, inputs):
+            gp, g = inputs
             cs = []
             for i, kind in enumerate(cfg.attn_pattern):
-                x, c = self._block_prefill(kind, gp[i], x, positions, max_len,
-                                           lengths=lengths)
+                x, c = self._block_prefill(
+                    kind, gp[i], x, positions, max_len, lengths=lengths,
+                    experts=None if stacks is None else (stacks[i], g))
                 x = constrain(x, "B", "S", None)
                 cs.append(c)
             return x, tuple(cs)
@@ -387,12 +431,14 @@ class TransformerLM:
         if self.unroll:
             per_group = []
             for g in range(cfg.n_groups):
-                gp = jax.tree.map(lambda l: l[g], params["blocks"])
-                x, cs = group_body(x, gp)
+                gp = jax.tree.map(lambda l: l[g], blocks)
+                x, cs = group_body(x, (gp, g))
                 per_group.append(cs)
             gcaches = jax.tree.map(lambda *ls: jnp.stack(ls), *per_group)
         else:
-            x, gcaches = jax.lax.scan(group_body, x, params["blocks"])
+            x, gcaches = jax.lax.scan(
+                group_body, x,
+                (blocks, jnp.arange(cfg.n_groups, dtype=jnp.int32)))
         tail_caches = []
         for i, kind in enumerate(cfg.pattern_tail):
             x, c = self._block_prefill(kind, params["tail"][i], x, positions,
@@ -409,31 +455,34 @@ class TransformerLM:
         return logits, cache
 
     def _block_decode(self, kind, p, c, x, pos, backend: str = "gather",
-                      layer=None):
+                      layer=None, experts=None):
+        """``(x, cache, rows)``: ``rows`` is the expert layer's rows per
+        expert, ``None`` where the layer has no experts."""
         cfg = self.cfg
+        rows = None
         if kind in ("global", "local"):
-            h, c = attn.attn_decode(p["attn"], cfg, rmsnorm(p["ln1"], x),
+            h, c = attn.attn_decode(p["attn"], cfg, self._norm(p["ln1"], x),
                                     c, pos, kind, backend=backend,
                                     layer=layer)
             x = x + h
-            hh = rmsnorm(p["ln2"], x)
-            if cfg.n_experts:
-                y, _ = moe_mod.moe_apply(p["moe"], cfg, hh)
-            else:
-                y = mlp_apply(p["mlp"], hh, cfg.mlp_activation)
+            y, rows = self._ffn_serve(p, self._norm(p["ln2"], x),
+                                      experts=experts)
             x = x + y
         elif kind == "ssm":
-            h, c = ssm_mod.ssm_decode(p["ssm"], cfg, rmsnorm(p["ln1"], x), c)
+            h, c = ssm_mod.ssm_decode(p["ssm"], cfg,
+                                      self._norm(p["ln1"], x), c)
             x = x + h
         elif kind == "rglru":
-            h, c = rglru_mod.rglru_decode(p["rec"], cfg, rmsnorm(p["ln1"], x), c)
+            h, c = rglru_mod.rglru_decode(p["rec"], cfg,
+                                          self._norm(p["ln1"], x), c)
             x = x + h
-            x = x + mlp_apply(p["mlp"], rmsnorm(p["ln2"], x),
+            x = x + mlp_apply(p["mlp"], self._norm(p["ln2"], x),
                               cfg.mlp_activation)
-        return x, c
+        return x, c, rows
 
     def decode_step(self, params, cache, token, pos,
-                    decode_backend: str = "gather"):
+                    decode_backend: str = "gather",
+                    expert_rows: bool = False):
         """token: [b] int32 (or [b, d] embeds); pos: [] int32, or [b]
         int32 for per-slot positions (continuous batching: each batch
         slot decodes its own sequence offset).
@@ -443,7 +492,9 @@ class TransformerLM:
         contiguous cache) or ``"pallas_paged"`` (the block-table Pallas
         kernel of :mod:`repro.kernels.paged_attention`; no gather).
 
-        Returns (logits [b, vocab] f32, new_cache).
+        Returns (logits [b, vocab] f32, new_cache), and with
+        ``expert_rows`` a third output ``[expert layers, n_experts]``
+        int32: the rows the step routed to each expert of each layer.
 
         The KV pools of paged attention layers, stacked over groups,
         ride through the depth loop as its *carry*: layer ``g`` writes
@@ -461,22 +512,26 @@ class TransformerLM:
         def body(carry, inputs):
             x, pools = carry
             gp, gc, g = inputs
-            new_cs, new_pools = [], []
+            new_cs, new_pools, rows = [], [], []
             for i, kind in enumerate(cfg.attn_pattern):
                 pool = pools[i]
+                ex = None if stacks is None else (stacks[i], g)
                 if pool is None:
-                    x, nc = self._block_decode(kind, gp[i], gc[i], x, pos,
-                                               backend=decode_backend)
+                    x, nc, r = self._block_decode(kind, gp[i], gc[i], x, pos,
+                                                  backend=decode_backend,
+                                                  experts=ex)
                 else:
                     c = dataclasses.replace(gc[i], kp=pool[0], vp=pool[1])
-                    x, nc = self._block_decode(kind, gp[i], c, x, pos,
-                                               backend=decode_backend,
-                                               layer=g)
+                    x, nc, r = self._block_decode(kind, gp[i], c, x, pos,
+                                                  backend=decode_backend,
+                                                  layer=g, experts=ex)
                     pool = (nc.kp, nc.vp)
                     nc = dataclasses.replace(nc, kp=None, vp=None)
                 new_cs.append(nc)
                 new_pools.append(pool)
-            return (x, tuple(new_pools)), tuple(new_cs)
+                if r is not None and expert_rows:
+                    rows.append(r)
+            return (x, tuple(new_pools)), (tuple(new_cs), tuple(rows))
 
         paged = tuple(isinstance(c, attn.PagedKVCache)
                       for c in cache["groups"])
@@ -485,28 +540,38 @@ class TransformerLM:
         rest = tuple(dataclasses.replace(c, kp=None, vp=None) if p else c
                      for c, p in zip(cache["groups"], paged))
         carry = (x, pools)
+        blocks, stacks = self._split_experts(params["blocks"])
         if self.unroll:
             new_groups = []
             for g in range(cfg.n_groups):
-                gp = jax.tree.map(lambda l: l[g], params["blocks"])
+                gp = jax.tree.map(lambda l: l[g], blocks)
                 gc = jax.tree.map(lambda l: l[g], rest)
                 carry, nc = body(carry, (gp, gc, g))
                 new_groups.append(nc)
-            new_rest = jax.tree.map(
+            new_rest, rows = jax.tree.map(
                 lambda *leaves: jnp.stack(leaves), *new_groups)
         else:
-            carry, new_rest = jax.lax.scan(
-                body, carry, (params["blocks"], rest,
+            carry, (new_rest, rows) = jax.lax.scan(
+                body, carry, (blocks, rest,
                               jnp.arange(cfg.n_groups, dtype=jnp.int32)))
         x, pools = carry
         new_gcache = tuple(
             c if p is None else dataclasses.replace(c, kp=p[0], vp=p[1])
             for c, p in zip(new_rest, pools))
+        rows = list(rows)                        # each [n_groups, e]
         new_tail = []
         for i, kind in enumerate(cfg.pattern_tail):
-            x, nc = self._block_decode(kind, params["tail"][i],
-                                       cache["tail"][i], x, pos,
-                                       backend=decode_backend)
+            x, nc, r = self._block_decode(kind, params["tail"][i],
+                                          cache["tail"][i], x, pos,
+                                          backend=decode_backend)
             new_tail.append(nc)
+            if r is not None and expert_rows:
+                rows.append(r[None])
         new_cache = {"groups": new_gcache, "tail": tuple(new_tail)}
-        return self._unembed(params, x)[:, 0, :], new_cache
+        logits = self._unembed(params, x)[:, 0, :]
+        if not expert_rows:
+            return logits, new_cache
+        if not rows:
+            return logits, new_cache, jnp.zeros((0, cfg.n_experts),
+                                                jnp.int32)
+        return logits, new_cache, jnp.concatenate(rows)

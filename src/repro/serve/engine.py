@@ -165,7 +165,10 @@ def build_prefill_step(model: TransformerLM, mesh: Mesh,
     ``batch``: the token batch size this step will be fed (the serving
     engine prefills one request at a time).  A batch of 1 replicates
     the batch dimension instead of sharding it — a size-1 dim cannot be
-    laid out over a >1-device data axis.
+    laid out over a >1-device data axis.  A serving prefill of batch 1
+    on a model axis that :func:`_model_split` accepts runs the model in
+    shares under ``shard_map``, as the decode step does: logits come out
+    split over the vocabulary, the cache over the KV heads.
     """
     pspecs = param_specs(jax.eval_shape(
         lambda: model.init(jax.random.key(0))), policy)
@@ -175,12 +178,31 @@ def build_prefill_step(model: TransformerLM, mesh: Mesh,
     tok_sh = NamedSharding(mesh, P(bspec, policy.seq_axis))
 
     if cache_len is not None:
-        def prefill_cached(params, tokens, lengths):
-            with axis_env(policy, mesh=mesh):
-                return model.prefill(params, tokens, cache_len,
-                                     lengths=lengths)
-
         len_sh = NamedSharding(mesh, P(bspec))
+        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+        split = _model_split(model, policy, sizes) if batch == 1 else 1
+        if split > 1:
+            # the model in shares over the model axis, as the decode
+            # step runs it: no expert weight is ever gathered
+            m = policy.model_axis
+
+            def body(params, tokens, lengths):
+                with axis_env(batch_axes=None, model_axis=None,
+                              seq_axis=None, mesh=None, manual=(m, split)):
+                    return model.prefill(params, tokens, cache_len,
+                                         lengths=lengths)
+
+            cspecs = cache_specs(model, 1, cache_len, policy,
+                                 model_axis_size=split)
+            prefill_cached = jax.shard_map(
+                body, mesh=mesh, in_specs=(pspecs, P(), P()),
+                out_specs=(P(None, m), cspecs), check_vma=False)
+        else:
+            def prefill_cached(params, tokens, lengths):
+                with axis_env(policy, mesh=mesh):
+                    return model.prefill(params, tokens, cache_len,
+                                         lengths=lengths)
+
         return jax.jit(spans.named("serve_prefill", prefill_cached),
                        in_shardings=(psh, tok_sh, len_sh)), psh, tok_sh
 
@@ -197,6 +219,40 @@ def build_prefill_step(model: TransformerLM, mesh: Mesh,
 
 def _is_paged_node(x) -> bool:
     return isinstance(x, (PagedKVCache, PagedSSMCache, PagedRGLRUCache))
+
+
+def _model_split(model: TransformerLM, policy: ShardingPolicy,
+                 sizes: Dict[str, int]) -> int:
+    """Size of the model axis where a serving step runs the model in
+    shares over it (``shard_map``, :func:`repro.dist.axisenv.model_shard`),
+    else 1.  Shares need attention layers only, with heads, KV heads,
+    vocabulary and the MLP's width (or the experts, or every expert's
+    width) dividing evenly over the axis, as ``param_specs`` splits
+    them, and no FSDP, ZeRO or sequence axis."""
+    msize = sizes.get(policy.model_axis, 1)
+    cfg = model.cfg
+    if cfg.n_experts:
+        vs = cfg.moe_virtual_split
+        ffn = ((cfg.d_ff // vs) % msize == 0
+               or (cfg.n_experts * vs) % msize == 0)
+    else:
+        ffn = cfg.d_ff % msize == 0
+    if (msize <= 1 or not ffn or policy.fsdp or policy.zero1
+            or policy.seq_axis is not None
+            or any(k not in ("global", "local") for k in cfg.all_kinds)
+            or cfg.n_heads % msize or cfg.n_kv_heads % msize
+            or cfg.vocab_size % msize):
+        return 1
+    return msize
+
+
+def _local_kv_heads(cache, split: int):
+    """Paged KV nodes' static ``kv_heads`` divided by ``split``: a
+    device's share of the heads inside a model-axis ``shard_map``."""
+    return jax.tree.map(
+        lambda n: (dataclasses.replace(n, kv_heads=n.kv_heads // split)
+                   if isinstance(n, PagedKVCache) else n),
+        cache, is_leaf=_is_paged_node)
 
 
 def _shift_block_ids(cache, shift):
@@ -261,10 +317,19 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
     per-backend formula (``min(max(pos)+1, cache_len)``), so
     generations are bit-identical to the solo/GSPMD step.  No
     collective with a pool operand is lowered at any mesh size — the
-    property ``repro.analysis`` gates.  On any mismatch the builder
-    falls back to the plain GSPMD step, which is always correct (the
-    global-id layout decodes unmapped as-is) but gathers the pools
-    around the kernel.
+    property ``repro.analysis`` gates.  On a model axis that
+    :func:`_model_split` accepts, the same ``shard_map`` step also maps
+    the model axis: each device runs the model on its share (its query
+    and KV heads and their slice of every pool, its expert share, its
+    vocabulary slice; :func:`repro.dist.axisenv.model_shard`), each
+    layer sums its attention output and its MLP or expert output over
+    the axis, and logits come out split over the vocabulary.  On any
+    mismatch the builder falls back to the plain GSPMD step, which is
+    always correct (the global-id layout decodes unmapped as-is) but
+    gathers the pools around the kernel.
+
+    A model with experts returns a third output, the rows each expert
+    of each layer was routed (``decode_step(..., expert_rows=True)``).
     """
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     pspecs = param_specs(jax.eval_shape(
@@ -284,64 +349,76 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
     else:
         pos_sh = NamedSharding(mesh, P())
 
+    with_rows = bool(model.cfg.n_experts)
+
     def decode(params, cache, token, pos):
         seq_override = kv_seq_axis if kv_seq_axis is not None else policy.seq_axis
         with axis_env(batch_axes=policy.data_axes if batch > 1 else None,
                       model_axis=policy.model_axis,
                       seq_axis=seq_override, mesh=mesh):
             return model.decode_step(params, cache, token, pos,
-                                     decode_backend=decode_backend)
+                                     decode_backend=decode_backend,
+                                     expert_rows=with_rows)
 
     data_size = 1
     for a in policy.data_axes:
         data_size *= sizes.get(a, 1)
+    split = _model_split(model, policy, sizes)
     use_shard_map = (
-        cache_factory is not None and shards > 1 and kv_seq_axis is None
-        and data_size == shards
+        cache_factory is not None and kv_seq_axis is None
+        # data shards each own a pool extent, or there is one extent
+        and (data_size == shards if data_size > 1 else shards == 1)
+        and (shards > 1 or split > 1)
         # FSDP/ZeRO scatter params over the data axes; under a manual
         # map nothing re-gathers them, so the body would compute on
         # weight shards — GSPMD fallback stays correct there.
         and not policy.fsdp and not policy.zero1
         and all(s == 1 for a, s in sizes.items()
-                if a not in policy.data_axes))
+                if a not in policy.data_axes
+                and (split == 1 or a != policy.model_axis)))
     if use_shard_map:
         bspec = policy.batch_spec
-        logit_spec = P(bspec, None)
+        m = policy.model_axis if split > 1 else None
+        manual = (m, split) if split > 1 else None
 
         def body(params, cache, token, pos):
             # flat data-shard index, from static axis sizes (partition-id
-            # arithmetic only — no collective may appear in this body)
+            # arithmetic only: the body's only collectives are the model
+            # axis's sums of each layer's partial outputs)
             g = jnp.int32(0)
             for a in policy.data_axes:
                 g = g * sizes.get(a, 1) + jax.lax.axis_index(a)
-            local = _shift_block_ids(cache, -g)
+            local = _local_kv_heads(_shift_block_ids(cache, -g), split)
             # mesh=None env: `constrain` is the identity — the body is
             # already device-local, GSPMD has nothing to place.
             with axis_env(batch_axes=None, model_axis=None, seq_axis=None,
-                          mesh=None):
-                logits, new_cache = model.decode_step(
-                    params, local, token, pos,
-                    decode_backend=decode_backend)
-            new_cache = _shift_block_ids(new_cache, g)
+                          mesh=None, manual=manual):
+                out = model.decode_step(params, local, token, pos,
+                                        decode_backend=decode_backend,
+                                        expert_rows=with_rows)
+            new_cache = _shift_block_ids(out[1], g)
             # `length` is replicated (out_spec P()): pass the incoming
             # replicated value through; the wrapper below recomputes it
             # from the *global* position vector, exactly as the unmapped
-            # step does — per-device lengths would diverge.
+            # step does — per-device lengths would diverge.  `kv_heads`
+            # goes back to the whole model's count.
             new_cache = jax.tree.map(
-                lambda new, old: (dataclasses.replace(new, length=old.length)
-                                  if isinstance(new, PagedKVCache) else new),
+                lambda new, old: (dataclasses.replace(
+                    new, length=old.length, kv_heads=old.kv_heads)
+                    if isinstance(new, PagedKVCache) else new),
                 new_cache, cache, is_leaf=_is_paged_node)
-            return logits, new_cache
+            return (out[0], new_cache) + out[2:]
 
+        # the routed rows are computed alike on every device
         smap = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspecs, cspecs, P(bspec),
                       P(bspec) if per_slot_pos else P()),
-            out_specs=(logit_spec, cspecs),
+            out_specs=(P(bspec, m), cspecs) + ((P(),) if with_rows else ()),
             check_vma=False)
 
         def decode_sm(params, cache, token, pos):
-            logits, new_cache = smap(params, cache, token, pos)
+            out = smap(params, cache, token, pos)
             new_cache = jax.tree.map(
                 lambda new: (dataclasses.replace(
                     new, length=jnp.broadcast_to(
@@ -349,8 +426,8 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
                                     new.cache_len).astype(jnp.int32),
                         new.length.shape))
                     if isinstance(new, PagedKVCache) else new),
-                new_cache, is_leaf=_is_paged_node)
-            return logits, new_cache
+                out[1], is_leaf=_is_paged_node)
+            return (out[0], new_cache) + out[2:]
 
         fn = decode_sm
     else:
@@ -360,7 +437,8 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
         spans.named("serve_decode", fn),
         in_shardings=(psh, csh, tok_sh, pos_sh),
         out_shardings=(NamedSharding(mesh, P(
-            policy.batch_spec if batch > 1 else None, None)), csh),
+            policy.batch_spec if batch > 1 else None, None)), csh)
+        + ((NamedSharding(mesh, P()),) if with_rows else ()),
         donate_argnums=(1,) if donate_cache else (),
     )
     return step, psh, csh
@@ -670,6 +748,9 @@ class ServeEngine:
             in_axes=(None, 0, 0))))
         self._sample = jax.jit(spans.named("serve_sample", self._sample_fn),
                                static_argnums=(4,))
+        #: the last decode step's rows per layer and expert (device array;
+        #: models with experts)
+        self.expert_rows = None
 
     def _resolve_shards(self) -> int:
         """Device-local pool extents for the paged cache geometry.
@@ -922,9 +1003,14 @@ class ServeEngine:
         """One decode step over every slot: ``tokens`` and ``positions``
         are ``[max_batch]`` int32.  A paged cache must already hold the
         page each slot writes (``page_table.prepare_step``).  Returns
-        ``(logits [max_batch, vocab] f32, cache)``."""
-        return self._decode(self.params, cache, jnp.asarray(tokens),
-                            jnp.asarray(positions))
+        ``(logits [max_batch, vocab] f32, cache)``; a model with experts
+        keeps the step's rows per expert and layer, on the device, in
+        ``expert_rows``."""
+        out = self._decode(self.params, cache, jnp.asarray(tokens),
+                           jnp.asarray(positions))
+        if len(out) == 3:
+            self.expert_rows = out[2]
+        return out[0], out[1]
 
     # ----------------------------------------------------------------- serve
     def serve(self, prompts: Sequence[np.ndarray], max_new_tokens: int,
@@ -1172,11 +1258,62 @@ class ServeEngine:
                     for _, layer_tokens in cow:
                         rec(layer_tokens)
 
+        # Plain admissions of one pass dispatch back to back and their
+        # first tokens come to the host in one pull at the end of the
+        # pass, so the host dispatches ahead of the device instead of
+        # waiting on each request's token.  The pull runs inside the
+        # last admission's ``serve.admit`` span, which stays open until
+        # the pass ends or another kind of admission comes next.
+        landing: List[tuple] = []      # (slot, _Slot, first token, span)
+        open_admit: List[spans.span] = []
+        freed = False
+
+        def close_admit():
+            while open_admit:
+                open_admit.pop().__exit__(None, None, None)
+
+        def land():
+            """Pull the landing admissions' first tokens and hand each
+            to its slot and telemetry, in admission order."""
+            nonlocal freed
+            if not landing:
+                return
+            try:
+                with spans.span("serve.first_token"):
+                    firsts = jax.device_get([a[2] for a in landing])
+            finally:
+                close_admit()
+            for (s, st, _, adm_span), first in zip(landing, firsts):
+                first = int(first[0])
+                st.out[0] = tok_vec[s] = first
+                if telemetry is not None:
+                    plen = st.req.prompt.shape[0]
+                    telemetry.record_prefill(
+                        plen, adm_span.seconds,
+                        padded_len=self.buckets.bucket_for(plen))
+                if finished(st, first):
+                    retire(s)           # this slot admits again
+                    freed = True
+            landing.clear()
+
         def admit():
+            nonlocal freed
+            try:
+                while True:
+                    freed = False
+                    admit_pass()
+                    land()
+                    if not (freed and (pending or suspended)):
+                        break
+            finally:
+                close_admit()
+
+        def admit_pass():
             nonlocal cache
             for s in range(B):
                 while slots[s] is None and (pending or suspended):
                     if suspended:
+                        land()
                         # resume FIFO before admitting new work; if the
                         # oldest suspension cannot fit yet, wait for
                         # pages (live slots will retire) rather than
@@ -1200,6 +1337,7 @@ class ServeEngine:
                     if (paged and sharing is not None
                             and keys.whole in memo
                             and self._table.can_admit_cached(s, plen, keys)):
+                        land()
                         # full skip: the exact prompt prefilled earlier
                         # and every page is still registered — attach it
                         # all, restore recurrent state from the host
@@ -1239,6 +1377,7 @@ class ServeEngine:
                     if paged and sharing is not None and sharing.suffix_feed:
                         k = self._table.joint_prefix_pages(s, keys, plen)
                         if k > 0:
+                            land()
                             # opt-in suffix feed (attention-only):
                             # attach the resident prefix pages and
                             # teacher-force the novel suffix through
@@ -1268,46 +1407,42 @@ class ServeEngine:
                             occupy(s, st, int(req.prompt[ktok]))
                             continue
                     pending.popleft()
-                    with spans.span("serve.admit",
-                                    request=req.req_id) as adm_span:
-                        logits, cache, one = self.prefill_into(
-                            cache, s, req.prompt, keys)
-                        if paged and sharing is not None:
-                            adm = self._table.last_admit
-                            if telemetry is not None:
-                                rec = getattr(telemetry,
-                                              "record_admit_shared", None)
-                                if rec is not None:
-                                    rec(plen, adm["attached_layer_tokens"],
-                                        adm["total_layer_tokens"])
-                            if (sharing.memo_size > 0
-                                    and self._table.fully_shareable(plen)
-                                    and keys.whole not in memo):
-                                memo[keys.whole] = (
-                                    np.asarray(logits),
-                                    self._table.state_snapshot(one), plen)
-                                while len(memo) > sharing.memo_size:
-                                    memo.pop(next(iter(memo)))
-                        if paged:
-                            note_pages(s)   # admission scatters the prefill
-                        with spans.span("serve.first_token"):
-                            key = self._keys(
-                                base, np.asarray([req.req_id], np.int32),
-                                np.zeros((1,), np.int32))
-                            first = int(np.asarray(sample(
-                                logits, key,
-                                np.asarray([req.temperature], np.float32),
-                                np.asarray([req.top_k], np.int32)))[0])
+                    close_admit()
+                    adm_span = spans.span("serve.admit", request=req.req_id)
+                    open_admit.append(adm_span.__enter__())
+                    logits, cache, one = self.prefill_into(
+                        cache, s, req.prompt, keys)
+                    if paged and sharing is not None:
+                        adm = self._table.last_admit
+                        if telemetry is not None:
+                            rec = getattr(telemetry,
+                                          "record_admit_shared", None)
+                            if rec is not None:
+                                rec(plen, adm["attached_layer_tokens"],
+                                    adm["total_layer_tokens"])
+                        if (sharing.memo_size > 0
+                                and self._table.fully_shareable(plen)
+                                and keys.whole not in memo):
+                            memo[keys.whole] = (
+                                np.asarray(logits),
+                                self._table.state_snapshot(one), plen)
+                            while len(memo) > sharing.memo_size:
+                                memo.pop(next(iter(memo)))
+                    if paged:
+                        note_pages(s)   # admission scatters the prefill
+                    with spans.span("serve.first_token"):
+                        key = self._keys(
+                            base, np.asarray([req.req_id], np.int32),
+                            np.zeros((1,), np.int32))
+                        first = sample(
+                            logits, key,
+                            np.asarray([req.temperature], np.float32),
+                            np.asarray([req.top_k], np.int32))
                     spans.add("serve.queue_wait", t_entry, adm_span.start_ns,
                               "serve.call", req.req_id)
-                    if telemetry is not None:
-                        telemetry.record_prefill(
-                            plen, adm_span.seconds,
-                            padded_len=self.buckets.bucket_for(plen))
-                    st = _Slot(req, pos=plen, first_token=first)
-                    occupy(s, st, first)
-                    if finished(st, first):
-                        retire(s)           # keep admitting into this slot
+                    st = _Slot(req, pos=plen, first_token=0)
+                    occupy(s, st, 0)            # its token lands later
+                    landing.append((s, st, first, adm_span))
 
         admit()
         while any(st is not None for st in slots) or suspended or pending:
@@ -1330,7 +1465,13 @@ class ServeEngine:
                     toks = sample(logits, keys, jnp.asarray(temp_vec),
                                   jnp.asarray(topk_vec))
                 with spans.span("serve.token_pull") as pull_span:
-                    toks = np.asarray(toks)
+                    if self.expert_rows is not None and spans.recording():
+                        toks, rows = jax.device_get((toks, self.expert_rows))
+                        spans.count("moe.rows", int(rows.sum()))
+                        spans.count("moe.rows_max",
+                                    int(rows.max(axis=-1).sum()))
+                    else:
+                        toks = np.asarray(toks)
                 spans.count("serve.decode_steps")
                 if telemetry is not None:
                     telemetry.record_decode(

@@ -16,7 +16,9 @@ brackets):
 * ``serve.admit`` (``serve.call`` or ``serve.step``): one prefilled
   admission, with the request's id: ``serve.prefill`` (the program),
   ``page_table.insert`` (into the pages or the contiguous cache) and
-  ``serve.first_token`` (keys, sample, pull to the host);
+  ``serve.first_token`` (key and sample); the last admission of a pass
+  holds a second ``serve.first_token``, the pull of every first token
+  of the pass to the host;
 * ``serve.step`` (``serve.call``): one decode loop iteration:
   ``page_table.grow`` (the step's page assignments), ``serve.decode``
   (decode, key and sample dispatches), ``serve.token_pull`` (the wait
@@ -26,7 +28,11 @@ Counters: ``serve.decode_steps``; ``page_table.pages_live`` and
 ``page_table.pages_pool``, the KV pages live slots hold and the pool's
 KV pages, summed over decode steps; ``page_table.assigns`` and
 ``page_table.forks``, pages ``prepare_step`` assigned or forked;
-``serve.compiles``, programs compiled while ``serve.call`` was open.
+``serve.compiles``, programs compiled while ``serve.call`` was open;
+for a model with experts, ``moe.rows``, the rows its decode steps
+routed, summed over steps, layers and experts, and ``moe.rows_max``,
+the busiest expert's rows in each layer and step, summed (read from the
+step's rows per expert, pulled with the tokens).
 """
 from __future__ import annotations
 

@@ -180,8 +180,8 @@ def test_unregistered_pallas_call_is_reported_not_guessed():
 # ------------------------------------------------------------- cost handlers
 def test_every_repo_kernel_registers_a_cost_handler():
     import repro.analysis.traffic  # noqa: F401  (imports the ops modules)
-    for kernel in ("flash_attention", "paged_attention", "rate_match",
-                   "refresh_sim"):
+    for kernel in ("flash_attention", "grouped_matmul", "paged_attention",
+                   "rate_match", "refresh_sim"):
         assert lookup_pallas_cost(
             f"_kernel at /x/src/repro/kernels/{kernel}/kernel.py:1"
         ) is not None, kernel

@@ -128,3 +128,42 @@ def test_rate_match_kernel_matches_ref(na, nr, length):
     a = np.asarray(schedule_bits(na, nr, length, backend="ref"))
     b = np.asarray(schedule_bits(na, nr, length, backend="pallas"))
     np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul
+# ---------------------------------------------------------------------------
+GMM_CASES = [
+    # m, k, n, group sizes, held offset, held count, layers, out dtype
+    (128, 64, 128, (10, 0, 20, 5, 30, 7, 0, 6), 0, 8, 1, None),
+    (200, 64, 128, (10, 0, 20, 5, 30, 7, 0, 6), 2, 4, 3, None),
+    (600, 128, 256, (100, 150, 0, 250, 40, 10, 30, 20), 4, 4, 2, None),
+    (700, 64, 128, (300, 0, 200, 100), 0, 4, 1, jnp.float32),
+    (64, 64, 128, (0, 0, 64, 0), 1, 2, 2, None),
+]
+
+
+@pytest.mark.parametrize("m,k,n,sizes,offset,held,layers,out_dtype",
+                         GMM_CASES)
+def test_grouped_matmul_matches_oracle(m, k, n, sizes, offset, held, layers,
+                                       out_dtype, rng):
+    """The held groups' rows (groups sharing a row tile, groups over
+    several tiles, empty groups, a layer of a stack) match the oracle; rows of
+    groups held elsewhere and rows past the routed ones are the
+    caller's to drop."""
+    from repro.kernels.grouped_matmul.ops import grouped_matmul
+    lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((layers, held, k, n)), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+    layer = layers - 1
+    got = grouped_matmul(lhs, rhs, gs, offset, layer, out_dtype=out_dtype)
+    want = grouped_matmul(lhs, rhs, gs, offset, layer, out_dtype=out_dtype,
+                          backend="ref")
+    assert got.dtype == want.dtype == (out_dtype or jnp.float32)
+    ends = np.cumsum(sizes)
+    rows = np.arange(m)
+    mine = ((rows >= (ends[offset] - sizes[offset]))
+            & (rows < ends[offset + held - 1]))
+    assert mine.sum() == sum(sizes[offset:offset + held])
+    np.testing.assert_allclose(np.asarray(got)[mine], np.asarray(want)[mine],
+                               rtol=1e-5, atol=1e-4)

@@ -139,6 +139,41 @@ def test_eos_retirement_frees_slot(engine, solo_engine, mixed_prompts):
     assert padded.shape == (2, 6)
 
 
+@pytest.mark.parametrize("new_tokens", [1, 4])
+def test_first_token_retirement_refills_slots(engine, solo_engine,
+                                              mixed_prompts, new_tokens):
+    """A request that ends on its first token (one new token, or EOS
+    first) frees its slot once the pass's first tokens land; the next
+    pass admits into it, and every request keeps its solo tokens."""
+    ref = [solo_engine.serve([p], new_tokens)[0] for p in mixed_prompts]
+    eos = int(ref[1][0])      # request 1's first token becomes "EOS"
+    outs = engine.serve(mixed_prompts, new_tokens, eos_id=eos)
+    for got, full in zip(outs, ref):
+        stop = np.where(full == eos)[0]
+        want = full[:stop[0] + 1] if stop.size else full
+        np.testing.assert_array_equal(got, want)
+    assert len(outs[1]) == 1
+
+
+def test_one_first_token_pull_per_admission_pass(engine, mixed_prompts,
+                                                 monkeypatch):
+    """The first tokens of one admission pass come to the host in one
+    transfer: 5 requests over 3 slots, 4 new tokens each, admit 3 at
+    the start and 2 in one later pass (both slots free at one step)."""
+    import repro.serve.engine as engine_mod
+    pulls = []
+    real = engine_mod.jax.device_get
+
+    def counted(x):
+        if isinstance(x, list):
+            pulls.append(len(x))
+        return real(x)
+    monkeypatch.setattr(engine_mod.jax, "device_get", counted)
+    prompts = [p[:5] for p in mixed_prompts]
+    engine.serve(prompts, 4)
+    assert pulls == [3, 2]
+
+
 # ---------------------------------------------------------------------------
 # sampling
 # ---------------------------------------------------------------------------

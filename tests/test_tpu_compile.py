@@ -9,7 +9,11 @@ width (bf16, 16-token pages) and checks that the executable carries the
 kernel as a ``tpu_custom_call``.  The compiled decode step at the chat
 benchmark cell's shapes is checked to update its KV pools in place: no
 copy, slice or restack of a pool, and a page write that touches one
-page, not the pool.
+page, not the pool.  The Mixtral-8x22B stage's decode and prefill
+steps, compiled for the 2x2 host with the model axis over its four
+chips, are checked for their collectives (two sums of a ``[slots,
+d_model]`` activation per layer, the embedding's sum and the logits'
+gather) and for moving no pool and no expert weight.
 
 All chip-compile tests live in this one file.  The topology is
 described inside a module fixture (never at import time): only one
@@ -18,17 +22,25 @@ file loads it, and every worker still collects the same tests.  The
 kernels interpret off a TPU backend, so a fixture makes them compile
 for the described chip while this module runs.
 """
+import collections
+import dataclasses
 import functools
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
+from repro.dist.sharding import ShardingPolicy
+from repro.kernels.grouped_matmul import kernel as gmm_kernel
 from repro.kernels.paged_attention import kernel as paged_kernel
 from repro.models.transformer import TransformerLM
+from repro.serve.engine import build_decode_step, build_prefill_step
+from repro.serve.paging import PageTable
 
 PAGE = 16
 BATCH = 8
@@ -56,6 +68,7 @@ def compile_for_tpu():
     so no other test in this process reuses one."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(paged_kernel, "pallas_interpret", lambda: False)
+        mp.setattr(gmm_kernel, "pallas_interpret", lambda: False)
         jax.clear_caches()
         yield
     jax.clear_caches()
@@ -176,3 +189,111 @@ def test_page_zeroing_touches_one_page(qwen_cell, one_chip):
     if isinstance(cost, (list, tuple)):
         cost = cost[0]
     assert cost["bytes accessed"] < 10e6
+
+
+# the mixtral-8x22b-4L.chat benchmark cell: one 4-layer stage of
+# Mixtral-8x22B (global attention, as published) over the four chips of
+# a v5e 2x2 host, 32 slots of max_ctx 1024 in 16-token pages
+STAGE = dict(layers=4, batch=32, max_ctx=1024, page_size=16, kv_pages=2050)
+_COLLECTIVE = re.compile(
+    r"= (\S+?)(?:\{[^}]*\})? (all-reduce|all-gather|reduce-scatter|"
+    r"all-to-all|collective-permute)(?:-start)?\(")
+_MOVES = re.compile(r"= \S+\[([\d,]*)\]\S* (copy|dynamic-slice|"
+                    r"dynamic-update-slice|all-gather|custom-call)\(.*")
+
+
+def _big_moves(hlo: str, elements: int):
+    """Copies, slices, gathers and allocations of at least ``elements``
+    elements: one expert's weight slice or more."""
+    found = []
+    for line in hlo.splitlines():
+        m = _MOVES.search(line)
+        if m is None or not m.group(1):
+            continue
+        if m.group(2) == "custom-call" and "AllocateBuffer" not in line:
+            continue
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= elements:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _custom_calls(hlo: str):
+    return collections.Counter(
+        m.group(1) for m in re.finditer(
+            r"%(\w+?)(?:\.\d+)? = .* custom-call\(.*"
+            r"custom_call_target=\"tpu_custom_call\"", hlo))
+
+
+@pytest.fixture(scope="module")
+def mixtral_stage(topo):
+    cfg = dataclasses.replace(get_config("mixtral-8x22b"),
+                              n_layers=STAGE["layers"],
+                              attn_pattern=("global",), window_size=None)
+    model = TransformerLM(cfg)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    policy = ShardingPolicy.for_mesh(mesh)
+    table = PageTable(model, STAGE["batch"], STAGE["max_ctx"],
+                      STAGE["page_size"], None)
+    step, psh, csh = build_decode_step(
+        model, mesh, policy, batch=STAGE["batch"],
+        cache_len=STAGE["max_ctx"], per_slot_pos=True,
+        cache_factory=table.init_cache, decode_backend="pallas_paged")
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=s), tree, shardings)
+
+    params = placed(jax.eval_shape(lambda: model.init(jax.random.key(0))),
+                    psh)
+    cache = placed(jax.eval_shape(table.init_cache), csh)
+    assert cache["groups"][0].kp.shape == (4, STAGE["kv_pages"], 16, 1024)
+    rep = NamedSharding(mesh, P())
+    vec = jax.ShapeDtypeStruct((STAGE["batch"],), jnp.int32, sharding=rep)
+    decode = step.lower(params, cache, vec, vec).compile().as_text()
+    pre = build_prefill_step(model, mesh, policy, cache_len=STAGE["max_ctx"],
+                             batch=1)[0]
+    prefill = pre.lower(
+        params, jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=rep)
+    ).compile().as_text()
+    return cfg, decode, prefill
+
+
+def test_mixtral_stage_kernels_are_custom_calls(mixtral_stage):
+    """The paged kernel runs on each chip's own heads and the grouped
+    matmul on each chip's expert share (gate, up and down), in decode
+    and in prefill."""
+    _, decode, prefill = mixtral_stage
+    assert _custom_calls(decode) == {"paged_decode_attention": 1,
+                                     "grouped_matmul": 3}
+    assert _custom_calls(prefill)["grouped_matmul"] == 3
+
+
+def test_mixtral_stage_collectives(mixtral_stage):
+    """Decode: in the layer loop (compiled once, as a while loop) one
+    sum of ``[slots, 1, d_model]`` after attention and one after the
+    experts; outside it the embedding's sum and the vocabulary-split
+    logits' gather.  Prefill: the same sums at ``[1, tokens, d_model]``
+    and nothing else."""
+    cfg, decode, prefill = mixtral_stage
+    d, b = cfg.d_model, STAGE["batch"]
+    got = collections.Counter(
+        (op, shape) for shape, op in _COLLECTIVE.findall(decode))
+    assert got == {("all-reduce", f"bf16[{b},1,{d}]"): 3,
+                   ("all-gather", f"f32[{b},{cfg.vocab_size}]"): 1}, got
+    assert decode.count("while(") == 1
+    got = collections.Counter(
+        (op, shape) for shape, op in _COLLECTIVE.findall(prefill))
+    assert got == {("all-reduce", f"bf16[1,1024,{d}]"): 3}, got
+
+
+def test_mixtral_stage_moves_no_pool_and_no_expert_weight(mixtral_stage):
+    """No copy, slice, gather or allocation of a pool, or of as much as
+    one expert's weight slice on a chip, in either step: the pools are
+    updated in place and the grouped matmul reads every layer's stacked
+    expert weights where they lie."""
+    cfg, decode, prefill = mixtral_stage
+    one_slice = cfg.d_model * cfg.d_ff // cfg.moe_virtual_split // 4
+    assert _pool_moves(decode, STAGE["kv_pages"]) == []
+    assert _big_moves(decode, one_slice) == []
+    assert _big_moves(prefill, one_slice) == []
