@@ -36,17 +36,17 @@ buys nothing.
 
 The kernel removes the copy:
 
-* **Grid layout** — ``(batch_slot, logical_page)`` with the page axis
-  innermost; one step takes one whole pool page of one layer, all KV
-  heads (block ``(None, None, page_size, kv_heads*head_dim)`` — the TPU
-  lowering requires a block's last two dims to be tile multiples or the
-  array's own).  The pool row is lane-dense, every KV head side by
-  side, so the query is laid out block-diagonally in VMEM and one
-  matmul scores every query head against its own KV head's lanes only.
-  TPU grids are sequential over the last dimension, so the
-  online-softmax state (running max, running sum, fp32 output
-  accumulator, per query head) lives in VMEM scratch across one slot's
-  page walk.
+* **Grid layout** — ``(batch_slot, page_block)`` with the block axis
+  innermost; one step takes a block of ``P`` logical pages of one
+  slot, each a whole pool page of one layer, all KV heads
+  (``pages_per_block``: about 512 KiB of K, from the shapes alone; a
+  grid step has a fixed cost on the chip, so one-page steps were the
+  kernel's largest cost).  The pool row is lane-dense, every KV head
+  side by side, so the query is laid out block-diagonally in VMEM and
+  one matmul scores every query head against its own KV head's lanes
+  only.  TPU grids are sequential, so the online-softmax state
+  (running max, running sum, fp32 output accumulator, per query head)
+  lives in VMEM scratch across one slot's walk.
 * **Pool layout** — ``[layers, n_pages, page_size,
   kv_heads*head_dim]``.  With ``head_dim`` (64 for qwen) as the minor
   axis the TPU's default layout put the page axis minor-most: every
@@ -57,15 +57,19 @@ The kernel removes the copy:
   carries the stacked pools through its layer scan and the kernel takes
   the layer as a third scalar-prefetch operand, so no pool is sliced,
   copied or restacked (guarded by ``tests/test_tpu_compile.py``).
-* **Block-table index map** — the block table, per-slot positions and
-  the layer index are scalar-prefetch operands
+* **Block-table DMA** — the block table, per-slot positions and the
+  layer index are scalar-prefetch operands
   (:class:`~jax.experimental.pallas.tpu.PrefetchScalarGridSpec`); the
-  K/V BlockSpec index maps evaluate ``(layer, block[b, j])`` so the pipeline
-  DMAs exactly one pool page HBM->VMEM per grid step, in block-table
-  order.  Ring/append validity, sliding windows, softcap, and the
-  partial tail page are reconstructed in-kernel from ``pos`` alone
-  (matching ``attention._cache_positions``), and pages with no valid
-  row take a block-level early exit.
+  pools stay in HBM and the kernel copies each page ``(layer,
+  block[b, j])`` of a block that holds a valid row into a
+  double-buffered VMEM block, starting the next grid step's copies
+  before it computes on its own.  Ring/append validity, sliding
+  windows, softcap, and the partial tail page are reconstructed
+  in-kernel from ``pos`` alone (matching
+  ``attention._cache_positions``): in scalar code for the pages to
+  copy, per row for the masks, V included, so rows of pages not
+  copied never reach the sums.  A block with no valid row costs no
+  copy and no vector work.
 * **Why no gather** — the gather costs a full logical-view read+write
   per layer per step regardless of context occupancy and defeats the
   energy model's point (telemetry now accounts that phantom traffic on

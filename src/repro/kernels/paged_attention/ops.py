@@ -40,12 +40,13 @@ def _pallas_cost(eqn) -> KernelCost:
     pos, layer, q, kp, vp)``, with ``kp``/``vp`` the stacked
     ``[layers, n_pages, page_size, kv_heads*head_dim]`` pools.  The
     scalar-prefetch operands (block, pos, layer) and q (index map
-    depends only on outer grid axes) stream once; the K/V page blocks
-    are driven by the *data-dependent* block-table index map, which the
-    grid walks once per (batch, logical_page) — every logical page's
-    physical page of the one layer is DMA'd whole, all KV heads at
-    once, which is exactly ``TrafficModel.kv_page_read_bytes`` at full
-    occupancy.  The output block is written once per batch slot.
+    depends only on outer grid axes) stream once.  K and V are billed
+    for the full walk, every logical page's physical page of the one
+    layer DMA'd whole, all KV heads at once — exactly
+    ``TrafficModel.kv_page_read_bytes`` at full occupancy.  That is an
+    upper bound: the kernel copies only the pages that hold a valid
+    row, which the avals cannot tell.  The output block is written
+    once per batch slot.
     """
     block, pos, layer, q, kp, vp = eqn.invars
     b, n_lp = block.aval.shape

@@ -713,6 +713,8 @@ class ServeEngine:
         # slot insertion is a pure copy/scatter for every layer kind.
         self._prefill = build_prefill_step(
             model, mesh, policy, cache_len=self.max_ctx, batch=1)[0]
+        #: (rows a block, blocks a slot) of the paged kernel's walk
+        self._kernel_walk = None
         sh = self.paged.sharing if self.paged is not None else None
         self._sharing = sh if (sh is not None and sh.enabled) else None
         if self.paged is not None:
@@ -728,6 +730,9 @@ class ServeEngine:
                 decode_backend=self.decode_backend, shards=shards)
             self._table.bind_shardings(self._cache_sh)
             self._insert = None
+            if decode_backend == "pallas_paged" and any(
+                    k in ("global", "local") for k in model.cfg.all_kinds):
+                self._kernel_walk = self._block_walk(mesh, policy)
         else:
             self._table = None
             self._decode, _, self._cache_sh = build_decode_step(
@@ -751,6 +756,20 @@ class ServeEngine:
         #: the last decode step's rows per layer and expert (device array;
         #: models with experts)
         self.expert_rows = None
+
+    def _block_walk(self, mesh: Mesh, policy: ShardingPolicy):
+        """(rows of one kernel block, blocks a slot) of the paged kernel's
+        walk over a whole-context block table, at the pool width each
+        device's kernel sees."""
+        from repro.kernels.paged_attention.kernel import pages_per_block
+        cfg, page = self.model.cfg, self.paged.page_size
+        split = _model_split(self.model, policy,
+                             dict(zip(mesh.axis_names, mesh.devices.shape)))
+        n_lp = -(-self.max_ctx // page)
+        ppb = pages_per_block(
+            page, cfg.n_kv_heads // split * cfg.resolved_head_dim,
+            jnp.dtype(cfg.dtype).itemsize, n_lp)
+        return page * ppb, -(-n_lp // ppb)
 
     def _resolve_shards(self) -> int:
         """Device-local pool extents for the paged cache geometry.
@@ -1459,6 +1478,11 @@ class ServeEngine:
                     self._table.count_pages()
                 active = [s for s in range(B) if slots[s] is not None]
                 ctx = [int(pos_vec[s]) + 1 for s in active]
+                if self._kernel_walk is not None and spans.recording():
+                    rows, blocks = self._kernel_walk
+                    spans.count("paged_attention.blocks", blocks * len(ctx))
+                    spans.count("paged_attention.blocks_live",
+                                sum(-(-c // rows) for c in ctx))
                 with spans.span("serve.decode") as dec_span:
                     logits, cache = self.decode_step(cache, tok_vec, pos_vec)
                     keys = self._keys(base, req_vec, emit_vec)

@@ -32,7 +32,12 @@ KV pages, summed over decode steps; ``page_table.assigns`` and
 for a model with experts, ``moe.rows``, the rows its decode steps
 routed, summed over steps, layers and experts, and ``moe.rows_max``,
 the busiest expert's rows in each layer and step, summed (read from the
-step's rows per expert, pulled with the tokens).
+step's rows per expert, pulled with the tokens); on the paged kernel's
+path, ``paged_attention.blocks``, the live slots times the kernel's
+blocks a slot, and ``paged_attention.blocks_live``, the blocks of
+those slots that hold a row of their context, summed over decode steps
+(one layer's walk over a table of the whole context, counted on the
+host from the slots' positions).
 """
 from __future__ import annotations
 

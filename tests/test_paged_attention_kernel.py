@@ -6,7 +6,12 @@ Contract under test, at three altitudes:
   kernel, interpret mode) matches ``backend="ref"`` (gather + dense
   softmax) over page sizes that do and don't divide the cache length
   (partial tail pages), ring wrap-around, per-slot positions, sliding
-  windows (including windows smaller than one page), and softcap.
+  windows (including windows smaller than one page and windows that
+  start mid-block), and softcap; over blocks of several pages (pool
+  widths whose pages make several blocks a slot, a last block cut short)
+  at positions whose live pages are one, two, one block, one block and
+  a page, and all; and over pools whose pages no valid row maps to hold
+  NaN.
 * **model level** — ``decode_step(..., decode_backend="pallas_paged")``
   on a paged cache tracks both the gather backend and the contiguous
   cache across lockstep greedy decoding on ALL 10 archs: logits agree
@@ -27,7 +32,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.experimental.pallas import tpu as pltpu
+
 from repro.configs import ARCH_IDS, get_config
+from repro.kernels.paged_attention import kernel as paged_kernel
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.models.transformer import TransformerLM
 from repro.serve import (PagedCacheConfig, PageTable, ServeEngine,
@@ -54,12 +62,28 @@ OP_CASES = [
     (2, 4, 2, 16, 2, 7, 3, None),         # window smaller than 2 pages
     (1, 1, 1, 8, 1, 6, 1, None),          # row-granular pages, window=1
     (2, 2, 3, 16, 24, 24, None, 50.0),    # one whole-cache page
+    # pools wide enough for several pages a block (f32 pages of 16 rows)
+    (2, 1, 1, 1024, 16, 160, None, None),  # F 1024, g 1: 8-page blocks of 10
+    (2, 2, 6, 128, 16, 640, 72, 30.0),    # F 256, g 6: 32 of 40, window
+    (3, 2, 2, 256, 16, 320, 40, None),    # F 512: 16 of 20, window 40
 ]
+
+
+def _slot_positions(rng, b, page, L, width):
+    """``b`` random positions straddling the ring boundary (pos >= L
+    wraps), then positions whose live pages are one, two, one block,
+    one block and a page, and all, then two past the wrap."""
+    ppb = paged_kernel.pages_per_block(page, width, 4, -(-L // page))
+    edges = [0, page, ppb * page - 1, ppb * page, L - 1, L + ppb * page,
+             2 * L - 1]
+    return np.concatenate([rng.integers(0, 2 * L, (b,)), edges])
 
 
 @pytest.mark.parametrize("b,kvh,g,hd,page,L,window,softcap", OP_CASES)
 def test_kernel_matches_gather_oracle(b, kvh, g, hd, page, L, window,
                                       softcap, rng):
+    pos = jnp.asarray(_slot_positions(rng, b, page, L, kvh * hd), jnp.int32)
+    b = pos.shape[0]
     n_lp = -(-L // page)
     n_pages = 2 + b * n_lp + 3
     q = jnp.asarray(rng.standard_normal((b, kvh, g, hd)), jnp.float32)
@@ -70,8 +94,6 @@ def test_kernel_matches_gather_oracle(b, kvh, g, hd, page, L, window,
     block = jnp.asarray(
         rng.permutation(np.arange(2, n_pages))[:b * n_lp].reshape(b, n_lp),
         jnp.int32)
-    # per-slot positions straddling the ring boundary (pos >= L wraps)
-    pos = jnp.asarray(rng.integers(0, 2 * L, (b,)), jnp.int32)
     ref = paged_attention(q, kp, vp, block, pos, cache_len=L, window=window,
                           softcap=softcap, backend="ref")
     pal = paged_attention(q, kp, vp, block, pos, cache_len=L, window=window,
@@ -85,6 +107,7 @@ STACKED_CASES = [
     (3, 2, 2, 3, 16, 5, 24, None, None),  # GQA 3, partial tail page
     (4, 3, 2, 2, 8, 4, 16, 6, 30.0),      # window + softcap
     (2, 2, 4, 1, 16, 3, 10, None, 50.0),  # MHA, ring wrap
+    (2, 2, 2, 1, 256, 16, 320, 40, None),  # F 512: 16-page blocks of 20
 ]
 
 
@@ -97,6 +120,8 @@ def test_kernel_stacked_pool_matches_ref_per_layer(layers, b, kvh, g, hd,
     by its scalar-prefetch layer index, where it lies: every layer holds
     different values, and each index matches the oracle run on that
     layer's pool alone."""
+    pos = jnp.asarray(_slot_positions(rng, b, page, L, kvh * hd), jnp.int32)
+    b = pos.shape[0]
     n_lp = -(-L // page)
     n_pages = 2 + b * n_lp + 3
     shape = (layers, n_pages, page, kvh * hd)
@@ -106,7 +131,6 @@ def test_kernel_stacked_pool_matches_ref_per_layer(layers, b, kvh, g, hd,
     block = jnp.asarray(
         rng.permutation(np.arange(2, n_pages))[:b * n_lp].reshape(b, n_lp),
         jnp.int32)
-    pos = jnp.asarray(rng.integers(0, 2 * L, (b,)), jnp.int32)
     kw = dict(cache_len=L, window=window, softcap=softcap)
     outs = []
     for layer in range(layers):
@@ -121,6 +145,53 @@ def test_kernel_stacked_pool_matches_ref_per_layer(layers, b, kvh, g, hd,
         outs.append(np.asarray(pal))
     # the layers differ, so reading the wrong one could not pass above
     assert not np.allclose(outs[0], outs[1])
+
+
+@pytest.mark.parametrize("kvh,g,hd,L,window", [
+    (1, 1, 1024, 160, None),      # 8-page blocks of 10, ring wrap
+    (2, 6, 128, 640, 72),         # 32-page blocks of 40, window
+])
+def test_kernel_ignores_poisoned_dead_rows(kvh, g, hd, L, window, rng,
+                                           monkeypatch):
+    """Every pool row that no valid row of any slot maps to holds NaN:
+    the pages no live row maps to, and the invalid rows of live pages.
+    The kernel fetches live pages only and masks V as well as the
+    scores, so its output stays finite and matches the oracle on the
+    clean pool.  It runs in TPU interpret mode, where a copy lands only
+    when it is waited on and fresh VMEM holds NaN, so a stale or
+    unfetched buffer row, or a page read before its wait, shows too."""
+    monkeypatch.setattr(paged_kernel, "pallas_interpret",
+                        lambda: pltpu.InterpretParams(
+                            dma_execution_mode="on_wait",
+                            uninitialized_memory="nan"))
+    page = 16
+    pos = _slot_positions(rng, 3, page, L, kvh * hd)
+    b, n_lp = pos.shape[0], -(-L // page)
+    n_pages = 2 + b * n_lp + 3
+    q = jnp.asarray(rng.standard_normal((b, kvh, g, hd)), jnp.float32)
+    kp = rng.standard_normal((n_pages, page, kvh * hd)).astype(np.float32)
+    vp = rng.standard_normal((n_pages, page, kvh * hd)).astype(np.float32)
+    block = rng.permutation(np.arange(2, n_pages))[:b * n_lp].reshape(b, n_lp)
+    kw = dict(cache_len=L, window=window)
+    ref = paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                          jnp.asarray(block, jnp.int32),
+                          jnp.asarray(pos, jnp.int32), backend="ref", **kw)
+    # the oracle's rule: slot s holds pos - ((pos % L - s) % L)
+    s = np.arange(n_lp * page)
+    kv_pos = pos[:, None] - ((pos[:, None] % L - s[None]) % L)
+    valid = (s[None] < L) & (kv_pos >= 0)
+    if window is not None:
+        valid &= kv_pos > pos[:, None] - window
+    keep = np.zeros((n_pages, page), bool)
+    keep[block.reshape(-1)] = valid.reshape(-1, page)
+    assert 0 < keep.sum() < keep.size
+    kp[~keep], vp[~keep] = np.nan, np.nan
+    pal = paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                          jnp.asarray(block, jnp.int32),
+                          jnp.asarray(pos, jnp.int32), backend="pallas", **kw)
+    assert np.isfinite(np.asarray(pal)).all()
+    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
+                               atol=2e-6, rtol=2e-6)
 
 
 def test_kernel_rejects_bad_pool_rank_or_width(rng):

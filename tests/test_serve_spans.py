@@ -153,6 +153,36 @@ def test_pages_live_match_the_contexts(engine, prompts, traced):
     assert "page_table.forks" not in rec.counts
 
 
+def test_kernel_blocks_match_the_contexts(engine, prompts, traced,
+                                         tmp_path, monkeypatch):
+    """On the paged kernel's path, two-page blocks (``BLOCK_BYTES`` cut to
+    two of this model's pages): every live slot counts the 4 blocks of
+    its 8-page table, and the blocks that hold its context; the tokens
+    are the gather engine's."""
+    from repro.kernels.paged_attention import kernel as paged_kernel
+    cfg = engine.model.cfg
+    page_bytes = PAGE * cfg.n_kv_heads * cfg.resolved_head_dim * 4
+    monkeypatch.setattr(paged_kernel, "BLOCK_BYTES", 2 * page_bytes)
+    jax.clear_caches()          # no kernel traced at another block size
+    try:
+        kern = ServeEngine(engine.model, engine.params, max_len=32,
+                           max_batch=3, paged=PagedCacheConfig(page_size=PAGE),
+                           decode_backend="pallas_paged")
+        kern.serve(prompts, 6)
+        rec, hooks, out = traced_serve(kern, prompts, tmp_path,
+                                       request_ids=IDS)
+    finally:
+        jax.clear_caches()
+    assert kern._kernel_walk == (2 * PAGE, 4)
+    ctxs = [c for ctx, _ in hooks.decode for c in ctx]
+    assert rec.counts["paged_attention.blocks"] == 4 * len(ctxs)
+    assert rec.counts["paged_attention.blocks_live"] == sum(
+        math.ceil(c / (2 * PAGE)) for c in ctxs)
+    for a, b in zip(traced[2], out):
+        np.testing.assert_array_equal(a, b)
+    assert "paged_attention.blocks" not in traced[0].counts
+
+
 def test_compiles_counted_inside_serve(engine, prompts, tmp_path):
     engine.serve(prompts, 6)
     rec, _, _ = traced_serve(engine, prompts, tmp_path / "warm")

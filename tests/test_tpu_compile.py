@@ -121,6 +121,52 @@ def test_paged_kernel_compiles_for_v5e(arch, cache_len, window, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# the two cells' kernel calls: 32 slots of 64 pages of 16 tokens, 2050
+# pool pages; qwen1.5-0.5b's 24 layers of 16 KV heads x 64 (F 1024,
+# 16-page blocks) and one chip's share of the Mixtral-8x22B stage, 4
+# layers of 2 KV heads x 128 and 6 query heads a KV head (F 256, one
+# 64-page block a slot)
+CELL_KERNELS = [
+    (24, 16, 1, 64, 16),
+    (4, 2, 6, 128, 64),
+]
+
+
+def _cell_kernel(layers, kvh, g, hd, one_chip, n_lp=64):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((layers, 32 * n_lp + 2, PAGE, kvh * hd), jnp.bfloat16)
+    step = jax.jit(lambda q, kp, vp, blk, pos, layer:
+                   paged_kernel.paged_decode_attention(
+                       q, kp, vp, blk, pos, layer, cache_len=n_lp * PAGE))
+    return step.lower(sds((32, kvh, g, hd), jnp.bfloat16), pool, pool,
+                      sds((32, n_lp), jnp.int32), sds((32,), jnp.int32),
+                      sds((), jnp.int32))
+
+
+@pytest.mark.parametrize("layers,kvh,g,hd,ppb", CELL_KERNELS)
+def test_paged_kernel_fits_vmem_at_cell_shapes(layers, kvh, g, hd, ppb,
+                                               one_chip, monkeypatch):
+    """The block walk at each cell's shapes: its block size, one kernel
+    call, and a compile within the default scoped VMEM limit (16 MiB on
+    a v5e), K and V double buffers included.  The compiler holds a
+    kernel to that limit: with blocks whose double buffers alone fill
+    it (a 1024-page table at ``BLOCK_BYTES`` 4 MiB) it refuses."""
+    assert paged_kernel.pages_per_block(PAGE, kvh * hd, 2, 64) == ppb
+    hlo = _cell_kernel(layers, kvh, g, hd, one_chip).compile().as_text()
+    assert _custom_calls(hlo) == {"paged_decode_attention": 1}
+    monkeypatch.setattr(paged_kernel, "BLOCK_BYTES", 4 * 2 ** 20)
+    big = paged_kernel.pages_per_block(PAGE, kvh * hd, 2, 1024)
+    assert 2 * 2 * big * PAGE * kvh * hd * 2 >= 16 * 2 ** 20
+    jax.clear_caches()
+    try:
+        with pytest.raises(Exception, match="vmem"):
+            _cell_kernel(1, kvh, g, hd, one_chip, n_lp=1024).compile()
+    finally:
+        jax.clear_caches()
+
+
 # the qwen1.5-0.5b.chat benchmark cell: 32 slots of max_ctx 1024 in
 # 16-token pages, 2050 pool pages (2048 + ZERO and DUMP)
 CELL = dict(batch=32, max_ctx=1024, page_size=16, kv_pages=2050)
