@@ -1,12 +1,24 @@
 """Serving stack: sharded step builders, the continuous-batching engine,
 paged cache management, and RTC traffic telemetry.
 
-``engine`` owns the compute (length-bucketed masked prefill, per-slot-
-position decode, unified per-request sampling); ``paging`` owns the
-cache residency (block tables, page pools, host offload); ``telemetry``
-owns the accounting (engine events -> DRAM bytes ->
+From the entry point down:
+
+* ``engine`` — :class:`ServeEngine` owns the compiled programs
+  (length-bucketed masked prefill, per-slot-position decode, unified
+  per-request sampling) and the cache layout; ``serve()`` checks its
+  arguments and hands the requests to
+* ``scheduler`` — a :class:`~repro.serve.scheduler.Scheduler` owns one
+  ``serve()`` call's state (the cache, the slots, the pending and
+  suspended queues, the first tokens still on the device, the prompt
+  memo) and runs admission, page growth, preemption and one decode
+  step at a time over
+* ``paging`` — the cache residency (block tables, page pools, host
+  offload) — and the engine's decode step.
+
+``telemetry`` owns the accounting (engine events -> DRAM bytes ->
 :class:`repro.core.workload.WorkloadProfile`), which is how serving
-traffic reaches the paper's RTC policy engine.
+traffic reaches the paper's RTC policy engine; its ``TelemetryHooks``
+states the events a sink may take.
 
 Paged-cache design note (PR 4)
 ------------------------------
@@ -93,13 +105,9 @@ physical KV pages, so N same-prefix requests allocate the prefix once.
   starvation) to co-schedule same-prefix requests while their pages
   are live; generations are bit-independent of the schedule because
   sampling keys are (request id, token index)-addressed.
-* **Full skip & suffix feed** — an exact whole-prompt hit on the
-  bounded memo skips prefill entirely (attach every page, restore the
-  host state snapshot, replay the memoized logits — bit-exact).  The
-  partial-prefix variant (``suffix_feed=True``) attaches the shared
-  full pages and feeds only the suffix through decode; it is opt-in
-  because prefill and decode-chain logits differ at float tolerance
-  (~1e-6), breaking the default bit-identity pin.
+* **Full skip** — an exact whole-prompt hit on the bounded memo skips
+  prefill entirely (attach every page, restore the host state
+  snapshot, replay the memoized logits — bit-exact).
 """
 from repro.serve.engine import (PrefillBuckets, Request, ServeEngine,
                                 build_decode_step, build_prefill_step,

@@ -25,9 +25,7 @@ policy engine.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -46,6 +44,8 @@ from repro.models.transformer import TransformerLM
 from repro.serve import spans
 from repro.serve.paging import (PagedCacheConfig, PageTable, PrefixKeys,
                                 prefix_page_keys, slot_floor)
+from repro.serve.scheduler import Scheduler
+from repro.serve.telemetry import TelemetryHooks
 
 __all__ = ["cache_specs", "build_prefill_step", "build_decode_step",
            "PrefillBuckets", "Request", "ServeEngine"]
@@ -547,44 +547,6 @@ class Request:
     keys: Optional[PrefixKeys] = None
 
 
-class _Slot:
-    """Mutable scheduler state of one occupied batch slot.
-
-    ``feed`` (suffix-feed sharing only): remaining prompt tokens to
-    teacher-force through the decode step before sampling starts; None
-    on every other path."""
-    __slots__ = ("req", "pos", "emitted", "out", "feed")
-
-    def __init__(self, req: Request, pos: int, first_token: int):
-        self.req = req
-        self.pos = pos            # next decode feed position
-        self.emitted = 1          # tokens sampled so far (incl. first)
-        self.out = [first_token]
-        self.feed = None
-
-
-class _Suspended:
-    """A preempted request: host-offloaded pages + scheduler state.
-
-    Created when the paged engine must reclaim a victim's device pages
-    mid-generation; resumed (bit-identically — sampling keys are
-    (request, token-index)-addressed) once a batch slot and enough free
-    pages exist.
-    """
-    __slots__ = ("req", "pos", "emitted", "out", "next_tok", "payload",
-                 "feed")
-
-    def __init__(self, req, pos, emitted, out, next_tok, payload,
-                 feed=None):
-        self.req = req
-        self.pos = pos
-        self.emitted = emitted
-        self.out = out
-        self.next_tok = next_tok
-        self.payload = payload
-        self.feed = feed
-
-
 class ServeEngine:
     """Continuous-batching serving loop over ``max_batch`` cache slots.
 
@@ -641,11 +603,13 @@ class ServeEngine:
     exact-duplicate prompt skips its prefill outright by replaying the
     memoized first-token logits and restoring recurrent state from a
     host snapshot, and the admission scheduler groups same-prefix
-    pending requests so their residency windows overlap.  Both default
-    paths are bit-identical to unshared serving on every arch (the
-    all-arch suite in ``tests/test_prefix_sharing.py`` pins it); the
-    opt-in ``suffix_feed`` path trades that guarantee for skipped
-    prefill compute on attention-only models.
+    pending requests so their residency windows overlap.  Both paths
+    are bit-identical to unshared serving on every arch (the all-arch
+    suite in ``tests/test_prefix_sharing.py`` pins it).
+
+    The engine owns the compiled programs and the cache layout; each
+    :meth:`serve` call's state (slots, queues, memo) lives in a
+    :class:`~repro.serve.scheduler.Scheduler`.
     """
 
     def __init__(self, model: TransformerLM, params: dict,
@@ -1051,9 +1015,9 @@ class ServeEngine:
         (greedy and stochastic requests batch together; each request's
         generation matches its solo serve bit-for-bit).  ``eos_id``
         overrides the engine default for this call.  ``telemetry`` is an
-        optional sink with ``record_prefill(plen, dt, padded_len)`` /
-        ``record_decode(ctx_lengths, dt)`` hooks — see
-        :class:`repro.serve.telemetry.ServeTelemetry`; prefill traffic
+        optional sink with any of the hooks
+        :class:`repro.serve.telemetry.TelemetryHooks` lists (all of them:
+        :class:`repro.serve.telemetry.ServeTelemetry`); prefill traffic
         is accounted from true prompt lengths, never padded ones.  A
         sink carrying a ``trace``
         (:class:`repro.core.trace.PageAccessTrace`) additionally gets
@@ -1074,14 +1038,26 @@ class ServeEngine:
         while a profiler trace is being collected.
         """
         with spans.span("serve.call") as call:
-            return self._serve(prompts, max_new_tokens, temperature, top_k,
-                               seed, eos_id, telemetry, request_ids,
-                               call.start_ns)
+            requests = self._requests(prompts, max_new_tokens, temperature,
+                                      top_k, request_ids)
+            hooks = TelemetryHooks(telemetry)
+            # which decode path moves the KV bytes: the gather path's
+            # materialized logical view is traffic the kernel never makes
+            hooks.configure_decode(backend=self.decode_backend,
+                                   paged=self._table is not None)
+            if max_new_tokens == 0:
+                return [np.zeros((0,), np.int32) for _ in requests]
+            eos = self.eos_id if eos_id is None else eos_id
+            sched = Scheduler(self, requests, hooks, seed, eos,
+                              t_entry=call.start_ns)
+            while sched.busy():
+                sched.step()
+            return sched.finish()
 
-    def _serve(self, prompts, max_new_tokens, temperature, top_k, seed,
-               eos_id, telemetry, request_ids, t_entry: int):
-        """:meth:`serve`'s body; ``t_entry`` stamps the call's entry
-        (``time.perf_counter_ns``), where each queue wait starts."""
+    def _requests(self, prompts, max_new_tokens, temperature, top_k,
+                  request_ids) -> List[Request]:
+        """:meth:`serve`'s arguments checked and made into requests,
+        ``order`` their input index."""
         if max_new_tokens < 0:
             raise ValueError("max_new_tokens must be >= 0")
         n_req = len(prompts)
@@ -1105,20 +1081,9 @@ class ServeEngine:
                     f"trace attribution, so two requests sharing one "
                     f"would silently alias")
             seen[rid] = i
-        out_index = seen      # req_id -> position in `prompts`/outputs
-        if telemetry is not None:
-            # tell the sink which decode path moves the KV bytes (the
-            # gather path's materialized logical view is real traffic
-            # the kernel path never generates); hasattr-guarded so
-            # plain-duck-typed sinks keep working.
-            conf = getattr(telemetry, "configure_decode", None)
-            if conf is not None:
-                conf(backend=self.decode_backend,
-                     paged=self._table is not None)
-        eos = self.eos_id if eos_id is None else eos_id
         vocab = self.model.cfg.vocab_size
-        temps = self._per_request(temperature, len(prompts), "temperature")
-        top_ks = self._per_request(top_k, len(prompts), "top_k")
+        temps = self._per_request(temperature, n_req, "temperature")
+        top_ks = self._per_request(top_k, n_req, "top_k")
         for i, (t, tk) in enumerate(zip(temps, top_ks)):
             if tk is not None and tk < 1:
                 raise ValueError(
@@ -1130,412 +1095,15 @@ class ServeEngine:
                 raise ValueError(
                     f"temperature must be finite and >= 0, got {t} "
                     f"(request {i})")
-        sharing = self._sharing
         requests = []
         for i, (p, t, tk) in enumerate(zip(prompts, temps, top_ks)):
             prompt = self._admit_prompt(p, i)
             keys = (prefix_page_keys(prompt, self.paged.page_size)
-                    if sharing is not None else None)
+                    if self._sharing is not None else None)
             requests.append(Request(
                 rids[i], prompt, max_new_tokens, temperature=float(t),
                 top_k=vocab if tk is None else int(tk), order=i, keys=keys))
-        outputs: List[Optional[np.ndarray]] = [None] * len(requests)
-        if max_new_tokens == 0:
-            return [np.zeros((0,), np.int32) for _ in requests]
-
-        B = self.max_batch
-        paged = self._table is not None
-        use_top_k = any(r.top_k != vocab for r in requests)
-
-        # Page-access trace: page ids are page-table state, so recording
-        # rides the host-side scheduling loop — nothing is added to the
-        # jitted steps.  Accesses that happen *between* decode steps
-        # (admission scatters, restore writes, offload reads) accumulate
-        # in pending_pages and fold into the next step's record.
-        trace = getattr(telemetry, "trace", None) if telemetry else None
-        if trace is not None:
-            if not paged:
-                raise ValueError(
-                    "telemetry.trace set but the engine is not paged — "
-                    "page-access traces need a PageTable (pass "
-                    "paged=PagedCacheConfig(...) at engine build)")
-            names = self._table.stream_names()
-            if tuple(trace.stream_names) != names:
-                raise ValueError(
-                    f"telemetry.trace streams {trace.stream_names} do not "
-                    f"match this engine's page table streams {names}")
-        pending_pages: Dict[int, set] = {}
-
-        def note_pages(s: int):
-            """Fold slot ``s``'s current page set into the next record."""
-            if trace is not None:
-                for si, pids in self._table.slot_page_ids(s):
-                    pending_pages.setdefault(si, set()).update(pids)
-
-        def sample(logits, keys, temps_, topks_):
-            return self._sample(logits, keys, temps_, topks_, use_top_k)
-
-        base = jax.random.key(seed)
-        cache = self.new_cache()
-        slots: List[Optional[_Slot]] = [None] * B
-        tok_vec = np.zeros((B,), np.int32)
-        pos_vec = np.zeros((B,), np.int32)
-        req_vec = np.zeros((B,), np.int32)
-        emit_vec = np.zeros((B,), np.int32)
-        temp_vec = np.zeros((B,), np.float32)
-        topk_vec = np.full((B,), vocab, np.int32)
-        if sharing is not None and sharing.schedule == "prefix":
-            # prefix-aware admission: group same-prefix requests so
-            # their residency windows overlap (sharing is an in-flight
-            # property — a registered page lives only while a slot
-            # holds it).  Group order is first arrival, so no group
-            # starves; generations are bit-independent of the schedule
-            # (sampling keys are (request, token-index)-addressed).
-            groups: Dict[bytes, List[Request]] = {}
-            for r in requests:
-                groups.setdefault(r.keys.group, []).append(r)
-            pending = collections.deque(
-                r for grp in groups.values() for r in grp)
-        else:
-            pending = collections.deque(requests)
-        suspended: collections.deque = collections.deque()
-        # whole-prompt memo: keys.whole -> (first-token logits, state
-        # snapshot, plen).  Host-resident, per serve call, FIFO-capped;
-        # an exact-duplicate prompt whose pages are all still registered
-        # admits through PageTable.admit_cached with no prefill at all.
-        memo: Dict[bytes, tuple] = {}
-
-        def occupy(s: int, st: _Slot, next_tok: int):
-            slots[s] = st
-            tok_vec[s], pos_vec[s] = next_tok, st.pos
-            req_vec[s], emit_vec[s] = st.req.req_id, st.emitted
-            temp_vec[s], topk_vec[s] = st.req.temperature, st.req.top_k
-
-        def retire(s: int):
-            nonlocal cache
-            st = slots[s]
-            outputs[out_index[st.req.req_id]] = np.asarray(st.out, np.int32)
-            slots[s] = None
-            if paged:
-                with spans.span("page_table.release"):
-                    cache = self._table.release(cache, s)
-
-        def finished(st: _Slot, token: int) -> bool:
-            if st.emitted >= st.req.max_new_tokens:
-                return True
-            if eos is not None and token == eos:
-                return True
-            return st.pos >= self.max_ctx    # logical context exhausted
-
-        def suspend(victim: int):
-            """Preempt a live slot: offload its pages to host."""
-            nonlocal cache
-            st = slots[victim]
-            note_pages(victim)   # offload reads every held page (before pop)
-            cache, payload = self._table.offload(cache, victim, st.pos)
-            suspended.append(_Suspended(st.req, st.pos, st.emitted, st.out,
-                                        int(tok_vec[victim]), payload,
-                                        feed=st.feed))
-            slots[victim] = None
-            if telemetry is not None:
-                telemetry.record_page_out(st.pos)
-
-        def grow():
-            """Assign the pages this step's writes need; when a pool
-            runs dry, preempt the NEWEST live request — including the
-            grower itself, which then suspends and waits FIFO — so the
-            oldest admitted request is only ever victimized by its own
-            elders (FCFS progress is preserved).  Pages are
-            shard-local, so only slots pinned to the grower's shard can
-            free the pages it needs — victims come from that shard."""
-            nonlocal cache
-            cow: List[Tuple[int, int]] = []
-            order = sorted((s for s in range(B) if slots[s] is not None),
-                           key=lambda s: slots[s].req.order)
-            for s in order:
-                if slots[s] is None:
-                    continue                 # preempted by an earlier grower
-                while slots[s] is not None:
-                    cache, ok = self._table.prepare_step(
-                        cache, s, int(pos_vec[s]), cow_events=cow)
-                    if ok:
-                        break
-                    g = self._table.shard_of(s)
-                    victims = [v for v in range(B) if slots[v] is not None
-                               and self._table.shard_of(v) == g]
-                    victim = max(victims, key=lambda v: slots[v].req.order)
-                    if victim == s and len(victims) == 1:
-                        raise RuntimeError(   # pragma: no cover
-                            "paged cache: resident-page budget exhausted "
-                            "with a single live slot in its shard — "
-                            "unreachable when every per-shard extent "
-                            "covers one full slot")
-                    suspend(victim)
-            if cow and telemetry is not None:
-                rec = getattr(telemetry, "record_cow", None)
-                if rec is not None:
-                    for _, layer_tokens in cow:
-                        rec(layer_tokens)
-
-        # Plain admissions of one pass dispatch back to back and their
-        # first tokens come to the host in one pull at the end of the
-        # pass, so the host dispatches ahead of the device instead of
-        # waiting on each request's token.  The pull runs inside the
-        # last admission's ``serve.admit`` span, which stays open until
-        # the pass ends or another kind of admission comes next.
-        landing: List[tuple] = []      # (slot, _Slot, first token, span)
-        open_admit: List[spans.span] = []
-        freed = False
-
-        def close_admit():
-            while open_admit:
-                open_admit.pop().__exit__(None, None, None)
-
-        def land():
-            """Pull the landing admissions' first tokens and hand each
-            to its slot and telemetry, in admission order."""
-            nonlocal freed
-            if not landing:
-                return
-            try:
-                with spans.span("serve.first_token"):
-                    firsts = jax.device_get([a[2] for a in landing])
-            finally:
-                close_admit()
-            for (s, st, _, adm_span), first in zip(landing, firsts):
-                first = int(first[0])
-                st.out[0] = tok_vec[s] = first
-                if telemetry is not None:
-                    plen = st.req.prompt.shape[0]
-                    telemetry.record_prefill(
-                        plen, adm_span.seconds,
-                        padded_len=self.buckets.bucket_for(plen))
-                if finished(st, first):
-                    retire(s)           # this slot admits again
-                    freed = True
-            landing.clear()
-
-        def admit():
-            nonlocal freed
-            try:
-                while True:
-                    freed = False
-                    admit_pass()
-                    land()
-                    if not (freed and (pending or suspended)):
-                        break
-            finally:
-                close_admit()
-
-        def admit_pass():
-            nonlocal cache
-            for s in range(B):
-                while slots[s] is None and (pending or suspended):
-                    if suspended:
-                        land()
-                        # resume FIFO before admitting new work; if the
-                        # oldest suspension cannot fit yet, wait for
-                        # pages (live slots will retire) rather than
-                        # admitting page-hungry new requests around it.
-                        sp = suspended[0]
-                        if not self._table.can_restore(sp.payload, s):
-                            break
-                        suspended.popleft()
-                        cache = self._table.restore(cache, s, sp.payload)
-                        note_pages(s)   # restore writes the new pages
-                        st = _Slot(sp.req, pos=sp.pos, first_token=0)
-                        st.out, st.emitted = sp.out, sp.emitted
-                        st.feed = sp.feed
-                        occupy(s, st, sp.next_tok)
-                        if telemetry is not None:
-                            telemetry.record_page_in(sp.payload.tokens)
-                        continue
-                    req = pending[0]
-                    plen = req.prompt.shape[0]
-                    keys = req.keys
-                    if (paged and sharing is not None
-                            and keys.whole in memo
-                            and self._table.can_admit_cached(s, plen, keys)):
-                        land()
-                        # full skip: the exact prompt prefilled earlier
-                        # and every page is still registered — attach it
-                        # all, restore recurrent state from the host
-                        # snapshot, and replay the memoized first-token
-                        # logits (bit-identical: both round trips are
-                        # exact).  No prefill executable runs.
-                        pending.popleft()
-                        spans.add("serve.queue_wait", t_entry,
-                                  time.perf_counter_ns(), "serve.call",
-                                  req.req_id)
-                        mlogits, msnap, _ = memo[keys.whole]
-                        cache = self._table.admit_cached(
-                            cache, s, plen, keys, msnap)
-                        note_pages(s)
-                        adm = self._table.last_admit
-                        if telemetry is not None:
-                            rec = getattr(telemetry, "record_admit_shared",
-                                          None)
-                            if rec is not None:
-                                rec(plen, adm["attached_layer_tokens"],
-                                    adm["total_layer_tokens"],
-                                    skipped_prefill=True)
-                        key = self._keys(base,
-                                         np.asarray([req.req_id], np.int32),
-                                         np.zeros((1,), np.int32))
-                        first = int(np.asarray(sample(
-                            jnp.asarray(mlogits), key,
-                            np.asarray([req.temperature], np.float32),
-                            np.asarray([req.top_k], np.int32)))[0])
-                        st = _Slot(req, pos=plen, first_token=first)
-                        occupy(s, st, first)
-                        if finished(st, first):
-                            retire(s)
-                        continue
-                    if paged and not self._table.can_admit(plen, s, keys):
-                        break                # wait for pages to free
-                    if paged and sharing is not None and sharing.suffix_feed:
-                        k = self._table.joint_prefix_pages(s, keys, plen)
-                        if k > 0:
-                            land()
-                            # opt-in suffix feed (attention-only):
-                            # attach the resident prefix pages and
-                            # teacher-force the novel suffix through
-                            # the decode step — no prefill, no new
-                            # executables, tolerance-level (not
-                            # bitwise) parity with the prefill path.
-                            pending.popleft()
-                            spans.add("serve.queue_wait", t_entry,
-                                      time.perf_counter_ns(), "serve.call",
-                                      req.req_id)
-                            ktok = k * self.paged.page_size
-                            cache = self._table.attach_prefix(
-                                cache, s, keys, k)
-                            note_pages(s)
-                            adm = self._table.last_admit
-                            if telemetry is not None:
-                                rec = getattr(telemetry,
-                                              "record_admit_shared", None)
-                                if rec is not None:
-                                    rec(plen, adm["attached_layer_tokens"],
-                                        adm["total_layer_tokens"],
-                                        suffix_feed=True)
-                            st = _Slot(req, pos=ktok, first_token=0)
-                            st.out, st.emitted = [], 0
-                            st.feed = collections.deque(
-                                int(t) for t in req.prompt[ktok + 1:])
-                            occupy(s, st, int(req.prompt[ktok]))
-                            continue
-                    pending.popleft()
-                    close_admit()
-                    adm_span = spans.span("serve.admit", request=req.req_id)
-                    open_admit.append(adm_span.__enter__())
-                    logits, cache, one = self.prefill_into(
-                        cache, s, req.prompt, keys)
-                    if paged and sharing is not None:
-                        adm = self._table.last_admit
-                        if telemetry is not None:
-                            rec = getattr(telemetry,
-                                          "record_admit_shared", None)
-                            if rec is not None:
-                                rec(plen, adm["attached_layer_tokens"],
-                                    adm["total_layer_tokens"])
-                        if (sharing.memo_size > 0
-                                and self._table.fully_shareable(plen)
-                                and keys.whole not in memo):
-                            memo[keys.whole] = (
-                                np.asarray(logits),
-                                self._table.state_snapshot(one), plen)
-                            while len(memo) > sharing.memo_size:
-                                memo.pop(next(iter(memo)))
-                    if paged:
-                        note_pages(s)   # admission scatters the prefill
-                    with spans.span("serve.first_token"):
-                        key = self._keys(
-                            base, np.asarray([req.req_id], np.int32),
-                            np.zeros((1,), np.int32))
-                        first = sample(
-                            logits, key,
-                            np.asarray([req.temperature], np.float32),
-                            np.asarray([req.top_k], np.int32))
-                    spans.add("serve.queue_wait", t_entry, adm_span.start_ns,
-                              "serve.call", req.req_id)
-                    st = _Slot(req, pos=plen, first_token=0)
-                    occupy(s, st, 0)            # its token lands later
-                    landing.append((s, st, first, adm_span))
-
-        admit()
-        while any(st is not None for st in slots) or suspended or pending:
-            if all(st is None for st in slots):
-                admit()
-                if all(st is None for st in slots):  # pragma: no cover
-                    raise RuntimeError(
-                        "serve stalled: no slot admissible — resident-page "
-                        "budget cannot hold any pending/suspended request")
-            with spans.span("serve.step"):
-                if paged:
-                    with spans.span("page_table.grow"):
-                        grow()
-                    self._table.count_pages()
-                active = [s for s in range(B) if slots[s] is not None]
-                ctx = [int(pos_vec[s]) + 1 for s in active]
-                if self._kernel_walk is not None and spans.recording():
-                    rows, blocks = self._kernel_walk
-                    spans.count("paged_attention.blocks", blocks * len(ctx))
-                    spans.count("paged_attention.blocks_live",
-                                sum(-(-c // rows) for c in ctx))
-                with spans.span("serve.decode") as dec_span:
-                    logits, cache = self.decode_step(cache, tok_vec, pos_vec)
-                    keys = self._keys(base, req_vec, emit_vec)
-                    toks = sample(logits, keys, jnp.asarray(temp_vec),
-                                  jnp.asarray(topk_vec))
-                with spans.span("serve.token_pull") as pull_span:
-                    if self.expert_rows is not None and spans.recording():
-                        toks, rows = jax.device_get((toks, self.expert_rows))
-                        spans.count("moe.rows", int(rows.sum()))
-                        spans.count("moe.rows_max",
-                                    int(rows.max(axis=-1).sum()))
-                    else:
-                        toks = np.asarray(toks)
-                spans.count("serve.decode_steps")
-                if telemetry is not None:
-                    telemetry.record_decode(
-                        ctx, dec_span.seconds + pull_span.seconds)
-                if trace is not None:
-                    # one trace step per decode step: every active slot's
-                    # resident pages (allocate-on-write: residency == the
-                    # context this step's KV sweep reads; the append lands
-                    # in the same set after grow()) plus whatever moved
-                    # between steps, with the weights re-streamed.
-                    for s in active:
-                        note_pages(s)
-                    trace.record_step(pending_pages, param_read=True)
-                    pending_pages.clear()
-                for s in active:
-                    st = slots[s]
-                    if st.feed is not None and st.feed:
-                        # suffix feed: this step consumed a prompt token;
-                        # its sampled draw is discarded (emit_vec stays 0,
-                        # so the eventual first token still uses sampling
-                        # key (request, 0)) and the next prompt token
-                        # rides the next step.
-                        st.pos += 1
-                        tok_vec[s], pos_vec[s] = st.feed.popleft(), st.pos
-                        continue
-                    st.feed = None   # last fed step falls through: its
-                    token = int(toks[s])   # draw IS the first emitted token
-                    st.out.append(token)
-                    st.emitted += 1
-                    st.pos += 1
-                    tok_vec[s], pos_vec[s], emit_vec[s] = (token, st.pos,
-                                                           st.emitted)
-                    if finished(st, token):
-                        retire(s)
-                admit()
-        if trace is not None and pending_pages:
-            # trailing page moves with no decode step after them (e.g. a
-            # final admission that retired on its prefill token)
-            trace.record_step(pending_pages, param_read=False)
-        return outputs  # type: ignore[return-value]
+        return requests
 
     # -------------------------------------------------------------- generate
     def generate(self, prompts: np.ndarray, n_new: int,

@@ -184,18 +184,6 @@ class PrefixSharingConfig:
                        arrival order.  Generations are bit-independent
                        of the schedule (sampling keys are (request,
                        token-index)-addressed), only the hit rate moves.
-    ``suffix_feed``  — opt-in compute skip for *proper*-prefix hits on
-                       attention-only models: attach the cached prefix
-                       pages and teacher-force only the novel suffix
-                       through the existing decode executable (zero new
-                       lowered executables).  Decode-path arithmetic is
-                       tolerance-equal, not bitwise-equal, to prefill
-                       (~1e-6 logit drift), so this mode trades the
-                       bit-identity guarantee for skipped prefill
-                       compute — hence opt-in.  The default sharing
-                       paths (dedup-attach and the full-prompt memo
-                       skip, which replays the memoized prefill logits
-                       exactly) stay bit-identical.
     ``memo_size``    — full-prompt memo entries kept per serve call
                        (prefill logits + recurrent-state snapshot,
                        host-resident; FIFO eviction).
@@ -203,7 +191,6 @@ class PrefixSharingConfig:
 
     enabled: bool = True
     schedule: str = "prefix"
-    suffix_feed: bool = False
     memo_size: int = 64
 
     def __post_init__(self):
@@ -491,8 +478,8 @@ class PageTable:
             "pages_registered": 0, "pages_attached": 0,
             "cow_forks": 0, "full_attaches": 0}
         # per-stream layer-token accounting of the most recent admit /
-        # admit_cached / attach_prefix — the engine turns this into the
-        # telemetry prefix-hit traffic class
+        # admit_cached — the scheduler turns this into the telemetry
+        # prefix-hit traffic class
         self.last_admit: Optional[Dict[str, int]] = None
 
         self.bind_shardings(cache_shardings)
@@ -1089,67 +1076,6 @@ class PageTable:
             args.append((jnp.asarray(bvec),
                          jnp.asarray(min(plen, st.cache_len), jnp.int32)))
         self.stats["full_attaches"] += 1
-        self.last_admit = adm
-        zeros, _ = self._reserved_ids(slot)
-        return self._attach_jit(cache, jnp.asarray(slot, jnp.int32),
-                                tuple(args), zeros)
-
-    def joint_prefix_pages(self, slot: int, keys: Optional[PrefixKeys],
-                           plen: int) -> int:
-        """Longest run of *full* prompt pages resident in ``slot``'s
-        shard across **every** KV stream (the suffix-feed attach
-        depth), capped so at least one prompt token remains to feed.
-        Returns 0 for recurrent models (state is not addressable by
-        token prefix) or when any stream cannot share."""
-        if keys is None:
-            return 0
-        g = self.shard_of(slot)
-        k = min((plen - 1) // self.page_size, len(keys.full))
-        for st in self.streams:
-            if st.is_state or not self._shareable(st, plen):
-                return 0
-            run = 0
-            for j in range(k):
-                if st.shared[g].get(keys.full[j]) is None:
-                    break
-                run += 1
-            k = min(k, run)
-            if k == 0:
-                return 0
-        return k
-
-    def attach_prefix(self, cache, slot: int, keys: PrefixKeys, k: int):
-        """Suffix-feed admission: attach the first ``k`` full prompt
-        pages of every KV stream from the registry and nothing else —
-        the engine teacher-forces the remaining prompt tokens through
-        the decode step, which allocates its own write pages via
-        :meth:`prepare_step`."""
-        g = self.shard_of(slot)
-        args = []
-        adm = {"attached_pages": 0, "registered_pages": 0,
-               "attached_layer_tokens": 0, "total_layer_tokens": 0}
-        for si, st in enumerate(self.streams):
-            if st.is_state:
-                raise PageTableError(
-                    f"attach_prefix: stream {st.where} (kind={st.kind!r}) "
-                    f"is recurrent state; suffix-feed sharing is "
-                    f"attention-only")
-            layers = self._stream_layers(st)
-            held: Dict[int, int] = {}
-            bvec = np.full((st.n_lp,), -1, np.int32)
-            for j in range(k):
-                pid = st.shared[g][keys.full[j]]
-                st.ref[pid] += 1
-                held[j] = pid
-                bvec[j] = pid
-                adm["attached_pages"] += 1
-                adm["attached_layer_tokens"] += self.page_size * layers
-                adm["total_layer_tokens"] += self.page_size * layers
-                self.stats["pages_attached"] += 1
-            st.slot_pages[slot] = held
-            args.append((jnp.asarray(bvec),
-                         jnp.asarray(min(k * self.page_size, st.cache_len),
-                                     jnp.int32)))
         self.last_admit = adm
         zeros, _ = self._reserved_ids(slot)
         return self._attach_jit(cache, jnp.asarray(slot, jnp.int32),

@@ -71,7 +71,7 @@ from typing import Optional, Sequence, Tuple
 from repro.core.workload import WorkloadProfile, from_decode
 from repro.models.config import ModelConfig
 
-__all__ = ["TrafficModel", "ServeTelemetry"]
+__all__ = ["TrafficModel", "ServeTelemetry", "TelemetryHooks"]
 
 _ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
 
@@ -241,6 +241,51 @@ class TrafficModel:
         return cls
 
 
+def _ignore(*args, **kwargs) -> None:
+    """A hook the sink does not have."""
+
+
+class TelemetryHooks:
+    """The serve loop's telemetry protocol, over any sink or ``None``.
+
+    :meth:`ServeEngine.serve <repro.serve.engine.ServeEngine.serve>`
+    wraps its ``telemetry`` argument in one of these, and the scheduler
+    calls the hooks on it unconditionally.  A sink may have any subset
+    of them; a hook it lacks is a no-op here:
+
+    * ``configure_decode(backend, paged)``: once a call, before any
+      request runs: the engine's ``decode_backend`` and whether its
+      cache is paged;
+    * ``record_prefill(plen, dt, padded_len)``: a prefilled admission's
+      first token reached the host; ``plen`` true prompt tokens,
+      ``dt`` the ``serve.admit`` span's seconds, ``padded_len`` the
+      prefill bucket;
+    * ``record_decode(ctx_lengths, dt)``: one decode step over the live
+      slots' contexts; ``dt`` the ``serve.decode`` plus
+      ``serve.token_pull`` spans' seconds;
+    * ``record_page_out(ctx)``, ``record_page_in(ctx)``: a slot holding
+      ``ctx`` tokens offloaded to the host, or restored;
+    * ``record_admit_shared(plen, hit_layer_tokens, total_layer_tokens,
+      skipped_prefill=False)``: an admission under prefix sharing;
+    * ``record_cow(layer_tokens)``: one copy-on-write fork.
+
+    ``trace`` is the sink's :class:`repro.core.trace.PageAccessTrace`,
+    which a paged engine appends each decode step's page accesses to,
+    or ``None`` when the sink has none.  :class:`ServeTelemetry` has
+    every hook.
+    """
+
+    HOOKS = ("configure_decode", "record_prefill", "record_decode",
+             "record_page_out", "record_page_in", "record_admit_shared",
+             "record_cow")
+    __slots__ = HOOKS + ("trace",)
+
+    def __init__(self, sink=None):
+        for name in self.HOOKS:
+            setattr(self, name, getattr(sink, name, _ignore))
+        self.trace = getattr(sink, "trace", None)
+
+
 class ServeTelemetry:
     """Accumulates engine events and emits the RTC workload profile.
 
@@ -307,7 +352,6 @@ class ServeTelemetry:
         # ADDS to the device.
         self.prefix_admits = 0           # admissions that touched keys
         self.prefix_full_skips = 0       # whole-prompt memo admissions
-        self.prefix_suffix_feeds = 0     # opt-in suffix-feed admissions
         self.prefix_hit_tokens = 0       # layer-tokens attached, not written
         self.prefix_hit_bytes_total = 0    # hit layer-tokens as KV bytes
         self.admit_write_bytes_total = 0   # novel admission KV+state bytes
@@ -382,8 +426,7 @@ class ServeTelemetry:
 
     def record_admit_shared(self, plen: int, hit_layer_tokens: int,
                             total_layer_tokens: int,
-                            skipped_prefill: bool = False,
-                            suffix_feed: bool = False) -> None:
+                            skipped_prefill: bool = False) -> None:
         """One prefix-aware admission, split hit vs written.
 
         ``hit_layer_tokens`` — (layer, token) cells served by attaching
@@ -400,10 +443,7 @@ class ServeTelemetry:
         ``skipped_prefill`` marks a full-prompt memo admission: no
         prefill executable ran, but the request still emits its first
         token off the memoized logits, so it accounts as one prefill
-        event of ``plen`` true tokens with zero pad waste.
-        ``suffix_feed`` marks the opt-in teacher-forced path (its novel
-        suffix bills as ordinary decode steps, so only the attached
-        prefix appears here)."""
+        event of ``plen`` true tokens with zero pad waste."""
         bpt = self.traffic.kv_page_token_bytes
         hit = int(hit_layer_tokens)
         total = int(total_layer_tokens)
@@ -422,8 +462,6 @@ class ServeTelemetry:
             self.prefill_tokens += int(plen)
             self.prefill_padded_tokens += int(plen)
             self.tokens_generated += 1
-        if suffix_feed:
-            self.prefix_suffix_feeds += 1
 
     def record_cow(self, layer_tokens: int) -> None:
         """One copy-on-write fork: ``layer_tokens`` (layer, token)

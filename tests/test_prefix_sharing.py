@@ -7,8 +7,7 @@ allocate the shared prefix pages **once** — the saving shows up in the
 page table's allocation stats, in telemetry's ``prefix_hit`` traffic
 class (whose exact-sum invariant against the unshared total is pinned
 here, including across preempt/restore), and in the page-access trace's
-per-step row set.  Also covers the duplicate-request-id rejection and
-the opt-in suffix-feed mechanism.
+per-step row set.  Also covers the duplicate-request-id rejection.
 """
 import collections
 
@@ -381,24 +380,3 @@ def test_trace_row_set_shrinks_under_sharing():
     assert all(a <= b for a, b in zip(tr_s.step_page_counts(),
                                       tr_u.step_page_counts()))
 
-
-# ---------------------------------------------------------------------------
-# suffix feed (opt-in)
-# ---------------------------------------------------------------------------
-def test_suffix_feed_mechanism():
-    """Opt-in suffix feed: a request extending a live request's full
-    prefix pages attaches them and teacher-forces only its suffix; it
-    emits the full requested generation length."""
-    cfg, eng = _engine(
-        "qwen1.5-0.5b",
-        PrefixSharingConfig(suffix_feed=True, memo_size=0), max_batch=2)
-    rng = np.random.default_rng(12)
-    a = rng.integers(0, cfg.vocab_size, (12,)).astype(np.int32)
-    b = np.concatenate([a[:8], rng.integers(
-        0, cfg.vocab_size, (4,)).astype(np.int32)])   # shares page 0
-    tele = _tele(cfg)
-    out = eng.serve([a, b], 8, temperature=[50.0, 50.0], seed=6,
-                    telemetry=tele)
-    assert tele.prefix_suffix_feeds >= 1
-    assert eng.page_table.stats["pages_attached"] > 0
-    assert all(o.shape[0] == 8 for o in out)

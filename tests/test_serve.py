@@ -11,7 +11,8 @@ from repro.configs import get_config
 from repro.core.dram import module
 from repro.core.rtc import Variant, evaluate
 from repro.models.transformer import TransformerLM
-from repro.serve import ServeEngine, ServeTelemetry, TrafficModel
+from repro.serve import (PagedCacheConfig, ServeEngine, ServeTelemetry,
+                         TrafficModel)
 from repro.train.optimizer import (AdamWConfig, adamw_init, adamw_update,
                                    cosine_schedule, global_norm)
 
@@ -318,6 +319,49 @@ def test_telemetry_workload_profile(engine, mixed_prompts):
     spec = module(4)
     rep = evaluate(spec, w, Variant.FULL_RTC_PLUS)
     assert 0.0 < rep.refresh_savings <= 1.0
+
+
+class _MinimalSink:
+    """Three hooks, as the benchmark's timestamp sink has: every other
+    hook the serve loop calls (``record_page_in``, ``record_cow``, ...)
+    and ``trace`` are missing."""
+
+    def __init__(self):
+        self.prefill, self.decode, self.page_out = [], [], []
+
+    def record_prefill(self, plen, dt=0.0, padded_len=None):
+        self.prefill.append(plen)
+
+    def record_decode(self, ctx_lengths, dt=0.0):
+        self.decode.append(list(ctx_lengths))
+
+    def record_page_out(self, ctx):
+        self.page_out.append(ctx)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+def test_minimal_sink_serves(qwen, mixed_prompts, paged):
+    """A sink with some of the hooks serves the tokens a serve without
+    telemetry does, and its hooks fire as often as
+    :class:`ServeTelemetry`'s.  The paged engine's budget of 8 pages
+    preempts, so the missing ``record_page_in`` is called too."""
+    cfg, model, params = qwen
+    kw = {"paged": PagedCacheConfig(page_size=8, resident_pages=8)} \
+        if paged else {}
+    eng = ServeEngine(model, params, max_len=32, max_batch=3, **kw)
+    want = eng.serve(mixed_prompts, 20)
+    sink = _MinimalSink()
+    got = eng.serve(mixed_prompts, 20, telemetry=sink)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    full = ServeTelemetry(TrafficModel.from_config(cfg, max_len=32))
+    eng.serve(mixed_prompts, 20, telemetry=full)
+    assert sink.prefill == [p.shape[0] for p in mixed_prompts]
+    assert len(sink.decode) == full.decode_steps
+    assert len(sink.prefill) + sum(map(len, sink.decode)) == sum(
+        o.shape[0] for o in got)
+    assert len(sink.page_out) == full.page_outs == full.page_ins
+    assert (full.page_outs > 0) == paged
 
 
 def test_traffic_model_accounting():
