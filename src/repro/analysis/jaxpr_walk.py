@@ -25,6 +25,15 @@ rules mirror how XLA treats the equations:
   the output continues the operand's identity (``inplace``), so the
   buffer is never billed as a fresh full-size write at the jaxpr
   boundary.
+* a scatter or dynamic_update_slice of a **constant fill** (a
+  broadcast literal) into a paged KV pool — the zeroing of a freshly
+  assigned page — bills ``fill_write``, a derived class, not the pool's
+  write class.  A constant written into any other resident buffer
+  (state, block tables) bills that buffer's own class.
+* **while** (a data-dependent trip count) walks its body once, as one
+  iteration: the page table's assignment loop, one iteration an
+  assigned page.  Only derived classes (fills, block tables) may move
+  inside one; a gated class there is a walker gap.
 * **scan** multiplies its body's bytes by the trip count; cache leaves
   ride through keeping their taint, as xs/ys slices or, for the paged
   KV pools the decode step updates in place, as carry.  Stacking the
@@ -84,8 +93,11 @@ TRAFFIC_CLASSES = (
     "kv_sweep_read", "kv_page_read", "kv_append_write",
     "state_read", "state_write",
     "gather_view_read", "gather_view_write",
-    "meta_read", "meta_write", "param_read", "param_write",
+    "meta_read", "meta_write", "param_read", "param_write", "fill_write",
 )
+
+#: classes a data-dependent loop may move (per iteration, derived only)
+_LOOP_CLASSES = frozenset({"fill_write", "meta_read", "meta_write"})
 
 _STRUCTURAL = frozenset({
     "reshape", "transpose", "squeeze", "expand_dims", "broadcast_in_dim",
@@ -115,6 +127,11 @@ class Taint:
     resident: bool = True
     inplace: bool = True
     src: Optional[int] = None
+
+
+#: taint of a constant fill (a broadcast literal): not resident, and an
+#: in-place write of it into a KV pool bills ``fill_write``
+_FILL = Taint("fill", resident=False, inplace=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,6 +208,10 @@ class _Walker:
     def _eqn(self, eqn, env: Dict, mult: int) -> None:
         prim = eqn.primitive.name
 
+        if prim == "broadcast_in_dim" and _is_literal(eqn.invars[0]):
+            env[eqn.outvars[0]] = _FILL
+            return
+
         if prim in _STRUCTURAL or prim == "dynamic_slice":
             # same buffer, different view: free, taint flows through.
             # dynamic_slice start operands are scalars; bill them only
@@ -237,8 +258,7 @@ class _Walker:
             return
 
         if prim == "while":
-            self.problems.append(
-                "while: unbounded trip count not statically billable")
+            self._while(eqn, env, mult)
             return
 
         if prim in _HOST_SYNC:
@@ -283,7 +303,10 @@ class _Walker:
         for v in indices:
             self._read(env, v, mult)
         self._read(env, update, mult)    # a resident update is re-read
-        self.buckets[WRITE_BUCKET[t.cls]] += _aval_bytes(update.aval) * mult
+        bucket = ("fill_write" if t.cls == "kv_pool"
+                  and self._get(env, update) is _FILL
+                  else WRITE_BUCKET[t.cls])
+        self.buckets[bucket] += _aval_bytes(update.aval) * mult
         env[eqn.outvars[0]] = t          # in-place chain continues
 
     def _pallas(self, eqn, env: Dict, mult: int) -> None:
@@ -331,6 +354,20 @@ class _Walker:
             t = self._get(body_env, var)
             if t is not None:
                 env[outer] = t
+
+    def _while(self, eqn, env: Dict, mult: int) -> None:
+        """One iteration of a data-dependent loop; carries map through
+        like a scan's.  A gated class moving inside it is a gap."""
+        p = eqn.params
+        taints = [self._get(env, v) for v in eqn.invars[p["cond_nconsts"]:]]
+        before = dict(self.buckets)
+        self._sub(p["body_jaxpr"], taints, env, eqn.outvars, mult)
+        moved = [c for c in TRAFFIC_CLASSES if c not in _LOOP_CLASSES
+                 and self.buckets[c] != before[c]]
+        if moved:
+            self.problems.append(
+                f"while: unbounded trip count not statically billable "
+                f"({', '.join(moved)})")
 
     def _shard_map(self, eqn, env: Dict, mult: int) -> None:
         """Manual-mesh (shard_map) region: walk the body once on its

@@ -276,7 +276,8 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
                       policy: ShardingPolicy, batch: int, cache_len: int,
                       kv_seq_axis=None, per_slot_pos: bool = False,
                       cache_factory=None, decode_backend: str = "gather",
-                      donate_cache: bool = True, shards: int = 1):
+                      donate_cache: bool = True, shards: int = 1,
+                      assign=None):
     """One-token decode with sharded KV cache. Returns
     (step_fn, param_shardings, cache_shardings).
 
@@ -328,9 +329,21 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
     always correct (the global-id layout decodes unmapped as-is) but
     gathers the pools around the kernel.
 
+    ``assign``: the page table's pending decode-growth assignments ride
+    the step (:meth:`repro.serve.paging.PageTable.apply_assignments`,
+    which ``assign`` is).  The step then takes ``(params, cache, host)``
+    with ``host`` one int32 ``[2 + 2k, batch]`` array — tokens,
+    positions, the ``k`` KV streams' page ids and their logical page
+    indices (:meth:`~repro.serve.paging.PageTable.fold_pending`) — and
+    applies the assignments before the layer scan, each device to its
+    own pool extent under ``shard_map``.  Needs ``per_slot_pos``.
+
     A model with experts returns a third output, the rows each expert
     of each layer was routed (``decode_step(..., expert_rows=True)``).
     """
+    if assign is not None and not per_slot_pos:
+        raise ValueError("assign rides per-slot decode steps only "
+                         "(per_slot_pos=True)")
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     pspecs = param_specs(jax.eval_shape(
         lambda: model.init(jax.random.key(0))), policy)
@@ -349,9 +362,24 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
     else:
         pos_sh = NamedSharding(mesh, P())
 
+    in_sh = (psh, csh) + ((tok_sh, pos_sh) if assign is None else (
+        NamedSharding(mesh, P(None, policy.batch_spec if batch > 1
+                              else None)),))
+
     with_rows = bool(model.cfg.n_experts)
 
-    def decode(params, cache, token, pos):
+    def inputs(cache, args, shard=0):
+        """(cache with the assignments applied, token, pos) of the
+        step's host arguments."""
+        if assign is None:
+            return (cache,) + tuple(args)
+        (host,) = args
+        k = (host.shape[0] - 2) // 2
+        return (assign(cache, host[2:2 + k], host[2 + k:], shard),
+                host[0], host[1])
+
+    def decode(params, cache, *args):
+        cache, token, pos = inputs(cache, args)
         seq_override = kv_seq_axis if kv_seq_axis is not None else policy.seq_axis
         with axis_env(batch_axes=policy.data_axes if batch > 1 else None,
                       model_axis=policy.model_axis,
@@ -381,14 +409,15 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
         m = policy.model_axis if split > 1 else None
         manual = (m, split) if split > 1 else None
 
-        def body(params, cache, token, pos):
+        def body(params, cache, *args):
             # flat data-shard index, from static axis sizes (partition-id
             # arithmetic only: the body's only collectives are the model
             # axis's sums of each layer's partial outputs)
             g = jnp.int32(0)
             for a in policy.data_axes:
                 g = g * sizes.get(a, 1) + jax.lax.axis_index(a)
-            local = _local_kv_heads(_shift_block_ids(cache, -g), split)
+            local, token, pos = inputs(
+                _local_kv_heads(_shift_block_ids(cache, -g), split), args, g)
             # mesh=None env: `constrain` is the identity — the body is
             # already device-local, GSPMD has nothing to place.
             with axis_env(batch_axes=None, model_axis=None, seq_axis=None,
@@ -412,13 +441,15 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
         # the routed rows are computed alike on every device
         smap = jax.shard_map(
             body, mesh=mesh,
-            in_specs=(pspecs, cspecs, P(bspec),
-                      P(bspec) if per_slot_pos else P()),
+            in_specs=(pspecs, cspecs) + (
+                (P(bspec), P(bspec) if per_slot_pos else P())
+                if assign is None else (P(None, bspec),)),
             out_specs=(P(bspec, m), cspecs) + ((P(),) if with_rows else ()),
             check_vma=False)
 
-        def decode_sm(params, cache, token, pos):
-            out = smap(params, cache, token, pos)
+        def decode_sm(params, cache, *args):
+            out = smap(params, cache, *args)
+            pos = args[1] if assign is None else args[0][1]
             new_cache = jax.tree.map(
                 lambda new: (dataclasses.replace(
                     new, length=jnp.broadcast_to(
@@ -435,7 +466,7 @@ def build_decode_step(model: TransformerLM, mesh: Mesh,
 
     step = jax.jit(
         spans.named("serve_decode", fn),
-        in_shardings=(psh, csh, tok_sh, pos_sh),
+        in_shardings=in_sh,
         out_shardings=(NamedSharding(mesh, P(
             policy.batch_spec if batch > 1 else None, None)), csh)
         + ((NamedSharding(mesh, P()),) if with_rows else ()),
@@ -691,7 +722,8 @@ class ServeEngine:
                 model, mesh, policy, batch=self.max_batch,
                 cache_len=self.max_ctx, per_slot_pos=True,
                 cache_factory=self._table.init_cache,
-                decode_backend=self.decode_backend, shards=shards)
+                decode_backend=self.decode_backend, shards=shards,
+                assign=self._table.apply_assignments)
             self._table.bind_shardings(self._cache_sh)
             self._insert = None
             if decode_backend == "pallas_paged" and any(
@@ -811,7 +843,8 @@ class ServeEngine:
                     cache_len=self.max_ctx, per_slot_pos=True,
                     cache_factory=self._table.init_cache,
                     decode_backend=self.decode_backend,
-                    shards=self._table.shards)
+                    shards=self._table.shards,
+                    assign=self._table.apply_assignments)
                 insert_fn = None
             else:
                 decode_fn, _, cache_sh = build_decode_step(
@@ -828,13 +861,16 @@ class ServeEngine:
         else:
             cache = jax.eval_shape(
                 lambda: self.model.init_cache(B, self.max_len))
-        tok = jax.ShapeDtypeStruct((B,), jnp.int32)
-        pos = jax.ShapeDtypeStruct((B,), jnp.int32)
+        if self._table is not None:
+            host = (jax.ShapeDtypeStruct(
+                (2 + 2 * len(self._table.kv_streams), B), jnp.int32),)
+        else:
+            host = (jax.ShapeDtypeStruct((B,), jnp.int32),) * 2
         arts = [dict(
-            name="decode", fn=decode_fn, args=(aparams, cache, tok, pos),
+            name="decode", fn=decode_fn, args=(aparams, cache) + host,
             roles={0: "params", 1: "cache"},
             expect_donate_argnums=(1,),
-            shardings=(None, cache_sh, None, None))]
+            shardings=(None, cache_sh) + (None,) * len(host))]
         top = self.buckets.ladder[-1]
         arts.append(dict(
             name="prefill", fn=prefill_fn,
@@ -984,13 +1020,22 @@ class ServeEngine:
 
     def decode_step(self, cache, tokens: np.ndarray, positions: np.ndarray):
         """One decode step over every slot: ``tokens`` and ``positions``
-        are ``[max_batch]`` int32.  A paged cache must already hold the
-        page each slot writes (``page_table.prepare_step``).  Returns
-        ``(logits [max_batch, vocab] f32, cache)``; a model with experts
-        keeps the step's rows per expert and layer, on the device, in
-        ``expert_rows``."""
-        out = self._decode(self.params, cache, jnp.asarray(tokens),
-                           jnp.asarray(positions))
+        are ``[max_batch]`` int32.  A paged cache must already hold, or
+        have pending, the page each slot writes
+        (``page_table.prepare_step``): the step applies the pending
+        assignments, sent in one host array with the tokens and
+        positions.  Returns ``(logits [max_batch, vocab] f32, cache)``;
+        a model with experts keeps the step's rows per expert and
+        layer, on the device, in ``expert_rows``."""
+        if self._table is None:
+            out = self._decode(self.params, cache, jnp.asarray(tokens),
+                               jnp.asarray(positions))
+        else:
+            host = np.empty((2 + 2 * len(self._table.kv_streams),
+                             self.max_batch), np.int32)
+            host[0], host[1] = tokens, positions
+            self._table.fold_pending(host[2:])
+            out = self._decode(self.params, cache, host)
         if len(out) == 3:
             self.expert_rows = out[2]
         return out[0], out[1]

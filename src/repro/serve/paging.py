@@ -92,7 +92,10 @@ from repro.serve import spans
 
 __all__ = ["PagedCacheConfig", "PageTable", "PagePayload", "PageTableError",
            "PrefixSharingConfig", "PrefixKeys", "prefix_page_keys",
-           "logical_view", "slot_floor"]
+           "logical_view", "slot_floor", "NO_PAGE"]
+
+#: a pending-assignment entry that assigns nothing
+NO_PAGE = -1
 
 
 class PageTableError(RuntimeError):
@@ -402,6 +405,16 @@ class PageTable:
     output can be pinned to the decode step's shardings
     (``cache_shardings``), so the admit/decode/offload round trip stays
     layout-stable on real meshes.
+
+    Decode growth is host-only: :meth:`prepare_step` picks a fresh page
+    and records it in a pending row (one int32 entry per slot and KV
+    stream, :data:`NO_PAGE` for none).  The decode step takes the rows
+    (:meth:`fold_pending`) and applies them before its layer scan
+    through :meth:`apply_assignments` — zero the page, write its block
+    entry — so no program runs between steps.  Every other program of
+    the table first applies what is still pending with the flush program
+    (:meth:`flush`), built from the same function; a caller that decodes
+    through the bare model flushes first.
     """
 
     def __init__(self, model: TransformerLM, max_batch: int, max_ctx: int,
@@ -482,6 +495,15 @@ class PageTable:
         # prefix-hit traffic class
         self.last_admit: Optional[Dict[str, int]] = None
 
+        #: KV stream indices, in the order of the pending rows
+        self.kv_streams: Tuple[int, ...] = tuple(
+            si for si, st in enumerate(self.streams) if not st.is_state)
+        # pending assignments: [0] page ids, [1] logical page indices,
+        # one row per KV stream, one entry per slot
+        self._pending = np.full((2, len(self.kv_streams), self.max_batch),
+                                NO_PAGE, np.int32)
+        self._n_pending = 0
+
         self.bind_shardings(cache_shardings)
 
     def shard_of(self, slot: int) -> int:
@@ -497,7 +519,7 @@ class PageTable:
         self._csh = cache_shardings
         # donate the cache arg (as the decode step does): these ops
         # rewrite a slice of the pools, and without donation each admit/
-        # retire/page-assign would copy every pool buffer on device.
+        # retire/flush would copy every pool buffer on device.
         # fetch must NOT donate — offload reads pages out of a cache
         # that stays live.
         kw = {"donate_argnums": (0,)}
@@ -513,10 +535,8 @@ class PageTable:
                                           self._restore_fn), **kw)
         self._attach_jit = jax.jit(named("page_table_attach",
                                          self._attach_fn), **kw)
-        self._assign_jit = {
-            si: jax.jit(named(f"page_table_assign_{si}",
-                              functools.partial(self._assign_fn, si)), **kw)
-            for si, st in enumerate(self.streams) if not st.is_state}
+        self._flush_jit = jax.jit(named("page_table_flush",
+                                        self._flush_fn), **kw)
         self._fork_jit = {
             si: jax.jit(named(f"page_table_fork_{si}",
                               functools.partial(self._fork_fn, si)), **kw)
@@ -537,6 +557,8 @@ class PageTable:
         for k in self.stats:
             self.stats[k] = 0
         self.last_admit = None
+        self._pending.fill(NO_PAGE)
+        self._n_pending = 0
 
     # ------------------------------------------------------------- structure
     def _positions(self):
@@ -815,24 +837,29 @@ class PageTable:
                                   dataclasses.replace(pc, block=block))
         return cache
 
-    def _assign_fn(self, si, cache, slot, jdx, pid):
-        """Assign a zeroed page to logical page ``jdx`` of ``slot``
-        (decode growth: allocate-on-write at a page boundary)."""
-        st = self.streams[si]
-        pc = self._get(cache, st.where)
-        if st.where[0] == "groups":
-            pc = dataclasses.replace(
-                pc,
-                kp=pc.kp.at[:, pid].set(0),
-                vp=pc.vp.at[:, pid].set(0),
-                block=pc.block.at[:, slot, jdx].set(pid))
-        else:
-            pc = dataclasses.replace(
-                pc,
-                kp=pc.kp.at[pid].set(0),
-                vp=pc.vp.at[pid].set(0),
-                block=pc.block.at[slot, jdx].set(pid))
-        return self._replace(cache, st.where, pc)
+    def apply_assignments(self, cache, pids, jdxs, shard=0):
+        """Apply pending decode-growth assignments: for each KV stream
+        ``r`` and slot ``s`` with ``pids[r, s] != NO_PAGE``, zero page
+        ``pids[r, s]`` across the stream's stacked layers and point the
+        slot's logical page ``jdxs[r, s]`` at it.  ``pids``/``jdxs`` are
+        ``[len(kv_streams), b]`` int32 (``b`` the cache's slots).
+
+        The one rule of an assignment, shared by the decode step (which
+        applies the rows it is sent before its layer scan) and the flush
+        program.  ``shard`` is the data shard whose pool extent the
+        cache holds (inside the ``shard_map`` decode body, where pools
+        and block ids are device-local): page ids are global and move
+        by ``shard`` extents.  Only the assigned pages are touched: a
+        loop over the assigned entries, not over the slots."""
+        for r, si in enumerate(self.kv_streams):
+            st = self.streams[si]
+            pc = self._get(cache, st.where)
+            cache = self._replace(cache, st.where, _assign_pages(
+                pc, pids[r], jdxs[r], shard, st.where[0] == "groups"))
+        return cache
+
+    def _flush_fn(self, cache, rows):
+        return self.apply_assignments(cache, rows[0], rows[1])
 
     def _fork_fn(self, si, cache, slot, src, dst, jdx):
         """Copy-on-write fork: duplicate shared page ``src`` into the
@@ -956,6 +983,25 @@ class PageTable:
                       for st in self.streams)
         return zeros, dumps
 
+    def flush(self, cache):
+        """Apply the pending assignments with the flush program (no
+        dispatch when none is pending)."""
+        if not self._n_pending:
+            return cache
+        rows, self._n_pending = self._pending.copy(), 0
+        self._pending.fill(NO_PAGE)
+        return self._flush_jit(cache, rows)
+
+    def fold_pending(self, out: np.ndarray) -> None:
+        """Hand the pending assignments to the decode step: write the
+        page-id rows then the logical-page rows into ``out``
+        (``[2 * len(kv_streams), max_batch]`` int32) and clear them."""
+        k = len(self.kv_streams)
+        out[:k], out[k:] = self._pending
+        spans.count("page_table.assigns_folded", self._n_pending)
+        self._pending.fill(NO_PAGE)
+        self._n_pending = 0
+
     def admit(self, cache, one, slot: int, plen: int,
               keys: Optional[PrefixKeys] = None):
         """Allocate pages (from ``slot``'s shard extent) for a freshly
@@ -968,6 +1014,7 @@ class PageTable:
         prefill write for that row lands in DUMP); a miss allocates as
         before and registers the fresh page under its content key.
         ``keys=None`` is the original allocator, bit for bit."""
+        cache = self.flush(cache)
         g = self.shard_of(slot)
         pages, blocks = [], []
         adm = {"attached_pages": 0, "registered_pages": 0,
@@ -1040,6 +1087,7 @@ class PageTable:
         The KV length high-water mark is ``min(plen, cache_len)`` per
         stream, exactly what the skipped prefill's admit would have
         set."""
+        cache = self.flush(cache)
         g = self.shard_of(slot)
         args = []
         adm = {"attached_pages": 0, "registered_pages": 0,
@@ -1085,6 +1133,7 @@ class PageTable:
         """Free a retired slot's pages; its block rows return to DUMP.
         A shared (registered) page only drops one reference — it frees
         when its last holder lets go."""
+        cache = self.flush(cache)
         g = self.shard_of(slot)
         for st in self.streams:
             held = st.slot_pages.pop(slot, None)
@@ -1105,6 +1154,10 @@ class PageTable:
         slot.  Returns ``(cache, ok)``; ``ok`` is False when a pool is
         exhausted (the engine must preempt a victim and retry).
 
+        A fresh page is picked on the host and recorded as pending: the
+        decode step (or the flush program) zeroes it and writes the block
+        entry.
+
         Copy-on-write: when the write lands in a *shared* page
         (refcount > 1) the slot forks — a fresh page is allocated, the
         shared content copied device-side, and the block row
@@ -1116,10 +1169,10 @@ class PageTable:
 
         Invariant — *partial progress is committed*: page assignments
         (and forks) for streams visited before the exhausted one stay
-        in the cache and in ``slot_pages`` even on the ``ok=False``
-        return.  That is deliberate and safe: an assigned page is
-        recorded under its ``jdx``, so the post-preemption retry skips
-        it (a forked page is private, so the retry's ``ref`` probe
+        in ``slot_pages`` (and pending, or in the cache) even on the
+        ``ok=False`` return.  That is deliberate and safe: an assigned
+        page is recorded under its ``jdx``, so the post-preemption retry
+        skips it (a forked page is private, so the retry's ``ref`` probe
         skips it too) and only the still-missing streams act, and page
         content stays consistent until the decode step writes through
         the block table — generations are bit-identical to a serve
@@ -1127,9 +1180,8 @@ class PageTable:
         pins this).  Callers must not assume the cache is untouched
         when ``ok`` is False."""
         g = self.shard_of(slot)
-        for si, st in enumerate(self.streams):
-            if st.is_state:
-                continue
+        for r, si in enumerate(self.kv_streams):
+            st = self.streams[si]
             jdx = (pos % st.cache_len) // self.page_size
             held = st.slot_pages[slot]
             pid = held.get(jdx)
@@ -1145,7 +1197,7 @@ class PageTable:
                 st.ref[pid] -= 1
                 held[jdx] = dst
                 cache = self._fork_jit[si](
-                    cache, jnp.asarray(slot, jnp.int32),
+                    self.flush(cache), jnp.asarray(slot, jnp.int32),
                     jnp.asarray(pid, jnp.int32), jnp.asarray(dst, jnp.int32),
                     jnp.asarray(jdx, jnp.int32))
                 self.stats["cow_forks"] += 1
@@ -1158,9 +1210,10 @@ class PageTable:
                 return cache, False
             pid = st.free[g].pop()
             held[jdx] = pid
-            cache = self._assign_jit[si](
-                cache, jnp.asarray(slot, jnp.int32),
-                jnp.asarray(jdx, jnp.int32), jnp.asarray(pid, jnp.int32))
+            if self._pending[0, r, slot] != NO_PAGE:
+                cache = self.flush(cache)   # one pending page a slot
+            self._pending[:, r, slot] = pid, jdx
+            self._n_pending += 1
             spans.count("page_table.assigns")
         return cache, True
 
@@ -1184,6 +1237,7 @@ class PageTable:
         (``jax.device_get`` into numpy), so the content round-trips
         through host memory, not a device alias.
         """
+        cache = self.flush(cache)
         g = self.shard_of(slot)
         kv, state = {}, {}
         for si, st in enumerate(self.streams):
@@ -1230,6 +1284,7 @@ class PageTable:
         """Re-admit an offloaded slot: new pages (from ``slot``'s shard
         extent — any slot/shard, not necessarily the original), same
         bytes."""
+        cache = self.flush(cache)
         g = self.shard_of(slot)
         args = []
         for si, st in enumerate(self.streams):
@@ -1249,6 +1304,30 @@ class PageTable:
                              jnp.asarray(kpg), jnp.asarray(vpg)))
         return self._restore_jit(cache, jnp.asarray(slot, jnp.int32),
                                  tuple(args))
+
+
+def _assign_pages(pc: PagedKVCache, pids, jdxs, shard, grouped: bool):
+    """:meth:`PageTable.apply_assignments` on one KV stream: a loop over
+    the slots whose entry assigns a page (valid entries first), each
+    zeroing one page of every stacked layer in place and writing one
+    block entry."""
+    ok = pids != NO_PAGE
+    order = jnp.nonzero(ok, size=pids.shape[0], fill_value=0)[0]
+    pages = pids - shard * pc.kp.shape[-3]
+
+    def one(i, c):
+        kp, vp, block = c
+        s = order[i]
+        p = pages[s]
+        if grouped:
+            return (kp.at[:, p].set(0), vp.at[:, p].set(0),
+                    block.at[:, s, jdxs[s]].set(p))
+        return (kp.at[p].set(0), vp.at[p].set(0),
+                block.at[s, jdxs[s]].set(p))
+
+    kp, vp, block = jax.lax.fori_loop(0, jnp.sum(ok), one,
+                                      (pc.kp, pc.vp, pc.block))
+    return dataclasses.replace(pc, kp=kp, vp=vp, block=block)
 
 
 # ---------------------------------------------------------------------------

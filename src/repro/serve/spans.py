@@ -20,7 +20,8 @@ brackets):
   holds a second ``serve.first_token``, the pull of every first token
   of the pass to the host;
 * ``serve.step`` (``serve.call``): one decode loop iteration:
-  ``page_table.grow`` (the step's page assignments), ``serve.decode``
+  ``page_table.grow`` (the step's page assignments, picked on the host:
+  the decode step applies them), ``serve.decode``
   (decode, key and sample dispatches), ``serve.token_pull`` (the wait
   for the tokens), ``page_table.release`` (per retiring slot), admits.
 
@@ -28,6 +29,8 @@ Counters: ``serve.decode_steps``; ``page_table.pages_live`` and
 ``page_table.pages_pool``, the KV pages live slots hold and the pool's
 KV pages, summed over decode steps; ``page_table.assigns`` and
 ``page_table.forks``, pages ``prepare_step`` assigned or forked;
+``page_table.assigns_folded``, the assignments a decode step applied
+(the others went through the page table's flush program);
 ``serve.compiles``, programs compiled while ``serve.call`` was open;
 for a model with experts, ``moe.rows``, the rows its decode steps
 routed, summed over steps, layers and experts, and ``moe.rows_max``,

@@ -108,6 +108,55 @@ def test_dynamic_update_slice_bills_update_bytes_in_place():
     assert t is not None and t.inplace            # same buffer flows out
 
 
+def _page_loop(update):
+    """A data-dependent loop writing ``update(i)`` into page ``i`` of a
+    pool, ``n`` times, as the page table's assignment loop does."""
+    def f(pool, n):
+        return jax.lax.fori_loop(
+            0, n, lambda i, p: p.at[i].set(update(i)), pool)
+    return jax.make_jaxpr(f)(jnp.zeros((8, 4, 2), jnp.float32), 2)
+
+
+@pytest.mark.parametrize("fill,cls,gated", [
+    (True, "kv_pool", None),
+    (False, "kv_pool", "kv_append_write"),
+    (True, "state", "state_write"),
+], ids=["zero", "data", "state_zero"])
+def test_while_bills_one_iteration_of_derived_classes_only(fill, cls, gated):
+    """A loop with a data-dependent trip count walks its body once: a
+    constant fill of a pool page bills ``fill_write`` (one page) and
+    continues the pool's in-place chain; written data would be gated
+    ``kv_append_write`` traffic, which such a loop cannot bill.  Only a
+    KV pool's fill is derived: a constant written into a state buffer
+    stays gated ``state_write``."""
+    closed = _page_loop(
+        (lambda i: 0.0) if fill else
+        (lambda i: jnp.full((4, 2), i, jnp.float32)))
+    res = walk_jaxpr(closed, [Taint(cls, src=0), None])
+    assert res.outvar_taints[0] is not None and res.outvar_taints[0].inplace
+    if gated is None:
+        assert res.buckets["fill_write"] == 4 * 2 * 4
+        assert res.buckets["kv_append_write"] == 0
+        assert res.problems == []
+    else:
+        assert res.buckets["fill_write"] == 0
+        assert res.buckets[gated] == 4 * 2 * 4
+        assert res.problems == ["while: unbounded trip count not "
+                                f"statically billable ({gated})"]
+
+
+def test_constant_write_into_state_bills_state_write():
+    """Outside any loop, zeroing rows of a state buffer (as release and
+    state resets do) bills the buffer's own ``state_write``, not
+    ``fill_write``."""
+    closed = jax.make_jaxpr(lambda st: st.at[1].set(0.0))(
+        jnp.zeros((4, 8), jnp.float32))
+    res = walk_jaxpr(closed, [Taint("state", src=0)])
+    assert res.buckets["state_write"] == 8 * 4
+    assert res.buckets["fill_write"] == 0
+    assert res.problems == []
+
+
 def test_pool_gather_materializes_resident_view():
     pool = jnp.zeros((8, 4, 2), jnp.float32)      # 8 pages
     idx = jnp.array([0, 3, 1])

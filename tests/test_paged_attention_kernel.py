@@ -294,6 +294,7 @@ def _lockstep3(model, params, decode, tables, cache_c, caches,
                 caches[be], ok = table.prepare_step(
                     caches[be], s, int(pos[s]))
                 assert ok, f"{msg}: {be} pool exhausted at step {i}"
+            caches[be] = table.flush(caches[be])
         posj = jnp.asarray(pos)
         lc, cache_c = decode["gather"](params, cache_c, tok_c, posj)
         lg, caches["gather"] = decode["gather"](

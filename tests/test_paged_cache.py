@@ -29,6 +29,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.models.transformer import TransformerLM
 from repro.serve import (PagedCacheConfig, PageTable, ServeEngine,
                          ServeTelemetry, TrafficModel, logical_view)
+from repro.serve.paging import NO_PAGE
 
 MAX_CTX = 24     # logical context capacity (and contiguous cache length)
 BUCKET = 16      # padded prefill shape (one executable per arch)
@@ -95,10 +96,30 @@ def _build_pair(arch, plens, page_size=PAGE):
             np.asarray(toks, np.int32), np.asarray(plens, np.int32))
 
 
+_FOLDED = {}
+
+
+def _decode_folded(table, params, cache, tok, pos):
+    """The paged side's decode, as the engine's step runs it: one
+    program that applies the table's pending assignments
+    (``PageTable.apply_assignments``, handed over by ``fold_pending``)
+    and then runs the model's step."""
+    k = len(table.kv_streams)
+    if table not in _FOLDED:
+        _FOLDED[table] = jax.jit(
+            lambda p, c, t, q, rows: table.model.decode_step(
+                p, table.apply_assignments(c, rows[:k], rows[k:]), t, q))
+    rows = np.empty((2 * k, table.max_batch), np.int32)
+    table.fold_pending(rows)
+    return _FOLDED[table](params, cache, tok, pos, rows)
+
+
 def _lockstep(model, params, decode, table, cache_c, cache_p,
               tok, pos, steps, msg):
     """Decode both cache forms in lockstep, asserting bitwise equality
-    of per-step logits and of every resident page after each step."""
+    of per-step logits and of every resident page after each step.  The
+    paged side's step applies the page assignments ``prepare_step``
+    left pending."""
     tok_c = tok_p = jnp.asarray(tok)
     for i in range(steps):
         for s in range(pos.shape[0]):
@@ -106,7 +127,7 @@ def _lockstep(model, params, decode, table, cache_c, cache_p,
             assert ok, f"{msg}: pool exhausted at step {i}"
         posj = jnp.asarray(pos)
         lc, cache_c = decode(params, cache_c, tok_c, posj)
-        lp, cache_p = decode(params, cache_p, tok_p, posj)
+        lp, cache_p = _decode_folded(table, params, cache_p, tok_p, posj)
         np.testing.assert_array_equal(
             np.asarray(lc), np.asarray(lp),
             err_msg=f"{msg}: decode step {i} logits")
@@ -164,6 +185,7 @@ def test_unrolled_decode_matches_scan(arch, backend):
     for s in range(pos.shape[0]):
         cache_p, ok = table.prepare_step(cache_p, s, int(pos[s]))
         assert ok
+    cache_p = table.flush(cache_p)
     unrolled = TransformerLM(model.cfg, unroll=True)
     outs = [jax.jit(functools.partial(m.decode_step, decode_backend=backend))(
         params, cache_p, jnp.asarray(tok), jnp.asarray(pos))
@@ -318,6 +340,124 @@ def test_paged_engine_matches_contiguous_all_archs(arch):
     b = pag.serve(prompts, 20, temperature=temps, top_k=topks, seed=11)
     for i, (x, y) in enumerate(zip(a, b)):
         np.testing.assert_array_equal(x, y, err_msg=f"{arch} request {i}")
+
+
+def _count_calls(obj, attr):
+    """Wrap ``obj.attr`` on the instance; returns the list of calls."""
+    calls, fn = [], getattr(obj, attr)
+
+    def wrapped(*a, **k):
+        calls.append(a)
+        return fn(*a, **k)
+    setattr(obj, attr, wrapped)
+    return calls
+
+
+def test_decode_applies_assignments_with_no_program_between_steps():
+    """An unpressured paged serve grows through page assignments that
+    the decode step applies: the flush program never runs, and the
+    tokens match the contiguous engine's."""
+    cfg, ref, pag = _engine_pair(
+        "qwen1.5-0.5b", {"page_size": 4, "max_ctx": 32, "_max_len": 16},
+        ref_max_len=32)
+    table = pag.page_table
+    flushes = _count_calls(table, "_flush_jit")
+    assigns = _count_calls(table, "fold_pending")
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in (6, 3)]
+    a = ref.serve(prompts, 14, seed=2)
+    b = pag.serve(prompts, 14, seed=2)
+    for i, (x, y) in enumerate(zip(a, b)):
+        np.testing.assert_array_equal(x, y, err_msg=f"request {i}")
+    assert flushes == []
+    assert len(assigns) == 13             # one hand-off a decode step
+
+
+@pytest.mark.parametrize("arch,plens,kw", [
+    ("qwen1.5-0.5b", (5, 9, 3), dict(page_size=4, resident_pages=8)),
+    # gemma2's local ring and global streams: a grower that takes its
+    # local page and finds no global one is the newest slot, preempts
+    # itself and is offloaded holding its pending local assignment
+    ("gemma2-9b", (16, 7, 3), dict(page_size=4, resident_pages=9)),
+])
+def test_preempted_serve_matches_unconstrained_and_contiguous(arch, plens,
+                                                              kw):
+    """Preemption under a tight budget meets assignments still pending
+    (the flush program applies them before the offload): the tokens
+    match an unconstrained paged serve and the contiguous engine."""
+    cfg, ref, tight = _engine_pair(
+        arch, dict(kw, max_ctx=32, _max_len=16), ref_max_len=32,
+        max_batch=3)
+    ample = ServeEngine(ref.model, ref.params, max_len=16, max_batch=3,
+                        paged=PagedCacheConfig(page_size=kw["page_size"],
+                                               max_ctx=32))
+    table = tight.page_table
+    pending_victims = []
+    offload = table.offload
+
+    def watched(cache, slot, tokens):
+        pending_victims.append(
+            bool((table._pending[0, :, slot] != NO_PAGE).any()))
+        return offload(cache, slot, tokens)
+    table.offload = watched
+    flushes = _count_calls(table, "_flush_jit")
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in plens]
+    outs = [e.serve(prompts, 14, seed=11) for e in (ref, ample, tight)]
+    for i, (x, y, z) in enumerate(zip(*outs)):
+        np.testing.assert_array_equal(x, y, err_msg=f"{arch} ample {i}")
+        np.testing.assert_array_equal(x, z, err_msg=f"{arch} tight {i}")
+    assert pending_victims and flushes       # preempted over pending rows
+    if arch == "gemma2-9b":
+        assert any(pending_victims)
+
+
+def test_assignment_zeroes_one_page_and_writes_one_block_entry(monkeypatch):
+    """The assignment rule with one real entry and 31 ``NO_PAGE`` ones
+    runs its loop once, zeroes that page in every layer and points one
+    block entry at it; every other page and entry is untouched.  The
+    flush program applies the same rows to the same result."""
+    model, *_ = _arch("qwen1.5-0.5b")
+    table = PageTable(model, max_batch=32, max_ctx=MAX_CTX, page_size=PAGE)
+    cache = table.init_cache()
+    node = cache["groups"][0]
+    rng = np.random.default_rng(0)
+    node = dataclasses.replace(
+        node, kp=jnp.asarray(rng.normal(size=node.kp.shape), node.kp.dtype),
+        vp=jnp.asarray(rng.normal(size=node.vp.shape), node.vp.dtype))
+    cache = {**cache, "groups": (node,) + cache["groups"][1:]}
+    rows = np.full((2, 1, 32), NO_PAGE, np.int32)
+    rows[:, 0, 7] = 12, 2                       # slot 7: page 12 at jdx 2
+    before = jax.tree.map(np.asarray, node)
+    out = jax.jit(table.apply_assignments)(cache, rows[0], rows[1])
+    after = out["groups"][0]
+    for name in ("kp", "vp"):
+        a, b = np.asarray(getattr(after, name)), getattr(before, name)
+        assert not a[:, 12].any()
+        np.testing.assert_array_equal(np.delete(a, 12, axis=1),
+                                      np.delete(b, 12, axis=1))
+    block = np.array(after.block)
+    assert (block[:, 7, 2] == 12).all()
+    block[:, 7, 2] = before.block[:, 7, 2]
+    np.testing.assert_array_equal(block, before.block)
+    # the loop's trip count, read where it is set (eagerly, so concrete)
+    trips, loop = [], jax.lax.fori_loop
+
+    def counted(lo, hi, body, init):
+        trips.append(int(hi) - int(lo))
+        return loop(lo, hi, body, init)
+    monkeypatch.setattr(jax.lax, "fori_loop", counted)
+    eager = table.apply_assignments(cache, jnp.asarray(rows[0]),
+                                    jnp.asarray(rows[1]))
+    monkeypatch.undo()
+    assert trips == [1]
+    for x, y in zip(jax.tree.leaves(eager), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    flushed = table._flush_jit(jax.tree.map(jnp.copy, cache), rows)
+    for x, y in zip(jax.tree.leaves(flushed), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # ---------------------------------------------------------------------------

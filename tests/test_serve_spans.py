@@ -153,6 +153,14 @@ def test_pages_live_match_the_contexts(engine, prompts, traced):
     assert "page_table.forks" not in rec.counts
 
 
+def test_every_assignment_rides_the_decode_step(traced):
+    """With no preemption each page assignment is applied by the decode
+    step it was made for: the folded count is the assignment count."""
+    rec = traced[0]
+    assert rec.counts["page_table.assigns_folded"] == \
+        rec.counts["page_table.assigns"] > 0
+
+
 def test_kernel_blocks_match_the_contexts(engine, prompts, traced,
                                          tmp_path, monkeypatch):
     """On the paged kernel's path, two-page blocks (``BLOCK_BYTES`` cut to

@@ -176,65 +176,132 @@ _POOL_MOVES = re.compile(
     r"custom-call)\(.*")
 
 
-def _pool_moves(hlo: str, n_pages: int):
-    """HLO instructions (fused or not) that copy, slice, restack or
-    allocate a buffer with the pool's page count among its dims."""
-    found = []
+_DEFS = re.compile(r"%([\w.\-]+) = \S+?\[([\d,]*)\]")
+_DUS_UPDATE = re.compile(r"dynamic-update-slice\(%[\w.\-]+, %([\w.\-]+)")
+
+
+def _moved(hlo: str, pattern):
+    """``(line, dims moved)`` of each ``pattern`` match: the result's
+    dims, but a dynamic-update-slice's update's — one that writes a page
+    in place has a pool-shaped result and moves only the page."""
+    dims = {m.group(1): m.group(2) for m in _DEFS.finditer(hlo)}
     for line in hlo.splitlines():
-        m = _POOL_MOVES.search(line)
-        if m is None or str(n_pages) not in m.group(1).split(","):
+        m = pattern.search(line)
+        if m is None:
             continue
         if m.group(2) == "custom-call" and "AllocateBuffer" not in line:
             continue
-        found.append(line.strip()[:160])
-    return found
+        moved = m.group(1)
+        if m.group(2) == "dynamic-update-slice":
+            upd = _DUS_UPDATE.search(line)
+            if upd is not None:
+                moved = dims.get(upd.group(1), moved)
+        yield line.strip()[:160], [int(d) for d in moved.split(",") if d]
+
+
+def _pool_moves(hlo: str, n_pages: int):
+    """HLO instructions (fused or not) that copy, slice, restack or
+    allocate a buffer with the pool's page count among its dims."""
+    return [line for line, dims in _moved(hlo, _POOL_MOVES)
+            if n_pages in dims]
 
 
 @pytest.fixture(scope="module")
-def qwen_cell(one_chip):
+def qwen_cell(topo):
+    """The cell's engine step as it is dispatched: the paged table's
+    pending assignments ride the decode step's host array."""
     model = TransformerLM(get_config("qwen1.5-0.5b"))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    table = PageTable(model, CELL["batch"], CELL["max_ctx"],
+                      CELL["page_size"], None)
+    step, psh, csh = build_decode_step(
+        model, mesh, ShardingPolicy.for_mesh(mesh), batch=CELL["batch"],
+        cache_len=CELL["max_ctx"], per_slot_pos=True,
+        cache_factory=table.init_cache, decode_backend="pallas_paged",
+        assign=table.apply_assignments)
 
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
+    def placed(tree, shardings):
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=s), tree, shardings)
 
-    params = on_chip(jax.eval_shape(lambda: model.init(jax.random.key(0))))
-    cache = on_chip(jax.eval_shape(
-        lambda: model.init_paged_cache(**CELL)))
-    return model, params, cache
+    params = placed(jax.eval_shape(lambda: model.init(jax.random.key(0))),
+                    psh)
+    cache = placed(jax.eval_shape(table.init_cache), csh)
+    return table, step, params, cache, csh
 
 
 def test_decode_step_updates_pool_in_place(qwen_cell, one_chip):
     """The decode step at the chat cell's shapes moves no pool: the
-    stacked pools ride the layer scan's carry, each layer writes its
-    rows by scatter into them and the kernel reads them where they lie.
-    The page-minor layout or the scan's per-layer slice and restack
-    would show here as copies, slices or allocations of pool size."""
-    model, params, cache = qwen_cell
+    page assignments it is sent zero their pages and write their block
+    entries in place, the stacked pools ride the layer scan's carry,
+    each layer writes its rows by scatter into them and the kernel
+    reads them where they lie.  The page-minor layout or the scan's
+    per-layer slice and restack would show here as copies, slices or
+    allocations of pool size."""
+    table, step, params, cache, _ = qwen_cell
     node = cache["groups"][0]
     assert node.kp.shape == (24, CELL["kv_pages"], CELL["page_size"], 1024)
-    vec = jax.ShapeDtypeStruct((CELL["batch"],), jnp.int32,
-                               sharding=one_chip)
-    step = jax.jit(functools.partial(model.decode_step,
-                                     decode_backend="pallas_paged"),
-                   donate_argnums=(1,))
-    hlo = step.lower(params, cache, vec, vec).compile().as_text()
+    assert len(table.kv_streams) == 1
+    host = jax.ShapeDtypeStruct((4, CELL["batch"]), jnp.int32,
+                                sharding=one_chip)
+    hlo = step.lower(params, cache, host).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert _pool_moves(hlo, CELL["kv_pages"]) == []
 
 
 def test_page_zeroing_touches_one_page(qwen_cell, one_chip):
-    """Zeroing one page of every layer's pool, as the page table's
-    assignment does, reads and writes about that page (24 x 32 KB),
-    not a stride through the whole 1.6 GB pool."""
-    _, _, cache = qwen_cell
-    pool = cache["groups"][0].kp
-    pid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    zero = jax.jit(lambda kp, p: kp.at[:, p].set(0), donate_argnums=(0,))
-    cost = zero.lower(pool, pid).compile().cost_analysis()
+    """The page table's assignment rule (the function the decode step
+    and the flush program share), compiled for the cell's 32 slots,
+    reads and writes about one page of every layer's pool (24 x 32 KB
+    each of K and V) — one iteration of its loop over the assigned
+    entries — not a page for every slot (24 MB) nor a stride through
+    the whole 1.6 GB pool.  The cost analysis bills a loop's body once,
+    so this bound rules out a zeroing vectorized over every slot; that
+    the loop runs once for one real entry and 31 ``NO_PAGE`` ones is
+    pinned by ``test_paged_cache.py``
+    (``test_assignment_zeroes_one_page_and_writes_one_block_entry``)."""
+    table, _, _, cache, csh = qwen_cell
+    rows = jax.ShapeDtypeStruct((2, 1, CELL["batch"]), jnp.int32,
+                                sharding=one_chip)
+    flush = jax.jit(table._flush_fn, donate_argnums=(0,), out_shardings=csh)
+    cost = flush.lower(cache, rows).compile().cost_analysis()
     if isinstance(cost, (list, tuple)):
         cost = cost[0]
     assert cost["bytes accessed"] < 10e6
+
+
+def _undonated_zeroing(table, cache, csh, one_chip):
+    rows = jax.ShapeDtypeStruct((2, 1, CELL["batch"]), jnp.int32,
+                                sharding=one_chip)
+    return jax.jit(table._flush_fn, out_shardings=csh).lower(cache, rows)
+
+
+def _layer_restack(table, cache, csh, one_chip):
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def restack(kp, i):
+        page = jax.lax.dynamic_index_in_dim(kp, i, 0)
+        return jax.lax.dynamic_update_index_in_dim(kp, page * 2, i, 0)
+    return jax.jit(restack, donate_argnums=(0,)).lower(
+        cache["groups"][0].kp, layer)
+
+
+@pytest.mark.parametrize("program,op", [
+    (_undonated_zeroing, "copy"),
+    (_layer_restack, "dynamic-update-slice"),
+], ids=["undonated_zeroing", "layer_restack"])
+def test_pool_move_detector_catches_real_moves(qwen_cell, one_chip,
+                                               program, op):
+    """Negative controls of the detector the in-place tests use: the
+    page zeroing compiled without donating the cache must copy a pool,
+    and a layer sliced out of the stacked pool and written back is a
+    dynamic-update-slice whose update holds every page of the layer.
+    Each is reported, as the ``op`` it is, though the second's result
+    only has the pool's shape it writes in place."""
+    table, _, _, cache, csh = qwen_cell
+    hlo = program(table, cache, csh, one_chip).compile().as_text()
+    found = _pool_moves(hlo, CELL["kv_pages"])
+    assert any(f" {op}(" in line for line in found), found
 
 
 # the mixtral-8x22b-4L.chat benchmark cell: one 4-layer stage of
@@ -251,16 +318,8 @@ _MOVES = re.compile(r"= \S+\[([\d,]*)\]\S* (copy|dynamic-slice|"
 def _big_moves(hlo: str, elements: int):
     """Copies, slices, gathers and allocations of at least ``elements``
     elements: one expert's weight slice or more."""
-    found = []
-    for line in hlo.splitlines():
-        m = _MOVES.search(line)
-        if m is None or not m.group(1):
-            continue
-        if m.group(2) == "custom-call" and "AllocateBuffer" not in line:
-            continue
-        if np.prod([int(d) for d in m.group(1).split(",")]) >= elements:
-            found.append(line.strip()[:160])
-    return found
+    return [line for line, dims in _moved(hlo, _MOVES)
+            if dims and np.prod(dims) >= elements]
 
 
 def _custom_calls(hlo: str):
@@ -283,7 +342,8 @@ def mixtral_stage(topo):
     step, psh, csh = build_decode_step(
         model, mesh, policy, batch=STAGE["batch"],
         cache_len=STAGE["max_ctx"], per_slot_pos=True,
-        cache_factory=table.init_cache, decode_backend="pallas_paged")
+        cache_factory=table.init_cache, decode_backend="pallas_paged",
+        assign=table.apply_assignments)
 
     def placed(tree, shardings):
         return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
@@ -294,8 +354,8 @@ def mixtral_stage(topo):
     cache = placed(jax.eval_shape(table.init_cache), csh)
     assert cache["groups"][0].kp.shape == (4, STAGE["kv_pages"], 16, 1024)
     rep = NamedSharding(mesh, P())
-    vec = jax.ShapeDtypeStruct((STAGE["batch"],), jnp.int32, sharding=rep)
-    decode = step.lower(params, cache, vec, vec).compile().as_text()
+    host = jax.ShapeDtypeStruct((4, STAGE["batch"]), jnp.int32, sharding=rep)
+    decode = step.lower(params, cache, host).compile().as_text()
     pre = build_prefill_step(model, mesh, policy, cache_len=STAGE["max_ctx"],
                              batch=1)[0]
     prefill = pre.lower(
@@ -319,15 +379,17 @@ def test_mixtral_stage_collectives(mixtral_stage):
     """Decode: in the layer loop (compiled once, as a while loop) one
     sum of ``[slots, 1, d_model]`` after attention and one after the
     experts; outside it the embedding's sum and the vocabulary-split
-    logits' gather.  Prefill: the same sums at ``[1, tokens, d_model]``
-    and nothing else."""
+    logits' gather.  The page assignments ahead of it, each chip on its
+    share of the pool, are the only other while loop, with no
+    collective.  Prefill: the same sums at ``[1, tokens, d_model]`` and
+    nothing else."""
     cfg, decode, prefill = mixtral_stage
     d, b = cfg.d_model, STAGE["batch"]
     got = collections.Counter(
         (op, shape) for shape, op in _COLLECTIVE.findall(decode))
     assert got == {("all-reduce", f"bf16[{b},1,{d}]"): 3,
                    ("all-gather", f"f32[{b},{cfg.vocab_size}]"): 1}, got
-    assert decode.count("while(") == 1
+    assert decode.count("while(") == 2
     got = collections.Counter(
         (op, shape) for shape, op in _COLLECTIVE.findall(prefill))
     assert got == {("all-reduce", f"bf16[1,1024,{d}]"): 3}, got
